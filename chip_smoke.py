@@ -1,0 +1,320 @@
+"""chip_smoke.py — does the system still start on the chip?
+
+Drives the main path once, through the entry points a user calls (`import
+paddle_tpu as paddle`, `SpmdTrainer`, `ServingEngine`), at the full width of
+GPT-2-small (hidden 768, 12 layers, 12 heads, vocab 50304, sequence 1024, bf16
+autocast, dropout 0; random weights from --seed), in ONE process:
+
+  train  batch 16, AdamW, a few train_steps on one repeated batch. Checks: the
+         loss is finite and ends lower than it started; parameters and loss
+         live on a TPU device; the compiled step contains the Pallas flash
+         kernels (a step that took the naive softmax(QK^T)V path fails).
+  serve  ServingEngine, max_batch 8, bf16, six seeded prompts of 64..512
+         tokens, 32 new tokens each. Checks: every request finishes with
+         reason "length", token ids are in range, and the first request's
+         greedy output equals model.generate on the same prompt.
+
+With no arguments it needs one TPU chip and refuses to run without one (exit
+2, nothing on stdout — never a smaller model on the CPU). Any phase that
+raises ends the script non-zero; nothing is caught and carried on from. The
+LAST line of stdout is exactly
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+--multichip  (four chips; the builder runs it, the driver never does) runs ONLY
+             the sharded trainer and what it is compared with: SpmdTrainer on
+             build_mesh((2, 2), ("dp", "mp")) with sharding_stage=2, same
+             width, same batch, against the one-chip trainer's losses in this
+             same process, step by step; then checks the parameters really
+             are spread over four distinct devices. "count" is then 4.
+--rehearse   the same control flow at tiny shapes on whatever backend jax has
+             (the CPU, for rehearsals 1 and 2 of the on-chip-measurement
+             guide). Its last line says "ok": false — a rehearsal can never
+             be read as a pass.
+"""
+import argparse
+import collections
+import json
+import sys
+import time
+from importlib import metadata
+
+import jax
+import numpy as np
+
+import paddle_tpu as paddle
+from paddle_tpu.distributed.mesh import build_mesh
+from paddle_tpu.distributed.split import collect_spmd_specs
+from paddle_tpu.distributed.spmd import SpmdTrainer
+from paddle_tpu.inference.serving import ServingEngine
+from paddle_tpu.models import GPTConfig, GPTForCausalLM, GPTPretrainLoss
+
+FULL = dict(vocab_size=50304, hidden_size=768, num_layers=12, num_heads=12,
+            max_seq_len=1024)
+TINY = dict(vocab_size=512, hidden_size=64, num_layers=2, num_heads=4,
+            max_seq_len=128)
+
+#: --multichip: sharded vs one-chip loss, per step. Both run the same bf16
+#: matmuls, but tensor parallelism splits every contraction in two and the
+#: dp mean adds the halves in another order; bf16 carries 8 bits of mantissa
+#: (2**-8 ~ 4e-3 per rounding), and a few such roundings compound over the
+#: optimizer steps — 1e-2 relative holds that, and is two orders below what
+#: a wrong sharding rule does to a loss of ~10.
+MULTICHIP_RTOL = 1e-2
+
+
+def say(**fields):
+    """One informational JSON line (every line but the last)."""
+    print(json.dumps(fields), flush=True)
+
+
+class CacheEvents:
+    """jax's own persistent-compile-cache hit/miss events, per phase."""
+
+    def __init__(self):
+        self._seen = collections.Counter()
+        jax.monitoring.register_event_listener(
+            lambda name, **kw: self._seen.update([name]))
+
+    def take(self):
+        hits = self._seen.pop("/jax/compilation_cache/cache_hits", 0)
+        misses = self._seen.pop("/jax/compilation_cache/cache_misses", 0)
+        return {"compile_cache_hits": hits, "compile_cache_misses": misses}
+
+
+def build_trainer(cfg_kw, seed, mesh, tp_layers=False, sharded=False):
+    """A seeded GPT + AdamW + SpmdTrainer on `mesh`. tp_layers builds the
+    model from the tensor-parallel layers (weights tagged with their 'mp'
+    specs); sharded hands those specs to the trainer with ZeRO-2. The
+    sharded trainer and its one-chip reference both use tp_layers, so the
+    same seed gives them the same weights."""
+    paddle.seed(seed)
+    model = GPTForCausalLM(GPTConfig(dropout=0.0, tensor_parallel=tp_layers,
+                                     **cfg_kw))
+    opt = paddle.optimizer.AdamW(learning_rate=3e-4,
+                                 parameters=model.parameters())
+    kw = {}
+    if sharded:
+        kw = {"sharding_stage": 2,
+              "extra_param_specs": collect_spmd_specs(model)}
+    return SpmdTrainer(model, opt, loss_fn=GPTPretrainLoss(), mesh=mesh,
+                       dp_axis="dp", **kw)
+
+
+def make_batch(cfg_kw, batch, seed):
+    rng = np.random.RandomState(seed)
+    shape = (batch, cfg_kw["max_seq_len"])
+    return [paddle.to_tensor(
+        rng.randint(0, cfg_kw["vocab_size"], shape).astype(np.int32))
+        for _ in range(2)]
+
+
+def run_steps(trainer, batch, steps, events, label, expect_kernels):
+    """Compile the step, take `steps` train_steps on the repeated batch,
+    check the losses. Returns the per-step losses."""
+    specs = [(tuple(t.shape), "int32") for t in batch]
+    with paddle.amp.auto_cast(True, dtype="bfloat16"):
+        t0 = time.perf_counter()
+        trainer.aot_build(specs)
+        compile_s = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        losses = []
+        for _ in range(steps):
+            loss = trainer.train_step(*batch)
+            losses.append(loss)
+        jax.block_until_ready([t._data for t in losses])
+        run_s = time.perf_counter() - t0
+    loss_devices = losses[-1]._data.devices()
+    losses = [float(t._data) for t in losses]
+    # what XLA says the compiled step costs (its own cost and memory
+    # analysis, per device) — counts to set beside the seconds
+    xla = [{k: e.get(k) for k in ("flops", "argument_bytes", "temp_bytes")}
+           for e in paddle.trace.costs.table() if e["site"] == "trainer"][-1]
+    say(phase=label, compile_s=round(compile_s, 2), run_s=round(run_s, 2),
+        steps=steps, losses=[round(v, 4) for v in losses], xla=xla,
+        **events.take())
+    if not all(np.isfinite(losses)):
+        raise AssertionError(f"{label}: non-finite loss in {losses}")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(
+            f"{label}: loss did not fall on the repeated batch: {losses}")
+    # read AFTER the steps: None means the compiled step was rejected at
+    # call time and a different program ran
+    text = trainer.compiled_text()
+    if text is None:
+        raise AssertionError(
+            f"{label}: the compiled step did not run (the trainer fell "
+            "back to a lazy jit)")
+    if expect_kernels:
+        platforms = {d.platform for d in loss_devices}
+        for arr in trainer.params.values():
+            platforms |= {d.platform for d in arr.devices()}
+        if platforms != {"tpu"}:
+            raise AssertionError(
+                f"{label}: parameters/loss live on {sorted(platforms)}, "
+                "not on the TPU")
+        # fwd + dq + dkv Pallas calls per layer; the naive attention path
+        # has none
+        n_calls = text.count("tpu_custom_call")
+        if n_calls < 3:
+            raise AssertionError(
+                f"{label}: the compiled train step holds {n_calls} "
+                "tpu_custom_call(s) — attention went down the naive "
+                "softmax(QK^T)V path, not the Pallas flash kernels")
+        say(phase=label, tpu_custom_calls=n_calls)
+    return losses
+
+
+def phase_train(cfg_kw, batch, args, events, on_chip):
+    mesh = build_mesh((1,), ("dp",), devices=jax.devices()[:1])
+    trainer = build_trainer(cfg_kw, args.seed, mesh)
+    batch = make_batch(cfg_kw, batch, args.seed)
+    run_steps(trainer, batch, args.steps, events, "train",
+              expect_kernels=on_chip)
+
+
+def phase_serve(cfg_kw, args, events):
+    rehearse = args.rehearse
+    paddle.seed(args.seed)
+    model = GPTForCausalLM(GPTConfig(dropout=0.0, **cfg_kw))
+    model.eval()
+    vocab = cfg_kw["vocab_size"]
+    lo, hi, new_tokens = (8, 32, 8) if rehearse else (64, 512, 32)
+    rng = np.random.RandomState(args.seed)
+    lens = [lo] + [int(rng.randint(lo, hi + 1)) for _ in range(5)]
+    prompts = [rng.randint(0, vocab, (n,)).astype(np.int32) for n in lens]
+
+    t0 = time.perf_counter()
+    eng = ServingEngine(model, max_batch=4 if rehearse else 8,
+                        dtype="bfloat16")
+    rids = [eng.submit(p, max_new_tokens=new_tokens) for p in prompts]
+    results = eng.run_until_complete()
+    serve_s = time.perf_counter() - t0
+    for rid, n in zip(rids, lens):
+        req = results[rid]
+        toks = req.tokens
+        if req.finish_reason != "length" or len(toks) != new_tokens:
+            raise AssertionError(
+                f"serve: request {rid} (prompt {n}) ended "
+                f"{req.finish_reason!r} after {len(toks)} tokens")
+        if toks.min() < 0 or toks.max() >= vocab:
+            raise AssertionError(f"serve: request {rid} token id out of range")
+
+    t0 = time.perf_counter()
+    ref = model.generate(paddle.to_tensor(prompts[0][None]),
+                         max_new_tokens=new_tokens, temperature=0.0,
+                         dtype="bfloat16")
+    ref = np.asarray(ref._data)[0, lens[0]:]
+    generate_s = time.perf_counter() - t0
+    say(phase="serve", prompt_lens=lens, new_tokens=new_tokens,
+        serve_s=round(serve_s, 2), generate_s=round(generate_s, 2),
+        **events.take())
+    got = results[rids[0]].tokens
+    if not np.array_equal(got, ref):
+        raise AssertionError(
+            "serve: the engine's greedy tokens differ from model.generate "
+            f"on the same prompt: {got.tolist()} vs {ref.tolist()}")
+
+
+def phase_multichip(cfg_kw, batch, args, events, on_chip):
+    devices = jax.devices()[:4]
+    batch = make_batch(cfg_kw, batch, args.seed)
+
+    one = build_mesh((1,), ("dp",), devices=devices[:1])
+    trainer = build_trainer(cfg_kw, args.seed, one, tp_layers=True)
+    ref = run_steps(trainer, batch, args.steps, events,
+                    "one_chip_reference", expect_kernels=on_chip)
+    del trainer  # free chip 0 before the sharded trainer lands on it
+
+    mesh = build_mesh((2, 2), ("dp", "mp"), devices=devices)
+    trainer = build_trainer(cfg_kw, args.seed, mesh, tp_layers=True,
+                            sharded=True)
+    got = run_steps(trainer, batch, args.steps, events, "dp2_mp2_zero2",
+                    expect_kernels=on_chip)
+
+    rel = [abs(g - r) / abs(r) for g, r in zip(got, ref)]
+    say(phase="multichip_compare", rtol=MULTICHIP_RTOL,
+        rel_diff=[round(v, 6) for v in rel])
+    if max(rel) > MULTICHIP_RTOL:
+        raise AssertionError(
+            f"multichip: sharded losses {got} leave the one-chip losses "
+            f"{ref} by more than {MULTICHIP_RTOL} relative")
+
+    # really sharded: together the parameters' shards sit on four distinct
+    # devices, and an 'mp'-split weight holds half of itself on each
+    holders, split = set(), 0
+    for arr in trainer.params.values():
+        shards = arr.addressable_shards
+        holders |= {s.device for s in shards}
+        split += any(s.data.shape != arr.shape for s in shards)
+    say(phase="multichip_placement", devices_holding_shards=len(holders),
+        params=len(trainer.params), params_split=split)
+    if len(holders) != 4 or split == 0:
+        raise AssertionError(
+            f"multichip: parameters sit on {len(holders)} device(s) with "
+            f"{split} split — not spread over four chips")
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--multichip", action="store_true",
+                    help="four chips: only the sharded trainer and the "
+                         "one-chip trainer it is compared with")
+    ap.add_argument("--rehearse", action="store_true",
+                    help="tiny shapes on any backend; never prints "
+                         '"ok": true')
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--steps", type=int, default=6)
+    args = ap.parse_args(argv)
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    need = 4 if args.multichip else 1
+    on_chip = device["platform"] == "tpu"
+    if not args.rehearse and not on_chip:
+        print(f"chip_smoke.py needs a TPU; jax found {device}. Nothing "
+              "was run (use --rehearse for the tiny CPU rehearsal).",
+              file=sys.stderr)
+        return 2
+    if device["count"] < need:
+        print(f"chip_smoke.py --multichip needs 4 devices; jax found "
+              f"{device}. Nothing was run.", file=sys.stderr)
+        return 2
+
+    cfg_kw, batch = (TINY, 4) if args.rehearse else (FULL, 16)
+    # the persistent cache is for the chip: an XLA:CPU entry read back on
+    # another host warns about machine features on every load
+    cache_dir = None if args.rehearse else paddle.enable_compile_cache()
+    events = CacheEvents()
+    say(device=device, jax=jax.__version__,
+        libtpu=_libtpu_version(), compile_cache_dir=cache_dir,
+        config=cfg_kw, batch=batch, seed=args.seed,
+        rehearse=args.rehearse, multichip=args.multichip)
+
+    t0 = time.perf_counter()
+    if args.multichip:
+        phase_multichip(cfg_kw, batch, args, events, on_chip)
+    else:
+        phase_train(cfg_kw, batch, args, events, on_chip)
+        phase_serve(cfg_kw, args, events)
+    stats = dev.memory_stats() or {}
+    say(total_s=round(time.perf_counter() - t0, 2),
+        peak_bytes_in_use=stats.get("peak_bytes_in_use"))
+
+    if args.rehearse:
+        print(json.dumps({"ok": False, "rehearsal": "passed",
+                          "device": device}), flush=True)
+    else:
+        print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+def _libtpu_version():
+    try:
+        return metadata.version("libtpu")
+    except metadata.PackageNotFoundError:
+        return None
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -8,23 +8,10 @@ See SURVEY.md for the structural analysis of the reference this targets.
 """
 __version__ = "0.1.0"
 
-import os as _os
-
-if _os.environ.get("PADDLE_TPU_FORCE_CPU"):
-    # escape hatch for embedded/headless hosts where a sitecustomize pins the
-    # platform before user code can call jax.config.update (e.g. the C-ABI
-    # predictor host): honor the env var at first import
-    import jax as _jax
-
-    try:
-        _jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
-
 from . import flags as _flags_mod  # noqa: F401
 from .core import dtype as _dtype
 
-# dtypes (framework.proto:106 VarType.Type taxonomy)
+# dtypes (framework.proto:106 VarType.Type enumeration)
 bool = _dtype._NAME_TO_DTYPE["bool"]  # noqa: A001
 uint8 = _dtype.uint8
 int8 = _dtype.int8
@@ -47,6 +34,7 @@ from .core.device import (  # noqa: E402
     TPUPlace,
     XPUPlace,
     device_count,
+    enable_compile_cache,
     get_device,
     is_compiled_with_cuda,
     is_compiled_with_tpu,

@@ -13,12 +13,12 @@ def sub_jaxprs(eqn):
     """Yield (param_name, ClosedJaxpr-or-Jaxpr) for every inner jaxpr the
     eqn carries (pjit's `jaxpr`, cond's `branches` list, scan/while bodies,
     custom_*_call's `call_jaxpr`/`fun_jaxpr`...)."""
-    import jax
+    from jax.extend import core as jex_core
 
     for k, v in eqn.params.items():
         vals = v if isinstance(v, (list, tuple)) else (v,)
         for item in vals:
-            if isinstance(item, (jax.core.ClosedJaxpr, jax.core.Jaxpr)):
+            if isinstance(item, (jex_core.ClosedJaxpr, jex_core.Jaxpr)):
                 yield k, item
 
 

@@ -19,7 +19,7 @@ Checks per entry (TPU facts per /opt/skills/guides/pallas_guide.md):
   should be a multiple of the 128-lane register width (info: the block
   pads to a full lane tile, wasting lanes) and the second-minor a
   multiple of the dtype's sublane tile — 8 for f32, 16 for bf16, 32 for
-  int8/fp8 (warning: every access pays a relayout);
+  int8/fp8 (warning: every access pays a re-layout);
 - ``kernel-vmem-over-budget`` (error): streamed blocks are
   double-buffered by the Pallas pipeline (x2), scratch is resident (x1);
   the static total must fit the per-core VMEM budget (16 MiB) — the
@@ -121,7 +121,7 @@ def audit_entry(entry, budget=VMEM_BUDGET_BYTES):
             "kernel-block-misaligned", "warning",
             f"{kern}: sublane dim not a multiple of the dtype min tile "
             f"({', '.join(sublane_bad[:5])}) — every access pays a "
-            "relayout", where=where))
+            "re-layout", where=where))
 
     rows, total = vmem_breakdown(entry)
     if total > budget:

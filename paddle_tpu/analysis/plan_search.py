@@ -160,18 +160,17 @@ def _axis_program_report(plan, devices):
     sizes and run the full pass battery against the deployment mesh."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import AbstractMesh, PartitionSpec as P
 
     names, sizes = plan.mesh_axes
-    amesh = AbstractMesh(tuple(zip(names, sizes)))
+    amesh = AbstractMesh(tuple(sizes), tuple(names))
 
     def axis_prog(x):
         for a in names:
             x = jax.lax.psum(x, a)
         return x
 
-    f = shard_map(axis_prog, mesh=amesh, in_specs=P(), out_specs=P())
+    f = jax.shard_map(axis_prog, mesh=amesh, in_specs=P(), out_specs=P())
     closed = jax.make_jaxpr(f)(jnp.zeros((8, 8), jnp.float32))
     deploy = _DeployMesh(names, sizes, devices)
     return run_passes(closed, name=f"plan:{plan.describe()}",

@@ -162,11 +162,12 @@ def registered_passes():
 
 def _as_closed_jaxpr(fn_or_jaxpr, args, kwargs):
     import jax
+    from jax.extend import core as jex_core
 
-    if isinstance(fn_or_jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(fn_or_jaxpr, jex_core.ClosedJaxpr):
         return fn_or_jaxpr
-    if isinstance(fn_or_jaxpr, jax.core.Jaxpr):
-        return jax.core.ClosedJaxpr(fn_or_jaxpr, ())
+    if isinstance(fn_or_jaxpr, jex_core.Jaxpr):
+        return jex_core.ClosedJaxpr(fn_or_jaxpr, ())
     if callable(fn_or_jaxpr):
         return jax.make_jaxpr(fn_or_jaxpr)(*args, **kwargs)
     raise TypeError(

@@ -5,7 +5,7 @@ a missing sharding constraint replicates a tensor on every device, a typo'd
 collective axis deadlocks (or worse, silently runs on the wrong group), a
 non-bijective ppermute drops a rank's activation on the floor, and a
 collective inside one cond arm but not the other is a rank-divergence
-deadlock the 900s TPU watchdog reports as "timeout". All of it is visible
+deadlock that shows up only as a run that never ends. All of it is visible
 statically — this module propagates NamedSharding/PartitionSpec facts
 through a traced program's jaxpr under the mesh it is meant to run on and
 turns each hazard into a Finding with the offending provenance chain.

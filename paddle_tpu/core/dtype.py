@@ -1,7 +1,7 @@
 """Dtype registry for paddle_tpu.
 
 Reference parity: paddle/fluid/framework/framework.proto:106 (VarType.Type) defines the
-dtype taxonomy (BOOL..COMPLEX128); python/paddle/fluid/data_feeder.py convert_dtype.
+dtype enumeration (BOOL..COMPLEX128); python/paddle/fluid/data_feeder.py convert_dtype.
 TPU-native design: dtypes are jnp dtypes directly; bfloat16 is first-class (MXU native),
 float64 is supported but discouraged on TPU.
 """

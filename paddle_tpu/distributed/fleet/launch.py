@@ -11,6 +11,13 @@ names are kept so reference scripts port unchanged:
   PADDLE_TRAINER_ID / PADDLE_TRAINERS_NUM / PADDLE_TRAINER_ENDPOINTS /
   PADDLE_CURRENT_ENDPOINT.
 
+One process per chip: a chip belongs to the first process that touches it, so
+several local children that each start a TPU backend would all claim every chip and
+fail or hang. `--nproc_per_node > 1` is therefore for the CPU backend only
+(JAX_PLATFORMS=cpu — the multi-process test harness) and is refused otherwise. This
+launcher itself never initialises a jax backend (pinned by tests/test_chip_smoke.py):
+the children, not the parent, own the devices.
+
 Usage: python -m paddle_tpu.distributed.fleet.launch --ips host1,host2 train.py args…
 """
 import argparse
@@ -47,14 +54,27 @@ def get_cluster_env(ips, start_port, nproc_per_node, rank):
         "PADDLE_TRAINER_ENDPOINTS": ",".join(endpoints),
         "PADDLE_CURRENT_ENDPOINT": endpoints[rank],
         "PADDLE_LOCAL_RANK": str(rank % nproc_per_node),
-        "FLAGS_selected_tpus": str(rank % nproc_per_node),
     }
+
+
+def check_one_process_per_chip(nproc_per_node, environ):
+    """Refuse several local processes unless they are pinned off the TPU.
+    Decided from JAX_PLATFORMS alone — asking jax would start a backend in
+    the parent and take the chips from the children."""
+    platforms = environ.get("JAX_PLATFORMS", "")
+    if nproc_per_node > 1 and (not platforms or "tpu" in platforms.split(",")):
+        raise SystemExit(
+            f"fleetrun: --nproc_per_node {nproc_per_node} would start "
+            f"{nproc_per_node} processes that each claim every local TPU "
+            "chip. One process drives all local chips: use --nproc_per_node "
+            "1 (and shard over jax.devices() in the script), or set "
+            "JAX_PLATFORMS=cpu for a multi-process CPU run.")
 
 
 def launch_collective(args):
     """launch.py:208 parity: spawn local worker processes, wire env, wait, propagate
-    failures (kill the gang on first death — the reference's watchdog behavior)."""
-    hosts = args.ips.split(",")
+    failures (kill the gang on first death, as the reference does)."""
+    check_one_process_per_chip(args.nproc_per_node, os.environ)
     local_host_rank = 0  # index of this host in --ips (single-host default)
     n_local = args.nproc_per_node
     procs = []
@@ -113,6 +133,7 @@ def launch_ps(args):
 
     n_servers = args.server_num
     n_workers = args.worker_num if (args.worker_num or 0) > 0 else args.nproc_per_node
+    check_one_process_per_chip(n_servers + n_workers, os.environ)
     server_eps = ",".join(f"127.0.0.1:{free_port()}" for _ in range(n_servers))
     log_dir = args.log_dir
     if log_dir:

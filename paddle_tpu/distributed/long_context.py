@@ -253,31 +253,19 @@ def ulysses_attention_spmd(q, k, v, axis_name="sp", causal=False,
 
 
 def sequence_parallel_attention(q, k, v, mesh, impl="ring", causal=False,
-                                axis_name="sp", interpret=False):
+                                axis_name="sp", interpret=None):
     """Convenience wrapper: shard_map over the 'sp' axis of `mesh` on seq
     dim 1. impl: 'ring' (einsum blocks), 'ring_flash' (Pallas flash-kernel
     blocks — per-shard seq must be a multiple of 128), 'ulysses', or
     'ulysses_flash' (local attention through the flash kernel — FULL seq
-    must be a multiple of 128). interpret applies to the *_flash impls
-    (CPU kernel interpretation; auto-on off-TPU)."""
-    from jax.sharding import NamedSharding
+    must be a multiple of 128). interpret applies to the *_flash impls:
+    True/False is the caller's word; None asks the platform test now, at
+    call time (interpreted off-TPU, so models configured with a *_flash
+    impl work on the CPU test mesh)."""
+    if interpret is None:
+        from ..core.device import on_tpu
 
-    try:
-        from jax import shard_map as _sm
-
-        def smap(f, **kw):
-            return _sm(f, **kw)
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as _sm
-
-        smap = _sm
-
-    if impl.endswith("_flash") and not interpret:
-        # off-TPU the kernels only run interpreted — auto-enable so models
-        # configured with a *_flash impl work on the CPU test mesh
-        from ..ops.flash_attention import _on_tpu
-
-        interpret = not _on_tpu()
+        interpret = impl.endswith("_flash") and not on_tpu()
     if impl == "ring":
         body = functools.partial(ring_attention_spmd, axis_name=axis_name,
                                  causal=causal)
@@ -298,19 +286,8 @@ def sequence_parallel_attention(q, k, v, mesh, impl="ring", causal=False,
     if impl.endswith("_flash"):
         # pallas_call's out_shape carries no vma typing; skip the check
         kw["check_vma"] = False
-    try:
-        mapped = smap(body, mesh=mesh, in_specs=(spec, spec, spec),
-                      out_specs=spec, **kw)
-    except TypeError:
-        # older jax spells the knob check_rep; keep the check off when
-        # the flash body's pallas_call outputs carry no vma typing
-        try:
-            mapped = smap(body, mesh=mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec,
-                          **({"check_rep": False} if kw else {}))
-        except TypeError:  # no replication-check knob in this jax at all
-            mapped = smap(body, mesh=mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec)
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                           out_specs=spec, **kw)
     return mapped(q, k, v)
 
 

@@ -40,6 +40,14 @@ def get_mesh():
     return _CURRENT_MESH[0]
 
 
+def current_mesh():
+    """The mesh set by set_mesh()/mesh_scope(), or None. Unlike get_mesh()
+    it never builds a default one: for code that has to know whether it is
+    running under a mesh at all (SpmdTrainer scopes its mesh around the
+    traced forward so the Pallas flash kernel can shard_map itself)."""
+    return _CURRENT_MESH[0]
+
+
 @contextlib.contextmanager
 def mesh_scope(mesh):
     old = _CURRENT_MESH[0]

@@ -137,13 +137,9 @@ def expert_parallel_moe(x, gate_w, w1, b1, w2, b2, mesh, k=2, capacity_factor=2.
 
     Returns (out [T, d], aux_loss scalar). Differentiable.
     """
-    try:
-        from jax import shard_map as _sm
-    except ImportError:  # older jax keeps it under experimental
-        from jax.experimental.shard_map import shard_map as _sm
     body = functools.partial(moe_spmd, k=k, capacity_factor=capacity_factor,
                              activation=activation, axis_name=axis_name)
-    fn = _sm(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis_name, None), P(None, None),
                   P(axis_name, None, None), P(axis_name, None),

@@ -51,15 +51,6 @@ HANDOFF_SCHEMA = {
 }
 
 
-def _smap(f, mesh, in_specs, out_specs):
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-
 def _pure_call(layer, params, *args):
     """Call `layer` as a pure function of a params dict (name -> array)."""
     from ..core.functional import functional_state
@@ -123,7 +114,7 @@ class Pipeline:
             y_shape = x_all.shape[1:]
 
             # mark carry inits as device-varying over 'pp' (the module-
-            # level _vary: shard_map vma typing, identity fallback)
+            # level _vary: shard_map vma typing)
             buf = _vary(jnp.zeros_like(x_all[0]), ax)  # rank-held activation
             outs = _vary(jnp.zeros((n_micro,) + y_shape, x_all.dtype), ax)
             perm = [(i, (i + 1) % n_stage) for i in range(n_stage)]
@@ -165,7 +156,8 @@ class Pipeline:
         x_micro = x.reshape((self.n_micro, mb) + x.shape[1:])
         spmd = self.forward_fn()
         param_specs = {k: P(ax) for k in params}
-        mapped = _smap(spmd, self.mesh, in_specs=(param_specs, P()), out_specs=P())
+        mapped = jax.shard_map(spmd, mesh=self.mesh,
+                               in_specs=(param_specs, P()), out_specs=P())
         outs = mapped(params, x_micro)
         return Tensor(outs.reshape((self.n_micro * mb,) + outs.shape[2:]))
 
@@ -395,18 +387,8 @@ class PipelineTrainer:
             return jax.lax.psum(outs, ax)  # replicate from the last rank
 
         specs = {k: P(ax) for k in stage_params}
-        try:
-            mapped = jax.shard_map(spmd, mesh=self.mesh, in_specs=(specs, P()),
-                                   out_specs=P(), axis_names={ax})
-        except (AttributeError, TypeError):  # older jax: full-manual shard_map
-            if self.stage_param_specs:
-                import warnings
-
-                warnings.warn(
-                    "this jax lacks shard_map auto axes: the full-manual "
-                    "fallback replicates stage params over the tensor-"
-                    "parallel axis, dropping stage_param_specs sharding")
-            mapped = _smap(spmd, self.mesh, in_specs=(specs, P()), out_specs=P())
+        mapped = jax.shard_map(spmd, mesh=self.mesh, in_specs=(specs, P()),
+                               out_specs=P(), axis_names={ax})
         return mapped(stage_params, h_micro)
 
     # -- jitted train step ------------------------------------------------------

@@ -34,7 +34,7 @@ from ..framework import aot as _aot
 from ..framework import lineage as _lineage
 from ..profiler import RecordEvent as _RecordEvent
 from ..testing import failpoints as _failpoints
-from .mesh import get_mesh
+from .mesh import get_mesh, mesh_scope
 
 #: The checkpoint transfer edge (ISSUE 13; docs/ANALYSIS.md "Declaring a
 #: transfer edge"): the host-side train-state tree gather_train_state
@@ -136,17 +136,8 @@ def _pvary(x, ax):
     """Mark x device-varying over `ax` inside shard_map. Differentiating
     w.r.t. an UNVARYING (replicated) input auto-psums the cotangent across
     the axis — so a "local" gradient taken against replicated params comes
-    back pre-summed. pvary first keeps the grad genuinely rank-local.
-    On jax versions with neither pcast nor pvary, shard_map's cotangents
-    for replicated inputs are already rank-local (no auto-psum — verified
-    empirically on 0.4.x) and the identity fallback is correct."""
-    try:
-        return jax.lax.pcast(x, (ax,), to="varying")
-    except (AttributeError, TypeError):
-        try:
-            return jax.lax.pvary(x, (ax,))
-        except (AttributeError, TypeError):
-            return x
+    back pre-summed. Casting to varying first keeps the grad rank-local."""
+    return jax.lax.pcast(x, (ax,), to="varying")
 
 
 def owned_device_put(v, sh):
@@ -965,7 +956,11 @@ class SpmdTrainer:
 
         def step(params, opt_state, buffers, lr, rng, *batch):
             def loss_fn(p, b, r):
-                loss, new_buf, outs = fwd(p, buffers, b, r)
+                # this step is partitioned by XLA from its shardings; a
+                # kernel XLA cannot partition (Pallas flash) reads the
+                # scoped mesh and shard_maps itself over it
+                with mesh_scope(mesh):
+                    loss, new_buf, outs = fwd(p, buffers, b, r)
                 return loss.astype(jnp.float32), (new_buf, outs)
 
             if accum > 1:
@@ -1062,31 +1057,16 @@ class SpmdTrainer:
         return jax.jit(step, in_shardings=in_shardings, out_shardings=out_shardings,
                        donate_argnums=(0, 1, 2))
 
-    def _shard_map(self, f, in_specs, out_specs, check_rep=True):
-        """check_rep=False is for bodies whose replicated outputs flow
+    def _shard_map(self, f, in_specs, out_specs, check_vma=True):
+        """check_vma=False is for bodies whose replicated outputs flow
         through all_gather: the values are identical on every rank by
         construction (deterministic dequantize of identical gathered
         bytes), but static rep-inference cannot prove it — the compressed
         dp step's tests assert the cross-replica equality dynamically."""
-        ax = self.dp_axis
-        try:
-            return jax.shard_map(f, mesh=self.mesh, in_specs=in_specs,
-                                 out_specs=out_specs, axis_names={ax},
-                                 **({} if check_rep
-                                    else {"check_vma": False}))
-        except (AttributeError, TypeError):
-            try:
-                from jax import shard_map as sm
-            except ImportError:
-                from jax.experimental.shard_map import shard_map as sm
-
-            try:
-                return sm(f, mesh=self.mesh, in_specs=in_specs,
-                          out_specs=out_specs,
-                          **({} if check_rep else {"check_rep": False}))
-            except TypeError:
-                return sm(f, mesh=self.mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+        return jax.shard_map(f, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs,
+                             axis_names={self.dp_axis},
+                             check_vma=check_vma)
 
     def _build_localsgd(self, batch_arrays):
         """LocalSGD (fleet/meta_optimizers/localsgd_optimizer.py parity, SPMD):
@@ -1542,7 +1522,7 @@ class SpmdTrainer:
             if quant:
                 out_specs.append(P())
             return self._shard_map(local, in_specs, tuple(out_specs),
-                                   check_rep=False)(
+                                   check_vma=False)(
                 params, opt_state, buffers, lr, rng, *batch)
 
         batch_shard = NamedSharding(mesh, P(ax))
@@ -1662,6 +1642,16 @@ class SpmdTrainer:
         lr = jnp.asarray(self.optimizer.get_lr(), dtype=jnp.float32)
         rng = default_generator().fold_in(self.optimizer._step_count)
         return self._aot_compile(specs, lr, rng, force=True)
+
+    def compiled_text(self):
+        """HLO text of the latest step executable — what the device really
+        runs (chip_smoke.py reads it for the Pallas flash custom call).
+        None while the step is only a lazy jit: compile through
+        :meth:`aot_build` first. Also None once that executable rejected a
+        live call and the trainer went back to the lazy jit, so a reader
+        never inspects a program that is not the one running."""
+        executable = _aot.executable_of(self._compiled)
+        return None if executable is None else executable.as_text()
 
     # -- public ---------------------------------------------------------------
     def train_step(self, *batch):

@@ -1,7 +1,7 @@
-"""Structured error taxonomy.
+"""Structured error classification.
 
 Reference parity: paddle/fluid/platform/enforce.h (PADDLE_ENFORCE* macros) and
-errors.{h,cc} / error_codes.proto error-code taxonomy. Python-side enforce raises typed
+errors.{h,cc} / error_codes.proto error-code classification. Python-side enforce raises typed
 exceptions with the failing expression context instead of aborting.
 """
 

@@ -248,8 +248,8 @@ define_flag("perf_ledger", False,
 define_flag("perf_ledger_path", "",
             "with FLAGS_perf_ledger: path of the append-only JSONL "
             "ledger file. Appends are atomic (single write+flush+fsync "
-            "per row) and readers tolerate a torn tail, like bench.py "
-            "--banked. Empty = rows are kept in-process only (sentinel "
+            "per row) and readers tolerate a torn tail. Empty = rows "
+            "are kept in-process only (sentinel "
             "and metrics still run; nothing persists)")
 define_flag("perf_ledger_sigma", 4.0,
             "with FLAGS_perf_ledger: regression threshold — a step "

@@ -804,32 +804,17 @@ def _tp_wrap(run, tp_mesh, tp_specs, n_extra_in, out_specs, in_specs=None,
     """jit(shard_map(run)) for TP serving: params sharded per tp_specs and
     the n_extra_in trailing args replicated — or fully explicit in_specs
     (the serving engine passes its head-sharded cache specs); `donate`
-    forwards to jit (in-place cache updates). Owns the shard_map
-    import/check_vma version dance in ONE place."""
+    forwards to jit (in-place cache updates)."""
     import jax
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map as _sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
     if in_specs is None:
         in_specs = (tp_specs,) + (P(),) * n_extra_in
-    try:
-        mapped = _sm(run, mesh=tp_mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_vma=False)
-    except TypeError:
-        # older jax spells the knob check_rep; the check must actually be
-        # OFF either way — replication inference has no rule for the
-        # decode loop's while/scan carries (beam search, speculative),
-        # and falling back to a CHECKING shard_map turns those decodes
-        # into trace-time errors (the PR 17 clean-HEAD TP failures)
-        try:
-            mapped = _sm(run, mesh=tp_mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-        except TypeError:  # no replication checking in this jax at all
-            mapped = _sm(run, mesh=tp_mesh, in_specs=in_specs,
-                         out_specs=out_specs)
+    # check_vma OFF: replication inference has no rule for the decode
+    # loop's while/scan carries (beam search, speculative), and a CHECKING
+    # shard_map turns those decodes into trace-time errors
+    mapped = jax.shard_map(run, mesh=tp_mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     return jax.jit(mapped, donate_argnums=donate)
 
 

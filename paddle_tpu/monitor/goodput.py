@@ -3,7 +3,7 @@
 PR 19 made runs survive preemption and PR 17 made per-step speed
 persistent, but nothing measured what elasticity *costs*: a run that
 resumes twice and reshards once reports the same step_ms as an
-uninterrupted twin, and a wedged bench round cannot say where its 900 s
+uninterrupted twin, and a stalled bench run cannot say where its time
 went. This module is the per-run wall-clock accountant (ISSUE 20): one
 :class:`GoodputRun` classifies every second between ``start_run`` and
 ``end_run`` into EXCLUSIVE buckets —

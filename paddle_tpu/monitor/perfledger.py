@@ -7,10 +7,10 @@ peak-flops tables no measurement had ever corrected. This module is the
 durable record (ISSUE 17):
 
 **Ledger** — when ``FLAGS_perf_ledger`` is armed, trainers, serving
-engines, stage graphs, and every banked bench leg append one JSON row
+engines, stage graphs, and every completed bench leg append one JSON row
 per observation window to ``FLAGS_perf_ledger_path``: an append-only
 JSONL file (single write+flush+fsync per row; readers tolerate a torn
-tail, the ``bench.py --banked`` discipline). Each row carries the site,
+tail). Each row carries the site,
 the batch signature, the mesh fingerprint, an environment fingerprint
 (jax/jaxlib/python/machine/cpu_count + device kind when available), and
 a flat metrics dict — step wall ms, t_exec-windowed MFU, executable
@@ -129,7 +129,7 @@ def fingerprint_key(fp):
     return "|".join(f"{k}={fp.get(k)}" for k in CORE_FINGERPRINT)
 
 
-# -- JSONL persistence (the --banked discipline) -------------------------------
+# -- JSONL persistence -------------------------------------------------------
 
 def _jsonable(v):
     if isinstance(v, dict):
@@ -638,7 +638,7 @@ def record_stage_runner(runner, ledger=None, site="stage"):
 
 
 def record_leg(leg, data, ledger=None):
-    """One ledger row per banked bench leg: the leg's numeric fields
+    """One ledger row per completed bench leg: the leg's numeric fields
     (tokens/s, MFU, wall s, ...) under ``site="bench/<leg>"`` — BENCH
     retries auto-accumulate calibration data."""
     led = ledger if ledger is not None else get_ledger()

@@ -35,24 +35,24 @@ def scaled_dot_product_attention(
     args = [_t(query), _t(key), _t(value)]
     mask_val = attn_mask._data if isinstance(attn_mask, Tensor) else attn_mask
 
-    flash_ok = False
-    try:
-        from ...ops import flash_attention as fa
+    # a pure predicate: an import or lowering failure of the kernel raises
+    # — it never quietly becomes the naive path below
+    from ...ops import flash_attention as fa
 
-        q = args[0]
-        flash_ok = (
-            use_flash
-            and mask_val is None
-            and dropout_p == 0.0
-            and fa.supported(tuple(q.shape), str(q.dtype))
-        )
-    except Exception:
-        flash_ok = False
+    q = args[0]
+    flash_ok = (
+        use_flash
+        and mask_val is None
+        and dropout_p == 0.0
+        and fa.supported(tuple(q.shape), str(q.dtype))
+    )
 
     if flash_ok:
+        from ...distributed.mesh import current_mesh
+
         def fn(q, k, v):
             return fa.flash_attention(q, k, v, causal=is_causal,
-                                      window=window)
+                                      window=window, mesh=current_mesh())
 
         return apply(fn, *args)
 

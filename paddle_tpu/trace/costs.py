@@ -38,9 +38,8 @@ __all__ = ["record", "record_manual", "get", "table", "reset",
 _flags.define_flag(
     "device_peak_flops", 0.0,
     "peak device FLOP/s used as the MFU denominator; 0 = auto from the "
-    "device kind table (unknown kinds fall back to a nominal 1e12 so "
-    "MFU stays finite — absolute values are only meaningful on known "
-    "hardware)")
+    "device kind table (a TPU kind the table does not know raises; the "
+    "CPU test harness gets a nominal 1e12 so MFU stays finite there)")
 
 _LOCK = threading.Lock()
 _TABLE = {}   # (site, sig) -> entry dict
@@ -220,34 +219,40 @@ def sample_device_memory():
     return out
 
 
+def _peak_for(device, table, nominal, what):
+    """Look the device's kind up in `table`. The nominal constant is for
+    the CPU test harness only: on a TPU an unknown ``device_kind`` raises
+    — a utilization against an invented peak is worse than none."""
+    import jax
+
+    d = device or jax.devices()[0]
+    kind = str(getattr(d, "device_kind", d.platform)).lower()
+    for needle, peak in table:
+        if needle in kind:
+            return peak
+    if d.platform == "tpu":
+        raise ValueError(
+            f"no {what} on record for TPU device_kind {d.device_kind!r}; "
+            f"add it to trace/costs.py (known: "
+            f"{', '.join(n for n, _ in table)})")
+    return nominal
+
+
 def peak_flops(device=None):
     """The MFU denominator: FLAGS_device_peak_flops when set, else the
-    device-kind table, else a nominal 1e12 (keeps MFU finite on backends
-    with no published peak, e.g. the CPU test harness)."""
+    device-kind table; off-TPU a nominal 1e12 (keeps MFU finite on the
+    CPU test harness), on an unknown TPU kind an error."""
     override = float(_flags.get_flag("device_peak_flops", 0.0) or 0.0)
     if override > 0:
         return override
-    import jax
-
-    d = device or jax.devices()[0]
-    kind = str(getattr(d, "device_kind", d.platform)).lower()
-    for needle, flops in _PEAK_FLOPS_BY_KIND:
-        if needle in kind:
-            return flops
-    return _NOMINAL_PEAK
+    return _peak_for(device, _PEAK_FLOPS_BY_KIND, _NOMINAL_PEAK,
+                     "peak FLOP/s")
 
 
 def peak_hbm_bandwidth(device=None):
-    """Peak HBM bytes/s from the device-kind table, else a nominal
-    1e11 — the bandwidth denominator of the roofline
-    (analysis/cost_model.py prices ``max(flops/peak, bytes/bw)`` with
-    it; like :func:`peak_flops`, absolute values only mean something on
-    known hardware)."""
-    import jax
-
-    d = device or jax.devices()[0]
-    kind = str(getattr(d, "device_kind", d.platform)).lower()
-    for needle, bw in _PEAK_HBM_BW_BY_KIND:
-        if needle in kind:
-            return bw
-    return _NOMINAL_HBM_BW
+    """Peak HBM bytes/s from the device-kind table — the bandwidth
+    denominator of the roofline (analysis/cost_model.py prices
+    ``max(flops/peak, bytes/bw)`` with it). Off-TPU a nominal 1e11; on an
+    unknown TPU kind an error, like :func:`peak_flops`."""
+    return _peak_for(device, _PEAK_HBM_BW_BY_KIND, _NOMINAL_HBM_BW,
+                     "peak HBM bandwidth")

@@ -43,14 +43,13 @@ def nms_mask(boxes, scores, iou_threshold=3e-1, score_threshold=None, top_k=None
     if use_pallas is None:
         use_pallas = _np_kernel.supported(n)
     if use_pallas:
-        try:
-            keep_sorted_full = _np_kernel.nms_keep_mask_pallas(
-                boxes[order], iou_threshold)
-            keep = jnp.zeros(n, dtype=bool).at[order].set(keep_sorted_full)
-            return _nms_mask_filters(keep, scores, score_threshold, top_k,
-                                     order, n)
-        except Exception:  # Mosaic lowering/compile failure -> scan fallback
-            _np_kernel.mark_unsupported()
+        # a Mosaic lowering/compile failure raises: the scan below is the
+        # off-TPU path, not a net under the kernel
+        keep_sorted_full = _np_kernel.nms_keep_mask_pallas(
+            boxes[order], iou_threshold)
+        keep = jnp.zeros(n, dtype=bool).at[order].set(keep_sorted_full)
+        return _nms_mask_filters(keep, scores, score_threshold, top_k,
+                                 order, n)
     iou = _iou_matrix(boxes)
     iou_sorted = iou[order][:, order]
 

@@ -1382,11 +1382,7 @@ class TestAllowlistConsolidation:
 
 
 def _smap():
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm
+    return jax.shard_map
 
 
 def _mesh4(names=("dp",)):
@@ -1410,7 +1406,7 @@ class TestCollectiveAxisMismatch:
 
         return jax.make_jaxpr(_smap()(g, mesh=mesh, in_specs=P("dp"),
                                       out_specs=P(),
-                                      check_rep=False))(jnp.ones((8,)))
+                                      check_vma=False))(jnp.ones((8,)))
 
     def test_positive_axis_absent_from_deployment_mesh(self):
         other = jax.sharding.Mesh(np.array(jax.devices()[:4]), ("x",))
@@ -1453,7 +1449,7 @@ class TestPpermuteMalformed:
 
         return jax.make_jaxpr(_smap()(g, mesh=mesh, in_specs=P("dp"),
                                       out_specs=P("dp"),
-                                      check_rep=False))(jnp.ones((8,)))
+                                      check_vma=False))(jnp.ones((8,)))
 
     def test_positive_non_bijective(self):
         rep = run_passes(self._traced([(0, 1), (1, 1)]),
@@ -1503,7 +1499,7 @@ class TestBranchCollectiveMismatch:
 
         return jax.make_jaxpr(_smap()(g, mesh=mesh, in_specs=P("dp"),
                                       out_specs=P("dp"),
-                                      check_rep=False))(jnp.ones((8,)))
+                                      check_vma=False))(jnp.ones((8,)))
 
     def test_positive_one_arm_collective(self):
         rep = run_passes(self._traced(both_arms=False),
@@ -1535,7 +1531,7 @@ class TestBranchCollectiveMismatch:
 
         cj = jax.make_jaxpr(_smap()(g, mesh=mesh, in_specs=P("dp"),
                                     out_specs=P("dp"),
-                                    check_rep=False))(jnp.ones((8,)))
+                                    check_vma=False))(jnp.ones((8,)))
         rep = run_passes(cj, passes=["branch-collective-mismatch"],
                          mesh=_mesh4())
         assert len(rep.warnings) == 1
@@ -1554,7 +1550,7 @@ class TestBranchCollectiveMismatch:
 
         cj = jax.make_jaxpr(_smap()(g, mesh=mesh, in_specs=P("dp"),
                                     out_specs=P("dp"),
-                                    check_rep=False))(jnp.ones((8,)))
+                                    check_vma=False))(jnp.ones((8,)))
         rep = run_passes(cj, passes=["branch-collective-mismatch"],
                          mesh=_mesh4())
         assert rep.findings == []
@@ -1837,7 +1833,7 @@ class TestFlowSummary:
 
         return jax.make_jaxpr(_smap()(g, mesh=mesh, in_specs=P("dp"),
                                       out_specs=P(),
-                                      check_rep=False))(jnp.ones((8,)))
+                                      check_vma=False))(jnp.ones((8,)))
 
     def test_reduce_bytes_ring_factored(self):
         from paddle_tpu.analysis.sharding_flow import flow_summary

@@ -348,14 +348,13 @@ int main(int argc, char** argv) {
         return exe
 
     def _host_env(self):
-        # the embedded interpreter runs no conftest: PADDLE_TPU_FORCE_CPU
-        # makes the package itself pin the CPU backend at import
+        # the embedded interpreter runs no conftest: JAX_PLATFORMS pins
+        # the CPU backend for it
         repo_root = os.path.dirname(os.path.dirname(paddle.__file__))
         pythonpath = repo_root + (
             os.pathsep + os.environ["PYTHONPATH"]
             if os.environ.get("PYTHONPATH") else "")
-        return dict(os.environ, JAX_PLATFORMS="cpu",
-                    PADDLE_TPU_FORCE_CPU="1", PYTHONPATH=pythonpath)
+        return dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=pythonpath)
 
     def test_c_host_trains_lenet(self, tmp_path):
         """The reference's standalone native trainer, TPU-shaped: a pure C

@@ -117,7 +117,7 @@ class TestQuantizePrimitives:
 def _shard_reduce(x_per_rank, world, **kw):
     """Run quantized_all_reduce_ef under shard_map on `world` devices;
     returns the (replicated) reduced array from rank 0."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:world]), ("dp",))
@@ -128,7 +128,7 @@ def _shard_reduce(x_per_rank, world, **kw):
         return out[None]
 
     f = shard_map(body, mesh=mesh, in_specs=(P("dp"),),
-                  out_specs=P("dp"), check_rep=False)
+                  out_specs=P("dp"), check_vma=False)
     return np.asarray(jax.jit(f)(jnp.asarray(x_per_rank)))
 
 

@@ -30,7 +30,7 @@ class TestMesh:
 
 class TestCollectivesInShardMap:
     def test_psum_allreduce(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         mesh = build_mesh((8,), ("dp",))
         x = jnp.arange(8.0)
@@ -46,7 +46,7 @@ class TestCollectivesInShardMap:
         np.testing.assert_allclose(np.asarray(out), np.full(8, 28.0))
 
     def test_all_gather_and_scatter_reduce(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         mesh = build_mesh((8,), ("dp",))
         x = jnp.arange(8.0).reshape(8, 1)
@@ -63,7 +63,7 @@ class TestCollectivesInShardMap:
         np.testing.assert_allclose(np.asarray(out)[0], np.arange(8.0))
 
     def test_ppermute_shift(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         mesh = build_mesh((8,), ("dp",))
         x = jnp.arange(8.0).reshape(8, 1)

@@ -34,10 +34,11 @@ class TestFlashForward:
                                    atol=2e-5, rtol=2e-5)
 
     def test_head_dim_64_supported_on_tpu_gate(self):
-        from paddle_tpu.ops.flash_attention import supported, _on_tpu
+        from paddle_tpu.core.device import on_tpu
+        from paddle_tpu.ops.flash_attention import supported
 
-        if _on_tpu():
-            assert supported((8, 4096, 12, 64), "float32")
+        # the platform half of the predicate IS the one platform test
+        assert supported((8, 4096, 12, 64), "float32") == on_tpu()
         # shape gates independent of platform
         assert not supported((8, 100, 12, 64), "float32")   # seq % 128
         assert not supported((8, 1024, 12, 48), "float32")  # d % 64
@@ -73,6 +74,84 @@ class TestFlashBackward:
         g = jax.grad(loss)(q)
         assert g.dtype == jnp.bfloat16
         assert np.isfinite(np.asarray(g, np.float32)).all()
+
+
+class TestFlashUnderMesh:
+    """A Mosaic kernel cannot be partitioned by XLA, so under a mesh of
+    several devices the call shard_maps itself: batch over the data axes,
+    heads over 'mp', each only where it divides."""
+
+    @pytest.mark.parametrize("shape,axes,b,h,want", [
+        ((2, 2), ("dp", "mp"), 4, 2, (("dp",), None, "mp", None)),
+        ((2, 2), ("dp", "mp"), 3, 3, (None, None, None, None)),
+        ((2, 2), ("dp", "sharding"), 4, 2,
+         (("dp", "sharding"), None, None, None)),
+        ((4,), ("pp",), 4, 4, (None, None, None, None)),
+    ])
+    def test_spec_follows_the_axis_names_where_they_divide(
+            self, shape, axes, b, h, want):
+        from jax.sharding import PartitionSpec as P
+
+        from paddle_tpu.distributed.mesh import build_mesh
+        from paddle_tpu.ops.flash_attention import _mesh_spec
+
+        n = int(np.prod(shape))
+        mesh = build_mesh(shape, axes, devices=jax.devices()[:n])
+        assert _mesh_spec(mesh, b, h) == P(*want)
+
+    @pytest.mark.parametrize("manual", [{"dp"}, {"dp", "mp"}],
+                             ids=["dp_manual", "all_manual"])
+    def test_inside_a_shard_map_only_the_auto_axes_are_wrapped(self, manual):
+        """The trainer's shard_map steps (localsgd/DGC/compressed) and the
+        pipeline already split some axes: the call must not shard_map those
+        again (jax refuses a nested map over a manual axis)."""
+        from jax.sharding import PartitionSpec as P
+
+        from paddle_tpu.distributed.mesh import build_mesh
+
+        mesh = build_mesh((2, 2), ("dp", "mp"), devices=jax.devices()[:4])
+        q, k, v = _qkv(b=2, s=256, h=2, seed=5)
+
+        def body(q, k, v):
+            return flash_attention(q, k, v, causal=True, interpret=True,
+                                   mesh=mesh)
+
+        out = jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
+            axis_names=manual, check_vma=False))(q, k, v)
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(_naive(q, k, v, True)),
+                                   atol=2e-5, rtol=2e-5)
+
+    def test_sharded_fwd_and_grads_match_naive(self):
+        from paddle_tpu.distributed.mesh import build_mesh
+
+        mesh = build_mesh((2, 2), ("dp", "mp"), devices=jax.devices()[:4])
+        q, k, v = _qkv(b=2, s=256, h=2, seed=4)
+
+        def loss_flash(q, k, v):
+            o = flash_attention(q, k, v, causal=True, interpret=True,
+                                mesh=mesh)
+            return jnp.sum(o * jnp.cos(o))
+
+        def loss_naive(q, k, v):
+            o = _naive(q, k, v, True)
+            return jnp.sum(o * jnp.cos(o))
+
+        out = jax.jit(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=True, mesh=mesh))(q, k, v)
+        # really split: one (batch, head) slice per device
+        assert {s.data.shape for s in out.addressable_shards} \
+            == {(1, 256, 1, 64)}
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(_naive(q, k, v, True)),
+                                   atol=2e-5, rtol=2e-5)
+        gf = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+        gn = jax.grad(loss_naive, argnums=(0, 1, 2))(q, k, v)
+        for a, b, name in zip(gf, gn, "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=5e-5, rtol=5e-4,
+                                       err_msg=f"d{name} mismatch")
 
 
 def test_use_flash_knob_consumed():

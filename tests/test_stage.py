@@ -161,15 +161,26 @@ class TestPvaryDedupe:
         assert pipeline._vary is spmd._pvary
         assert long_context._vary is spmd._pvary
 
-    def test_identity_fallback_without_pcast_or_pvary(self, monkeypatch):
-        """On jax builds with NEITHER pcast nor pvary the helper is the
-        identity (shard_map cotangents are already rank-local there)."""
+    def test_marks_the_value_varying_over_the_axis(self):
+        """No identity arm: inside shard_map the helper really casts a
+        replicated value to device-varying over the named axis (an
+        unchanged value would let shard_map pre-sum its cotangent)."""
+        from jax.sharding import PartitionSpec as P
+
         from paddle_tpu.distributed import spmd
 
-        monkeypatch.delattr(jax.lax, "pcast", raising=False)
-        monkeypatch.delattr(jax.lax, "pvary", raising=False)
-        x = object()
-        assert spmd._pvary(x, "dp") is x
+        mesh = build_mesh((2,), ("dp",), devices=jax.devices()[:2])
+        seen = {}
+
+        def body(x):
+            seen["before"] = jax.typeof(x).vma
+            y = spmd._pvary(x, "dp")
+            seen["after"] = jax.typeof(y).vma
+            return y
+
+        jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P("dp"))(
+            np.ones((2,), np.float32))
+        assert seen == {"before": frozenset(), "after": frozenset({"dp"})}
 
 
 class TestSchedulesAndMeshes:

@@ -187,6 +187,28 @@ class TestGPTIntegration:
         armed = self._forward_logits(True, hidden=36)
         assert dense.tobytes() == armed.tobytes()
 
+    @pytest.mark.parametrize("approx", [False, True])
+    def test_compiled_block_mlp_raises_by_name_for_exact_gelu(
+            self, tpp, approx):
+        """Lowering for the chip (interpret=False): an exact-GELU block
+        raises by name BEFORE any kernel is built — never None, which would
+        send the block dense while FLAGS_tpp_kernels says otherwise. The
+        tanh form passes the gate (its lowering fails here only because
+        this backend is a CPU)."""
+        from paddle_tpu.models import GPTConfig, GPTForCausalLM
+
+        paddle.seed(0)
+        m = GPTForCausalLM(GPTConfig(
+            vocab_size=64, hidden_size=32, num_layers=1, num_heads=2,
+            max_seq_len=32, dropout=0.0, gelu_approx=approx))
+        blk = m.gpt.blocks[0]
+        x = jnp.zeros((2, 16, 32), jnp.float32)
+        want = ValueError if approx else NotImplementedError
+        match = "interpret mode" if approx else \
+            r"tpp\.gpt_block_mlp: exact \(erf\) GELU"
+        with pytest.raises(want, match=match):
+            tpp.gpt_block_mlp(x, blk.ln2, blk.mlp, interpret=False)
+
     def test_ports_land_in_registry_after_armed_train_step(self, tpp):
         from paddle_tpu.distributed.mesh import build_mesh
         from paddle_tpu.distributed.spmd import SpmdTrainer

@@ -317,6 +317,22 @@ class TestTrainerCostJoin:
         finally:
             paddle.set_flags({"device_peak_flops": 0.0})
 
+    @pytest.mark.parametrize("peak", [costs.peak_flops,
+                                      costs.peak_hbm_bandwidth])
+    def test_unknown_tpu_kind_raises_known_kind_and_cpu_answer(self, peak):
+        """The nominal constants are for the CPU harness only: a TPU the
+        table does not know is an error, never a default."""
+        import types
+
+        def dev(platform, kind):
+            return types.SimpleNamespace(platform=platform,
+                                         device_kind=kind)
+
+        assert peak(dev("tpu", "TPU v5 lite")) in (197e12, 0.8e12)
+        assert peak(dev("cpu", "cpu")) in (1e12, 1e11)
+        with pytest.raises(ValueError, match="TPU v99"):
+            peak(dev("tpu", "TPU v99"))
+
 
 class TestChromeExport:
     def test_export_loads_and_parents_resolve(self, tmp_path):

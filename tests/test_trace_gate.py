@@ -123,6 +123,10 @@ class TestInertByDefault:
         from paddle_tpu.inference.serving import ServingEngine
 
         monitor.reset()
+        # the cost registry is process-global and keyed by program label:
+        # an earlier test file on this worker may have captured the same
+        # serving programs (order depends on xdist's file scheduling)
+        trace.costs.reset()
         m = _tiny_model()
         rng = np.random.RandomState(0)
         prompts = [rng.randint(0, 64, (n,)).astype(np.int32)

@@ -117,7 +117,8 @@ def _cases():
 def _flash_case(f32):
     """The serving/training hot kernel: compiled at 2k seq on TPU;
     interpret mode off-TPU shrinks to 256 to stay tractable."""
-    from paddle_tpu.ops.flash_attention import _on_tpu, flash_attention
+    from paddle_tpu.core.device import on_tpu as _on_tpu
+    from paddle_tpu.ops.flash_attention import flash_attention
 
     on_tpu = _on_tpu()
     s = 2048 if on_tpu else 256
