@@ -12,8 +12,9 @@ it mechanical:
       reference table.
   metric-doc-stale    : a reference-table row naming a family no code
       registers (the doc promises telemetry that is gone).
-  span-undocumented   : a ``trace.span/start_span/emit`` name literal
-      missing from the span reference table.
+  span-undocumented   : a ``trace.span/start_span/emit/phase`` name
+      literal missing from the span reference table (step phases are
+      held to the same table as spans).
   span-doc-stale      : a span-table row with no emitting call site
       (dynamically-named families like ``collective/<op>`` are declared
       in :data:`DYNAMIC_SPANS` and accepted).
@@ -62,7 +63,7 @@ _METRIC_DEF_EXEMPT = ("monitor/registry.py", "monitor/exporters.py")
 _SPAN_DEF_EXEMPT = ("trace/__init__.py",)
 
 _METRIC_METHODS = ("counter", "gauge", "histogram")
-_SPAN_METHODS = ("span", "start_span", "emit")
+_SPAN_METHODS = ("span", "start_span", "emit", "phase")
 #: accepted receiver spellings — `_monitor.counter(...)` registers a
 #: metric, `scan.counter(...)` or a bare `emit(...)` helper does not
 _METRIC_RECEIVERS = ("monitor", "_monitor")

@@ -85,11 +85,11 @@ RULES = {
 #: module's path relative to the paddle_tpu package root.
 HOT_PATHS = {
     os.path.join("distributed", "spmd.py"): {
-        "train_step", "_train_step_impl", "_finish_step",
-        "_drain_verdicts"},
+        "train_step", "_train_step_impl", "_run_step", "_unpack_step",
+        "_finish_step", "_drain_verdicts"},
     os.path.join("inference", "serving.py"): {
         "step", "_step_inner", "_step_inner_sync", "_step_inner_async",
-        "_step_speculative", "_advance_prefill", "_activate",
+        "_admit_phase", "_step_speculative", "_advance_prefill", "_activate",
         "_admit_one_inner", "_advance_and_admit", "_dispatch_decode",
         "_apply_decode"},
 }

@@ -16,7 +16,6 @@ TPU-native design: ONE jitted train step over a Mesh.
 """
 import contextlib
 import functools
-import time
 
 import numpy as np
 import jax
@@ -1681,46 +1680,59 @@ class SpmdTrainer:
         # deliver=True: a sticky abort a swallowed stats() drain left
         # behind is re-raised (and cleared) HERE, to train_step's caller
         self._drain_verdicts(deliver=True)
-        t_step = time.perf_counter()
-        pre, self._prefetched = self._prefetched, None
-        if pre is not None and len(pre[0]) == len(batch) \
-                and all(a is b for a, b in zip(pre[0], batch)):
-            # prefetch() already staged THESE arrays on device while the
-            # previous step ran — consume the copies, skip marshalling.
-            # (A non-matching step discards the staging: stale copies
-            # must not linger to be consumed many steps later.)
-            batch_arrays = pre[1]
-            self._prefetch_hits += 1
-        else:
-            batch_arrays = [b._data if isinstance(b, Tensor)
-                            else jnp.asarray(np.asarray(b))  # lint: allow(step-loop-host-sync)
-                            for b in batch]
-        # value-transforming failpoint (scale:F) — chaos tests inject a
-        # gradient spike / non-finite batch here; one boolean check when
-        # nothing is armed (docs/ROBUSTNESS.md)
-        batch_arrays = _failpoints.transform("trainer/batch", batch_arrays)
-        lr = jnp.asarray(self.optimizer.get_lr(), dtype=jnp.float32)
-        # fresh per-step randomness (dropout etc.): deterministic under
-        # paddle.seed, varies per step — a trace-time key would bake ONE
-        # dropout mask into the compiled program
-        rng = default_generator().fold_in(self.optimizer._step_count)
-        sig_label = _batch_sig_label(batch_arrays)
-        self._last_sig = sig_label
-        entry = self._compiled_store.get(self._exec_key(batch_arrays))
-        if entry is None:
-            source = self._aot_compile(batch_arrays, lr, rng)
-            entry = self._compiled_store[self._exec_key(batch_arrays)]
-        else:
-            source = "memory"
-            if _monitor.is_enabled():
-                _aot.record_compile("trainer", sig_label, "memory")
+        with _trace.phase("train/step") as root:
+            with _trace.phase("train/batch") as ph:
+                pre, self._prefetched = self._prefetched, None
+                hit = pre is not None and len(pre[0]) == len(batch) \
+                    and all(a is b for a, b in zip(pre[0], batch))
+                ph.counts["prefetch_hit"] = int(hit)
+                if hit:
+                    # prefetch() already staged THESE arrays on device
+                    # while the previous step ran — consume the copies,
+                    # skip marshalling. (A non-matching step discards the
+                    # staging: stale copies must not linger to be consumed
+                    # many steps later.)
+                    batch_arrays = pre[1]
+                    self._prefetch_hits += 1
+                else:
+                    batch_arrays = [b._data if isinstance(b, Tensor)
+                                    else jnp.asarray(np.asarray(b))  # lint: allow(step-loop-host-sync)
+                                    for b in batch]
+                # value-transforming failpoint (scale:F) — chaos tests
+                # inject a gradient spike / non-finite batch here; one
+                # boolean check when nothing is armed (docs/ROBUSTNESS.md)
+                batch_arrays = _failpoints.transform("trainer/batch",
+                                                     batch_arrays)
+                lr = jnp.asarray(self.optimizer.get_lr(), dtype=jnp.float32)
+                # fresh per-step randomness (dropout etc.): deterministic
+                # under paddle.seed, varies per step — a trace-time key
+                # would bake ONE dropout mask into the compiled program
+                rng = default_generator().fold_in(self.optimizer._step_count)
+            with _trace.phase("train/resolve"):
+                sig_label = _batch_sig_label(batch_arrays)
+                self._last_sig = sig_label
+                entry = self._compiled_store.get(
+                    self._exec_key(batch_arrays))
+                if entry is None:
+                    source = self._aot_compile(batch_arrays, lr, rng)
+                    entry = self._compiled_store[
+                        self._exec_key(batch_arrays)]
+                else:
+                    source = "memory"
+                    if _monitor.is_enabled():
+                        _aot.record_compile("trainer", sig_label, "memory")
+            root.counts.update(step=int(self.optimizer._step_count),
+                               sig=sig_label, source=source)
+            return self._run_step(root, entry, lr, rng, batch_arrays,
+                                  sig_label, source)
+
+    def _run_step(self, root, entry, lr, rng, batch_arrays, sig_label,
+                  source):
+        """Call the resolved executable and unpack it (`train/dispatch`),
+        then the monitor tail (`train/finish`)."""
         compiled, guarded, narmed, qleg = entry
         if self._perf_ledger is not None:
             self._perf_cold = source != "memory"
-        # exec window starts AFTER compile resolution: stats()/MFU must
-        # divide flops by run time, not by jit-build + AOT-compile time
-        # (step_latency_ms keeps its historical include-compile meaning)
-        t_exec = time.perf_counter()
         # step span: compile-cache source + batch signature (+sync time,
         # stamped by _finish_step); carries the step's trace identity
         # and the weight version this step advances FROM (ISSUE 20)
@@ -1729,46 +1741,22 @@ class SpmdTrainer:
             step=int(self.optimizer._step_count), guarded=guarded,
             weight_version=str(self.weight_version))
         try:
-            if self.localsgd_k or self._is_dgc():
-                loss, self.params, self.opt_state, self.buffers = compiled(
-                    self.params, self.opt_state, self.buffers, lr, rng, *batch_arrays
-                )
+            # the exec window starts HERE, after compile resolution:
+            # stats()/MFU must divide flops by run time, not by jit-build
+            # + AOT-compile time (step_latency_ms keeps its historical
+            # include-compile meaning: the root phase's start)
+            with _trace.phase("train/dispatch") as disp:
+                if self.localsgd_k or self._is_dgc():
+                    loss, self.params, self.opt_state, self.buffers = \
+                        compiled(self.params, self.opt_state, self.buffers,
+                                 lr, rng, *batch_arrays)
+                else:
+                    loss = self._unpack_step(list(compiled(
+                        self.params, self.opt_state, self.buffers, lr, rng,
+                        *batch_arrays)), guarded, narmed, qleg)
                 self.optimizer._step_count += 1
-                return self._finish_step(loss, t_step, t_exec)
-            out = list(compiled(
-                self.params, self.opt_state, self.buffers, lr, rng, *batch_arrays
-            ))
-            # fixed unpack order matching _build's packing: loss, state,
-            # then the optional legs — outputs / numerics stats / finite
-            loss = out.pop(0)
-            self.params = out.pop(0)
-            self.opt_state = out.pop(0)
-            self.buffers = out.pop(0)
-            if self.return_outputs:  # ctor rejects localsgd/dgc combinations
-                self.last_outputs = jax.tree_util.tree_map(Tensor,
-                                                           out.pop(0))
-            nstats = out.pop(0) if narmed else None
-            finite = out.pop(0) if guarded else None
-            if qleg:
-                # the quantization-error norm stays device-resident
-                # until quantize_error()/stats() asks for it — no new
-                # per-step host sync
-                self._qerr_device = out.pop(0)
-            if nstats is not None:
-                # keep the stats leg device-resident; the host fetch
-                # happens only every FLAGS_numerics_interval steps
-                self._numerics_note(nstats)
-            if finite is not None:
-                # DEFERRED verdict (docs/PERF.md): the skip already
-                # happened on device if it happened at all — bank the
-                # device-resident verdict instead of syncing on it here.
-                # The schedule advances optimistically; _drain_verdicts
-                # rewinds it when a skip is discovered, so the loss
-                # trajectory is bit-exact with the old per-step fetch.
-                self._pending_verdicts.append(
-                    (int(self.optimizer._step_count), finite))
-            self.optimizer._step_count += 1
-            return self._finish_step(loss, t_step, t_exec)
+            with _trace.phase("train/finish") as fin:
+                return self._finish_step(loss, root, disp, fin)
         except BaseException:
             # the failing step still leaves its span (the very step a
             # trace gets pulled for); a stale handle must not leak into
@@ -1779,12 +1767,48 @@ class SpmdTrainer:
                 self._step_span = None
             raise
 
-    def _finish_step(self, loss, t_step, t_exec=None):
+    def _unpack_step(self, out, guarded, narmed, qleg):
+        """Fixed unpack order matching _build's packing: loss, state,
+        then the optional legs — outputs / numerics stats / finite.
+        Returns the loss."""
+        loss = out.pop(0)
+        self.params = out.pop(0)
+        self.opt_state = out.pop(0)
+        self.buffers = out.pop(0)
+        if self.return_outputs:  # ctor rejects localsgd/dgc combinations
+            self.last_outputs = jax.tree_util.tree_map(Tensor, out.pop(0))
+        nstats = out.pop(0) if narmed else None
+        finite = out.pop(0) if guarded else None
+        if qleg:
+            # the quantization-error norm stays device-resident until
+            # quantize_error()/stats() asks for it — no new per-step
+            # host sync
+            self._qerr_device = out.pop(0)
+        if nstats is not None:
+            # keep the stats leg device-resident; the host fetch happens
+            # only every FLAGS_numerics_interval steps
+            self._numerics_note(nstats)
+        if finite is not None:
+            # DEFERRED verdict (docs/PERF.md): the skip already happened
+            # on device if it happened at all — bank the device-resident
+            # verdict instead of syncing on it here. The schedule
+            # advances optimistically; _drain_verdicts rewinds it when a
+            # skip is discovered, so the loss trajectory is bit-exact
+            # with the old per-step fetch.
+            self._pending_verdicts.append(
+                (int(self.optimizer._step_count), finite))
+        return loss
+
+    def _finish_step(self, loss, root, disp, fin):
         """Monitor tail of train_step: optional FLAGS_benchmark device sync
         (so step_latency_ms measures device work) + the latency sample +
-        the step-span/stats() accounting the MFU report reads. `t_step`
-        includes any compile (the histogram's historical meaning);
-        `t_exec` excludes it — that is what stats()/MFU accumulate, so a
+        the step-span/stats() accounting the MFU report reads. The times
+        come from the step phases' own clock reads: the step ends where
+        the sync ends (a `train/sync` phase) or, with no sync, where
+        `train/finish` (`fin`) started. `step_ms` runs from the root
+        phase's start and so includes any compile (the histogram's
+        historical meaning); `exec_ms` runs from `train/dispatch`'s start
+        and excludes it — that is what stats()/MFU accumulate, so a
         2-step run is not dominated by the first step's compile."""
         # the handle's schedule identity, captured BEFORE the benchmark
         # drain below may rewind the counter for this very step's skip
@@ -1793,21 +1817,20 @@ class SpmdTrainer:
         # device-side skip still re-ran the program; the lineage tracks
         # states served/trained, not loss-improving updates)
         self.weight_version = self.weight_version.bump("step")
-        sync_ms = 0.0
+        sync_ms, end_ns = 0.0, fin.start_ns
         if _flags.get_flag("benchmark"):
-            t_sync = time.perf_counter()
-            if hasattr(loss, "block_until_ready"):
-                loss.block_until_ready()  # lint: allow(step-loop-host-sync)
-            _BENCH_SYNC.labels(site="trainer").inc()
-            # the device is drained anyway: settle pending guard
-            # verdicts for free (same-call skip visibility under
-            # FLAGS_benchmark, exactly the pre-deferral semantics);
-            # deliver=True — this raise reaches train_step's caller
-            self._drain_verdicts(force=True, deliver=True)
-            sync_ms = (time.perf_counter() - t_sync) * 1e3
-        now = time.perf_counter()
-        step_ms = (now - t_step) * 1e3
-        exec_ms = (now - (t_exec if t_exec is not None else t_step)) * 1e3
+            with _trace.phase("train/sync") as sync:
+                if hasattr(loss, "block_until_ready"):
+                    loss.block_until_ready()  # lint: allow(step-loop-host-sync)
+                _BENCH_SYNC.labels(site="trainer").inc()
+                # the device is drained anyway: settle pending guard
+                # verdicts for free (same-call skip visibility under
+                # FLAGS_benchmark, exactly the pre-deferral semantics);
+                # deliver=True — this raise reaches train_step's caller
+                self._drain_verdicts(force=True, deliver=True)
+            sync_ms, end_ns = sync.ms, sync.end_ns
+        step_ms = (end_ns - root.start_ns) / 1e6
+        exec_ms = (end_ns - disp.start_ns) / 1e6
         if _monitor.is_enabled():
             _STEP_MS.labels(site="trainer").observe(step_ms)
         self._step_count += 1
@@ -1817,7 +1840,6 @@ class SpmdTrainer:
         if sp is not None:
             sp.end(sync_ms=sync_ms, step_ms=step_ms, exec_ms=exec_ms)
             self._step_span = None
-            _trace.add_counter_sample("trainer_step_ms", step_ms)
         if self._perf_ledger is not None:
             self._ledger_step(step_ms, exec_ms, sync_ms)
         if self._async:

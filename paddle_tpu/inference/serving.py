@@ -808,6 +808,9 @@ class ServingEngine:
                    "occupancy_sum": 0, "occupancy_steps": 0,
                    "queue_wait_ms": _MsSummary(), "ttft_ms": _MsSummary(),
                    "inter_token_ms": _MsSummary()}
+        # admissions so far: [requests, prompt tokens] — the serve/step and
+        # serve/admit phases' `admitted`/`prompt_tokens` counts are deltas
+        self._admitted = [0, 0]
 
         # host-side slot state
         self._slot_req = [None] * self.B        # Request or None
@@ -895,11 +898,11 @@ class ServingEngine:
         padded[0, :n] = ids
         if self._paged:
             aid = self._resolve_adapter_slot(adapter)
-            t0 = time.perf_counter()
-            kc1, vc1, _ = self._prefill_pg(
-                self._params, jnp.asarray(padded), np.int32(n),
-                self._lora, jnp.asarray([aid], np.int32))
-            self._acc_ms("prefill", t0)
+            with _trace.phase("serve/prefill", tokens=n, bucket=pb) as ph:
+                kc1, vc1, _ = self._prefill_pg(
+                    self._params, jnp.asarray(padded), np.int32(n),
+                    self._lora, jnp.asarray([aid], np.int32))
+            self._acc_phase("prefill", ph)
             pid = self._next_pid
             self._next_pid += 1
             # full blocks land in the pool once (put_prefix may raise
@@ -912,16 +915,16 @@ class ServingEngine:
         # so its wall time must land in the same breakdown kind its
         # executed-flops counters feed — otherwise stats()['breakdown']
         # reports registration FLOPs with zero matching wall time
-        t0 = time.perf_counter()
-        kc1, vc1, _ = self._prefill(self._params, jnp.asarray(padded),
-                                    np.int32(n))
-        kc1d = vc1d = None
-        if self._draft is not None:  # the draft replays suffixes from its
-            # own cached prefix KV, like the target
-            kc1d, vc1d = self._draft_feed(self._params_d,
-                                          jnp.asarray(padded), np.int32(0),
-                                          *self._draft_row())
-        self._acc_ms("prefill", t0)
+        with _trace.phase("serve/prefill", tokens=n, bucket=pb) as ph:
+            kc1, vc1, _ = self._prefill(self._params, jnp.asarray(padded),
+                                        np.int32(n))
+            kc1d = vc1d = None
+            if self._draft is not None:  # the draft replays suffixes from
+                # its own cached prefix KV, like the target
+                kc1d, vc1d = self._draft_feed(
+                    self._params_d, jnp.asarray(padded), np.int32(0),
+                    *self._draft_row())
+        self._acc_phase("prefill", ph)
         pid = self._next_pid
         self._next_pid += 1
         self._prefixes[pid] = (ids, kc1, vc1, kc1d, vc1d)
@@ -1042,20 +1045,16 @@ class ServingEngine:
         self._m["steps"][kind] = self._m["steps"].get(kind, 0) + 1
         _STEPS.labels(kind=kind).inc()
 
-    def _acc_ms(self, kind, t0):
-        """Accumulate one step-kind slice's wall time (host-observed) for
-        stats()['breakdown']; returns the elapsed ms."""
-        return self._acc_ms_value(kind, (time.perf_counter() - t0) * 1e3)
-
-    def _acc_ms_value(self, kind, ms):
-        """Accumulate an already-computed slice (the async step books
-        dispatch+fetch windows only — the overlapped admission window is
-        booked under its own kinds by _advance_and_admit, and counting
-        it twice would make the kinds sum past real wall time)."""
+    def _acc_phase(self, kind, *phases):
+        """Book one step-kind slice of stats()['breakdown']: the summed
+        wall time of the closed step phases that cover it (a decode kind
+        is its dispatch + wait phases — in the async step the admission
+        window between them is booked under its own kinds, and counting
+        it twice would make the kinds sum past real wall time). The
+        phases' clock reads are the only ones taken."""
         st = self._m["step_ms"].setdefault(kind, [0, 0.0])
         st[0] += 1
-        st[1] += ms
-        return ms
+        st[1] += sum(ph.end_ns - ph.start_ns for ph in phases) / 1e6
 
     def stats(self):
         """Engine-lifetime observability snapshot: request counts by
@@ -1851,8 +1850,9 @@ class ServingEngine:
         seed = np.int32(req.seed)
         # fold value = index of the context's last token (n-1), matching
         # the decode step's schedule (each emission folds a unique value)
-        tok = int(self._pick1(logits, temp, topk, topp, seed,
-                              np.int32(n - 1)))
+        with _trace.phase("serve/prefill_wait"):
+            tok = int(self._pick1(logits, temp, topk, topp, seed,
+                                  np.int32(n - 1)))
         self._slot_req[slot] = req
         self._pos[slot] = n
         self._last[slot] = tok
@@ -1868,6 +1868,8 @@ class ServingEngine:
         reservation); prefix hit/miss is counted at the branch that
         actually decides reuse (_admit_one)."""
         req.admit_time = time.perf_counter()
+        self._admitted[0] += 1
+        self._admitted[1] += len(req.prompt_ids)
         wait_ms = (req.admit_time - req.submit_time) * 1e3 \
             if req.submit_time is not None else 0.0
         self._m["queue_wait_ms"].add(wait_ms)
@@ -1945,31 +1947,32 @@ class ServingEngine:
         # (dynamic_update_slice CLAMPS out-of-range starts, which would
         # silently shift tokens onto valid prefix columns)
         pb = self._bucket(n)
-        t0 = time.perf_counter()
         sp = None if req._span is None else _trace.start_span(
             "prefill", subsystem="serving", parent=req._span, slot=slot,
             tokens=n, bucket=pb)
         try:
-            padded = np.zeros((1, pb), np.int32)
-            padded[0, :n] = req.prompt_ids
-            kc1, vc1, logits = self._prefill(self._params,
-                                             jnp.asarray(padded),
-                                             np.int32(n))
-            draft_caches = None
-            if self._draft is not None:
-                draft_caches = self._draft_feed(self._params_d,
-                                                jnp.asarray(padded),
-                                                np.int32(0),
-                                                *self._draft_row())
-            self._activate(slot, req, kc1, vc1, logits,
-                           draft_caches=draft_caches)
+            with _trace.phase("serve/prefill", slot=slot, tokens=n,
+                              bucket=pb) as ph:
+                padded = np.zeros((1, pb), np.int32)
+                padded[0, :n] = req.prompt_ids
+                kc1, vc1, logits = self._prefill(self._params,
+                                                 jnp.asarray(padded),
+                                                 np.int32(n))
+                draft_caches = None
+                if self._draft is not None:
+                    draft_caches = self._draft_feed(self._params_d,
+                                                    jnp.asarray(padded),
+                                                    np.int32(0),
+                                                    *self._draft_row())
+                self._activate(slot, req, kc1, vc1, logits,
+                               draft_caches=draft_caches)
         except BaseException:
             # the failing admission's span must still be recorded (the
             # request itself is finished reason="error" by step())
             if sp is not None:
                 sp.end(error=True)
             raise
-        self._acc_ms("prefill", t0)
+        self._acc_phase("prefill", ph)
         if sp is not None:
             sp.end()
 
@@ -2018,22 +2021,23 @@ class ServingEngine:
             _PREFIX.labels(event=ev).inc()
         self._note_admission(req)
         pb = self._bucket(n)
-        t0 = time.perf_counter()
         sp = None if req._span is None else _trace.start_span(
             "prefill", subsystem="serving", parent=req._span, slot=slot,
             tokens=n, bucket=pb, paged=True)
         try:
-            padded = np.zeros((1, pb), np.int32)
-            padded[0, :n] = req.prompt_ids
-            kc1, vc1, logits = self._prefill_pg(
-                self._params, jnp.asarray(padded), np.int32(n),
-                self._lora, jnp.asarray([aid], np.int32))
-            self._activate(slot, req, kc1, vc1, logits)
+            with _trace.phase("serve/prefill", slot=slot, tokens=n,
+                              bucket=pb) as ph:
+                padded = np.zeros((1, pb), np.int32)
+                padded[0, :n] = req.prompt_ids
+                kc1, vc1, logits = self._prefill_pg(
+                    self._params, jnp.asarray(padded), np.int32(n),
+                    self._lora, jnp.asarray([aid], np.int32))
+                self._activate(slot, req, kc1, vc1, logits)
         except BaseException:
             if sp is not None:
                 sp.end(error=True)
             raise
-        self._acc_ms("prefill", t0)
+        self._acc_phase("prefill", ph)
         if sp is not None:
             sp.end()
 
@@ -2041,7 +2045,6 @@ class ServingEngine:
         self._m["occupancy_sum"] += len(active)
         self._m["occupancy_steps"] += 1
         _OCCUPANCY.set(len(active))
-        _trace.add_counter_sample("serving_batch_occupancy", len(active))
 
     def _dispatch_decode(self, active):
         """Enqueue ONE decode program for the active slots (device work
@@ -2140,9 +2143,10 @@ class ServingEngine:
                     try:
                         with _blackbox.progress("serving/admit"):
                             self._note_admission(req)
-                            t0 = time.perf_counter()
-                            self._activate(slot, req, kc1, vc1, logits)
-                            self._acc_ms("handoff_admit", t0)
+                            with _trace.phase("serve/handoff_admit",
+                                              slot=slot) as ph:
+                                self._activate(slot, req, kc1, vc1, logits)
+                            self._acc_phase("handoff_admit", ph)
                     except Exception:
                         self._finish_req(req, "error", slot=slot)
                         self._note_error()
@@ -2178,36 +2182,37 @@ class ServingEngine:
 
         req, kc1, vc1, off, C, kc1d, vc1d = self._prefilling[slot]
         self._count_step("prefill_chunk")
-        t0 = time.perf_counter()
         sp = None if req._span is None else _trace.start_span(
             "prefill_chunk", subsystem="serving", parent=req._span,
             slot=slot, offset=off, width=C)
         n = len(req.prompt_ids)
         end = min(off + C, n)
         try:
-            chunk = np.zeros((1, C), np.int32)
-            chunk[0, :end - off] = req.prompt_ids[off:end]
-            kc1, vc1, logits = self._prefill_chunk(
-                self._params, jnp.asarray(chunk), np.int32(off), kc1, vc1,
-                np.int32(end - off - 1))
-            if self._draft is not None:
-                kc1d, vc1d = self._draft_feed(self._params_d,
-                                              jnp.asarray(chunk),
-                                              np.int32(off), kc1d, vc1d)
-            if end >= n:
-                del self._prefilling[slot]
-                self._slot_req[slot] = None   # _activate re-binds
-                self._activate(slot, req, kc1, vc1, logits,
-                               draft_caches=(None if self._draft is None
-                                             else (kc1d, vc1d)))
-            else:
-                self._prefilling[slot] = [req, kc1, vc1, end, C, kc1d,
-                                          vc1d]
+            with _trace.phase("serve/prefill_chunk", slot=slot, offset=off,
+                              width=C) as ph:
+                chunk = np.zeros((1, C), np.int32)
+                chunk[0, :end - off] = req.prompt_ids[off:end]
+                kc1, vc1, logits = self._prefill_chunk(
+                    self._params, jnp.asarray(chunk), np.int32(off), kc1,
+                    vc1, np.int32(end - off - 1))
+                if self._draft is not None:
+                    kc1d, vc1d = self._draft_feed(self._params_d,
+                                                  jnp.asarray(chunk),
+                                                  np.int32(off), kc1d, vc1d)
+                if end >= n:
+                    del self._prefilling[slot]
+                    self._slot_req[slot] = None   # _activate re-binds
+                    self._activate(slot, req, kc1, vc1, logits,
+                                   draft_caches=(None if self._draft is None
+                                                 else (kc1d, vc1d)))
+                else:
+                    self._prefilling[slot] = [req, kc1, vc1, end, C, kc1d,
+                                              vc1d]
         except BaseException:
             if sp is not None:   # record the failing chunk's span too
                 sp.end(error=True)
             raise
-        self._acc_ms("prefill_chunk", t0)
+        self._acc_phase("prefill_chunk", ph)
         if sp is not None:
             sp.end(consumed=end)
 
@@ -2246,13 +2251,20 @@ class ServingEngine:
         # finished sibling engine cannot mask it, because the site only
         # deactivates when the LAST open step window closes
         with _blackbox.progress("serving/step"):
-            if self._perf_ledger is None:
-                return self._step_inner()
-            t0 = time.perf_counter()
+            root = _trace.phase("serve/step", queued=len(self._queue))
             try:
-                return self._step_inner()
+                with root:
+                    tokens0 = self._m["tokens"]
+                    admitted0 = self._admitted[0]
+                    done = self._step_inner(root)
+                    root.counts.update(
+                        admitted=self._admitted[0] - admitted0,
+                        emitted=self._m["tokens"] - tokens0,
+                        finished=len(done))
+                    return done
             finally:
-                self._ledger_round((time.perf_counter() - t0) * 1e3)
+                if self._perf_ledger is not None:
+                    self._ledger_round(root.ms)
 
     def _ledger_round(self, step_ms):
         """Armed-only (FLAGS_perf_ledger) per-round feed: the regression
@@ -2282,7 +2294,7 @@ class ServingEngine:
                 "engine instead of toggling it mid-flight")
         return self._paged
 
-    def _step_inner(self):
+    def _step_inner(self, root):
         if self._paged_active():
             # cold-page sweep rides the step cadence: registry-only prefix
             # frames untouched for page_cold_steps sweeps compress to int8
@@ -2294,10 +2306,20 @@ class ServingEngine:
         # paged engines too (their admission mutates the pool the
         # dispatched step's tables were snapshotted from).
         if self._async and self._draft is None and not self._paged:
-            return self._step_inner_async()
-        return self._step_inner_sync()
+            return self._step_inner_async(root)
+        return self._step_inner_sync(root)
 
-    def _step_inner_async(self):
+    def _admit_phase(self):
+        """The round's admission window as one `serve/admit` phase: what
+        every decoding row waits through."""
+        admitted0, tokens0 = self._admitted
+        with _trace.phase("serve/admit") as ph:
+            self._advance_and_admit()
+            ph.counts.update(admitted=self._admitted[0] - admitted0,
+                             prompt_tokens=self._admitted[1] - tokens0)
+        return ph
+
+    def _step_inner_async(self, root):
         """The async round (docs/PERF.md): dispatch the decode program
         for the slots active at entry (device starts immediately — jax
         dispatch is asynchronous), then run the HOST work of the next
@@ -2315,49 +2337,48 @@ class ServingEngine:
                   if self._slot_req[s] is not None
                   and s not in self._prefilling]
         self._note_occupancy(active)
+        root.counts["active"] = len(active)
         am = self._async_ms
         am["rounds"] += 1
-        dispatched = None
-        t0_ns = time.perf_counter_ns()
-        if active:
-            dispatched = self._dispatch_decode(active)
-        t_disp_ns = time.perf_counter_ns()
-        am["dispatch_ms"] += (t_disp_ns - t0_ns) / 1e6
+        dispatched = wait = None
+        with _trace.phase("serve/decode_dispatch") as disp:
+            if active:
+                dispatched = self._dispatch_decode(active)
+        am["dispatch_ms"] += disp.ms
         # ---- overlapped host window: round N+1's admission work runs
         # while round N's decode executes on device. The row copies the
         # admissions enqueue (_admit) sequence AFTER the in-flight decode
         # on its output cache — device-ordered, rows disjoint.
-        self._advance_and_admit()
-        t_ov_ns = time.perf_counter_ns()
-        am["overlap_ms"] += (t_ov_ns - t_disp_ns) / 1e6
+        admit = self._admit_phase()
+        am["overlap_ms"] += admit.ms
         if dispatched is not None:
             next_toks, kind = dispatched
             # THE round's one host sync: everything admission needed to
             # do already happened while the device was busy
-            next_toks = np.asarray(next_toks)  # lint: allow(step-loop-host-sync)
-            t1_ns = time.perf_counter_ns()
-            am["fetch_ms"] += (t1_ns - t_ov_ns) / 1e6
+            with _trace.phase("serve/decode_wait") as wait:
+                next_toks = np.asarray(next_toks)  # lint: allow(step-loop-host-sync)
+            am["fetch_ms"] += wait.ms
             # the kind's wall slice = dispatch + fetch windows; the
             # overlapped admission window is already booked under its
             # own kinds by _advance_and_admit
-            self._acc_ms_value(
-                kind, (t_disp_ns - t0_ns + t1_ns - t_ov_ns) / 1e6)
-            self._apply_decode(active, next_toks, kind, t0_ns, t1_ns)
-        else:
-            t1_ns = t_ov_ns
+            self._acc_phase(kind, disp, wait)
+            with _trace.phase("serve/emit"):
+                self._apply_decode(active, next_toks, kind, disp.start_ns,
+                                   wait.end_ns)
         if _trace.is_enabled():
-            # the PR 5 dispatch-vs-sync breakdown, span-attributed: the
-            # admission window rides INSIDE the device-compute window
-            _trace.emit("dispatch/decode", t0_ns, t_disp_ns,
+            # the PR 5 dispatch-vs-sync breakdown, span-attributed from
+            # the phases' clock reads: the admission window rides INSIDE
+            # the device-compute window
+            _trace.emit("dispatch/decode", disp.start_ns, disp.end_ns,
                         subsystem="serving", slots=len(active))
-            _trace.emit("dispatch/overlap", t_disp_ns, t_ov_ns,
+            _trace.emit("dispatch/overlap", admit.start_ns, admit.end_ns,
                         subsystem="serving")
-            if dispatched is not None:
-                _trace.emit("dispatch/fetch", t_ov_ns, t1_ns,
+            if wait is not None:
+                _trace.emit("dispatch/fetch", wait.start_ns, wait.end_ns,
                             subsystem="serving")
         return [self._finished[r] for r in set(self._finished) - before]
 
-    def _step_inner_sync(self):
+    def _step_inner_sync(self, root):
         import jax.numpy as jnp
 
         _fp.failpoint("serving/step")
@@ -2368,12 +2389,13 @@ class ServingEngine:
         self._expire_deadlines()
         # chunked admissions in flight advance ONE chunk each, so active
         # decodes below never wait for a whole long prefill
-        self._advance_and_admit()
+        self._admit_phase()
 
         active = [s for s in range(self.B)
                   if self._slot_req[s] is not None
                   and s not in self._prefilling]
         self._note_occupancy(active)
+        root.counts["active"] = len(active)
         if active:
             # speculative round: every active slot greedy AND spec_k+1
             # columns of headroom (near-capacity slots fall back to exact
@@ -2389,17 +2411,18 @@ class ServingEngine:
             # fed token into the draft cache so later speculative rounds
             # see an intact context (review r5: without this, one sampling
             # neighbor permanently cold-starts every survivor's draft)
-            t0 = time.perf_counter()
-            t0_ns = time.perf_counter_ns()
-            if self._draft is not None:
-                self._kc_d, self._vc_d = self._draft_sync(
-                    self._params_d, self._kc_d, self._vc_d,
-                    jnp.asarray(self._last), jnp.asarray(self._pos))
-            next_toks, kind = self._dispatch_decode(active)
-            next_toks = np.asarray(next_toks)  # lint: allow(step-loop-host-sync)
-            self._acc_ms(kind, t0)
-            t1_ns = time.perf_counter_ns()
-            self._apply_decode(active, next_toks, kind, t0_ns, t1_ns)
+            with _trace.phase("serve/decode_dispatch") as disp:
+                if self._draft is not None:
+                    self._kc_d, self._vc_d = self._draft_sync(
+                        self._params_d, self._kc_d, self._vc_d,
+                        jnp.asarray(self._last), jnp.asarray(self._pos))
+                next_toks, kind = self._dispatch_decode(active)
+            with _trace.phase("serve/decode_wait") as wait:
+                next_toks = np.asarray(next_toks)  # lint: allow(step-loop-host-sync)
+            self._acc_phase(kind, disp, wait)
+            with _trace.phase("serve/emit"):
+                self._apply_decode(active, next_toks, kind, disp.start_ns,
+                                   wait.end_ns)
         return [self._finished[r] for r in set(self._finished) - before]
 
     def _step_speculative(self, active):
@@ -2415,19 +2438,19 @@ class ServingEngine:
         import jax.numpy as jnp
 
         self._count_step("speculative")
-        t0 = time.perf_counter()
-        t0_ns = time.perf_counter_ns()
-        props, self._kc_d, self._vc_d = self._draft_propose(
-            self._params_d, self._kc_d, self._vc_d,
-            jnp.asarray(self._last), jnp.asarray(self._pos))
-        t_draft_ns = time.perf_counter_ns()
-        emit, m, self._kc, self._vc = self._verify(
-            self._params, self._kc, self._vc, jnp.asarray(self._last),
-            jnp.asarray(self._pos), props)
-        emit = np.asarray(emit)  # lint: allow(step-loop-host-sync)
-        m = np.asarray(m)  # lint: allow(step-loop-host-sync)
-        t1_ns = time.perf_counter_ns()
-        self._acc_ms("speculative", t0)
+        with _trace.phase("serve/decode_dispatch") as disp:
+            props, self._kc_d, self._vc_d = self._draft_propose(
+                self._params_d, self._kc_d, self._vc_d,
+                jnp.asarray(self._last), jnp.asarray(self._pos))
+            t_draft_ns = time.perf_counter_ns()   # draft | verify boundary
+            emit, m, self._kc, self._vc = self._verify(
+                self._params, self._kc, self._vc, jnp.asarray(self._last),
+                jnp.asarray(self._pos), props)
+        with _trace.phase("serve/decode_wait") as wait:
+            emit = np.asarray(emit)  # lint: allow(step-loop-host-sync)
+            m = np.asarray(m)  # lint: allow(step-loop-host-sync)
+        t0_ns, t1_ns = disp.start_ns, wait.end_ns
+        self._acc_phase("speculative", disp, wait)
         if _trace.is_enabled():
             _trace.emit("spec_draft", t0_ns, t_draft_ns,
                         subsystem="serving", slots=len(active),
@@ -2440,32 +2463,33 @@ class ServingEngine:
         self._m["spec_accepted"] += accepted
         _SPEC.labels(event="proposed").inc(proposed)
         _SPEC.labels(event="accepted").inc(accepted)
-        for s in active:
-            req = self._slot_req[s]
-            try:
-                _fp.failpoint("serving/slot")
-                n_acc = int(m[s]) + 1
-                toks = emit[s, :n_acc]
-                old_pos = int(self._pos[s])
-                self._last[s] = int(toks[-1])
-                if req._span is not None:
-                    _trace.emit("decode", t0_ns, t1_ns,
-                                subsystem="serving", parent=req._span,
-                                slot=s, pos=old_pos, kind="speculative",
-                                accepted=int(m[s]), emitted=n_acc)
-                for i, t in enumerate(toks):
-                    # advance pos PER TOKEN so _after_emit's eos/length/
-                    # capacity decisions are made at exactly the state the
-                    # single-token engine would have seen
-                    self._pos[s] = old_pos + i + 1
-                    req.output_ids.append(int(t))
-                    self._after_emit(s, req)
-                    if req.finished:
-                        break
-            except Exception:
-                if self._slot_req[s] is not None:
-                    self._finish_req(req, "error", slot=s)
-                self._note_error()
+        with _trace.phase("serve/emit"):
+            for s in active:
+                req = self._slot_req[s]
+                try:
+                    _fp.failpoint("serving/slot")
+                    n_acc = int(m[s]) + 1
+                    toks = emit[s, :n_acc]
+                    old_pos = int(self._pos[s])
+                    self._last[s] = int(toks[-1])
+                    if req._span is not None:
+                        _trace.emit("decode", t0_ns, t1_ns,
+                                    subsystem="serving", parent=req._span,
+                                    slot=s, pos=old_pos, kind="speculative",
+                                    accepted=int(m[s]), emitted=n_acc)
+                    for i, t in enumerate(toks):
+                        # advance pos PER TOKEN so _after_emit's eos/length/
+                        # capacity decisions are made at exactly the state the
+                        # single-token engine would have seen
+                        self._pos[s] = old_pos + i + 1
+                        req.output_ids.append(int(t))
+                        self._after_emit(s, req)
+                        if req.finished:
+                            break
+                except Exception:
+                    if self._slot_req[s] is not None:
+                        self._finish_req(req, "error", slot=s)
+                    self._note_error()
 
     def has_work(self):
         return bool(self._queue) or bool(self._handoff) \

@@ -267,6 +267,7 @@ def _flash_fwd(q3, k3, v3, causal, scale, interpret, window=None):
             pltpu.VMEM((blk, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q3, k3, v3)
     return o, lse[:, 0, :]
 
@@ -382,6 +383,7 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, causal, scale, interpret,
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
         scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dq",
     )(q3, k3, v3, do3, lse2, delta2)
 
     dk, dv = pl.pallas_call(
@@ -409,6 +411,7 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, causal, scale, interpret,
             pltpu.VMEM((blk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_dkv",
     )(q3, k3, v3, do3, lse2, delta2)
     return dq, dk, dv
 

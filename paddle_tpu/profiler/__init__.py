@@ -124,7 +124,7 @@ def export_chrome_tracing(path):
     merged exporter (paddle_tpu.trace.export_chrome), so host events are
     emitted sorted by start time — nested RecordEvents render as a tree
     from ts/dur ordering instead of unordered same-tier slices — and the
-    old API's output gains whatever trace spans / counter samples exist."""
+    old API's output gains whatever trace spans / step phases exist."""
     from .. import trace as _trace
 
     _trace.export_chrome(path)

@@ -27,6 +27,19 @@ appended as JSONL through the monitor event-log writer. Disabled mode
 same discipline as monitor/failpoints, pinned <5µs/call by
 tests/test_trace_gate.py.
 
+Beside the request spans sits the STEP-PHASE TIMELINE: ``with
+phase("serve/decode_wait", active=32):`` marks one of the small fixed
+set of step-level phases inside ``ServingEngine.step`` and
+``SpmdTrainer.train_step`` (docs/OBSERVABILITY.md lists them). It is
+always on — not behind ``FLAGS_trace``: entering a phase enters a
+``jax.profiler.TraceAnnotation`` (so any profiler capture shows it on
+the device's clock beside the XLA ops, and with no capture open it
+costs a fraction of a microsecond), and leaving it appends one plain
+tuple to a bounded ring of its own (``phases()``), which per-request
+spans cannot evict. The engine's and the trainer's own step-time
+accounting reads these phases' clock reads; there is no second set of
+timers.
+
 ``export_chrome(path)`` merges three sources into one chrome://tracing
 JSON (docs/OBSERVABILITY.md):
 
@@ -34,8 +47,8 @@ JSON (docs/OBSERVABILITY.md):
   renders from ts/dur ordering);
 - trace spans, one chrome *process* per subsystem, with flow events
   linking every multi-span trace_id across threads;
-- span-boundary counter samples (``add_counter_sample``) as ph="C"
-  counter tracks.
+- the step phases, as one more process ("phases"), one track per
+  phase family (``serve``, ``train``).
 
 The sibling :mod:`paddle_tpu.trace.costs` is the device cost registry:
 per-executable ``cost_analysis()``/``memory_analysis()`` tables captured
@@ -50,13 +63,15 @@ import json
 import threading
 import time
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from .. import flags as _flags
 
 __all__ = [
     "Span", "span", "start_span", "emit", "current_span", "new_trace_id",
     "enable", "disable", "is_enabled", "sync_from_flag", "clear",
     "spans", "open_spans", "set_capacity", "capacity", "summary",
-    "top_spans", "add_counter_sample", "counter_samples", "export_chrome",
+    "top_spans", "phase", "phases", "PHASE_CAPACITY", "export_chrome",
     "load_spans", "costs",
 ]
 
@@ -80,7 +95,6 @@ _TLS = threading.local()
 _SPAN_IDS = itertools.count(1)
 _TRACE_IDS = itertools.count(1)
 _BUF = collections.deque(maxlen=int(_flags.get_flag("trace_buffer", 4096)))
-_SAMPLES = collections.deque(maxlen=4096)   # (ts_ns, name, value)
 _OPEN = {}                  # span_id -> OPEN Span (entered/started, not
 _OPEN_CAP = 8192            # yet ended) — the blackbox dump's span tree
 
@@ -125,8 +139,9 @@ def capacity():
 def clear():
     with _LOCK:
         _BUF.clear()
-        _SAMPLES.clear()
         _OPEN.clear()
+    _PHASES.clear()
+    _PHASES_LOST_NS[0] = None
 
 
 def spans():
@@ -152,21 +167,6 @@ def _track_open(sp):
     # is off): a span that never closes is exactly the wedge evidence
     _blackbox.note("span_open", name=sp.name, subsystem=sp.subsystem,
                    trace_id=sp.trace_id)
-
-
-def counter_samples():
-    with _LOCK:
-        return list(_SAMPLES)
-
-
-def add_counter_sample(name, value):
-    """Record one (ts, name, value) counter sample — rendered as a ph='C'
-    track by export_chrome. Call sites sample at span boundaries (the
-    serving step samples batch occupancy, the trainer step latency)."""
-    if not _ENABLED[0]:
-        return
-    with _LOCK:
-        _SAMPLES.append((time.perf_counter_ns(), str(name), float(value)))
 
 
 def _stack():
@@ -391,6 +391,85 @@ def scoped_enabled(on=True):
         _ENABLED[0] = old
 
 
+# -- the step-phase timeline ---------------------------------------------------
+
+#: fixed capacity of the phase ring (not a flag): 60 s of a loop that turns
+#: 200 steps a second at eight phases a step. Full, it holds about 30 MB.
+PHASE_CAPACITY = 60 * 200 * 8
+_PHASES = collections.deque(maxlen=PHASE_CAPACITY)
+_PHASES_LOST_NS = [None]    # end_ns of the newest phase the ring evicted
+_PHASE_ROOTS = itertools.count(1)
+_now_ns = time.perf_counter_ns
+
+
+class _Phase:
+    """One open step phase. ``start_ns``/``end_ns`` are the phase's own
+    two clock reads (perf_counter_ns) — the caller's accounting reads
+    them instead of taking its own; ``counts`` may be filled in until
+    the phase closes."""
+
+    __slots__ = ("name", "counts", "start_ns", "end_ns", "parent",
+                 "step_no", "_ann")
+
+    def __init__(self, name, counts):
+        self.name = name
+        self.counts = counts
+        self.start_ns = self.end_ns = None
+
+    def __enter__(self):
+        st = getattr(_TLS, "phases", None)
+        if st is None:
+            st = _TLS.phases = []
+        if st:
+            top = st[-1]
+            self.parent, self.step_no = top.name, top.step_no
+        else:
+            self.parent, self.step_no = None, next(_PHASE_ROOTS)
+        st.append(self)
+        ann = self._ann = _TraceAnnotation(self.name)
+        ann.__enter__()
+        self.start_ns = _now_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.end_ns = _now_ns()
+        self._ann.__exit__(exc_type, exc, tb)
+        _TLS.phases.pop()               # `with` blocks close innermost first
+        if len(_PHASES) == PHASE_CAPACITY:
+            _PHASES_LOST_NS[0] = _PHASES[0][2]
+        _PHASES.append((self.name, self.start_ns, self.end_ns, self.parent,
+                        self.step_no, self.counts or None))
+        return False
+
+    @property
+    def ms(self):
+        """Duration of the closed phase in milliseconds."""
+        return (self.end_ns - self.start_ns) / 1e6
+
+
+def phase(name, **counts):
+    """Context manager for one step-level phase (always on; see the
+    module docstring). Nested phases on one thread record their parent's
+    name and share the root's ``step_no`` (a process-wide ordinal of
+    root phases). On leaving, ``(name, start_ns, end_ns, parent_name,
+    step_no, counts)`` goes to the phase ring."""
+    return _Phase(name, counts)
+
+
+def phases(since_ns=None):
+    """``(rows, lost)``: the phase ring oldest first — with `since_ns`
+    only the phases that ended at or after it — and whether the ring
+    has evicted a phase that ended at or after `since_ns` (with None:
+    any phase at all), i.e. whether ``rows`` is missing part of the
+    time asked for."""
+    rows = list(_PHASES)
+    lost_ns = _PHASES_LOST_NS[0]
+    if since_ns is None:
+        return rows, lost_ns is not None
+    return ([r for r in rows if r[2] >= since_ns],
+            lost_ns is not None and lost_ns >= since_ns)
+
+
 # -- summaries ----------------------------------------------------------------
 
 def summary():
@@ -426,7 +505,7 @@ def snapshot_summary(n=3):
 def export_chrome(path=None, include_host_events=True):
     """Merged chrome://tracing JSON: host RecordEvents + trace spans
     (pid = subsystem, flow events linking each multi-span trace_id) +
-    counter samples. Returns the trace dict; writes it when `path` given
+    the step phases. Returns the trace dict; writes it when `path` given
     (tools/timeline.py parity, extended with span identity)."""
     events = []
     pids = {"host": 1}
@@ -476,9 +555,26 @@ def export_chrome(path=None, include_host_events=True):
                 ev["bp"] = "e"
             events.append(ev)
 
-    for ts_ns, name, value in counter_samples():
-        events.append({"name": name, "ph": "C", "pid": pid_of("counters"),
-                       "ts": ts_ns / 1e3, "args": {name: value}})
+    # the step-phase timeline: one process, one track per phase family
+    # (nesting renders from ts/dur ordering, as for the host events)
+    rows, _ = phases()
+    families = {}
+    for name, start_ns, end_ns, parent, step_no, counts in rows:
+        fam = name.split("/", 1)[0]
+        tid = families.setdefault(fam, len(families) + 1)
+        args = {"step_no": step_no}
+        if parent is not None:
+            args["parent"] = parent
+        for k, v in (counts or {}).items():
+            args[k] = _json_safe(v)
+        events.append({"name": name, "ph": "X", "ts": start_ns / 1e3,
+                       "dur": (end_ns - start_ns) / 1e3,
+                       "pid": pid_of("phases"), "tid": tid, "cat": "phase",
+                       "args": args})
+    for fam, tid in families.items():
+        events.append({"name": "thread_name", "ph": "M",
+                       "pid": pids["phases"], "tid": tid,
+                       "args": {"name": fam}})
 
     for name, pid in pids.items():
         events.append({"name": "process_name", "ph": "M", "pid": pid,

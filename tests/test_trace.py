@@ -367,14 +367,18 @@ class TestChromeExport:
         flows = [e for e in doc["traceEvents"] if e.get("cat") == "flow"]
         assert any(e["ph"] == "s" for e in flows)
         assert any(e["ph"] == "f" for e in flows)
-        # counter samples from the step boundary
-        assert any(e["ph"] == "C"
-                   and e["name"] == "serving_batch_occupancy"
-                   for e in doc["traceEvents"])
+        # the step phases are drawn as one more process; the occupancy
+        # the old counter track sampled is the root phase's `active`
+        drawn = [e for e in doc["traceEvents"] if e.get("cat") == "phase"]
+        steps = [e for e in drawn if e["name"] == "serve/step"]
+        assert steps and all("active" in e["args"] for e in steps)
+        assert any(e["name"] == "serve/decode_wait"
+                   and e["args"]["parent"] == "serve/step" for e in drawn)
+        assert len({e["pid"] for e in drawn}) == 1
         # subsystem process naming
         meta = {e["args"]["name"] for e in doc["traceEvents"]
                 if e["ph"] == "M"}
-        assert "serving" in meta
+        assert {"serving", "phases", "serve"} <= meta
 
     def test_old_profiler_export_uses_merged_exporter(self, tmp_path):
         from paddle_tpu import profiler

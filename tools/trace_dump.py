@@ -13,7 +13,7 @@ queue_wait / prefill / decode sharing one trace_id per request — reports
 an error-severity finding and the exit code is 1 (the acceptance
 criterion in executable form). ``--chrome`` additionally writes the
 merged chrome://tracing JSON (host RecordEvents + spans + flow links +
-counter samples; open in chrome://tracing or Perfetto).
+the step phases; open in chrome://tracing or Perfetto).
 
 ``--json`` emits the tools/graph_lint.py report schema ({"tool",
 "passes", "targets": {name: {"name", "counts", "findings"}}, "totals"},
