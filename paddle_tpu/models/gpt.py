@@ -443,6 +443,25 @@ def _cache_map(f, c):
     return tuple(f(x) for x in c) if isinstance(c, tuple) else f(c)
 
 
+def _row_update(cache_i, val, pos_vec):
+    """Row b of `val` [B, KVh, t, hd] lands at its OWN columns pos_vec[b]..
+    of cache layer `cache_i` [B, KVh, T, hd] (continuous-batching serving:
+    slots sit at different sequence positions). A select on the column
+    index: one elementwise pass over the layer in the layout it is stored
+    in. The chip keeps the cache T-minor, where a per-row
+    dynamic_update_slice costs two relayouts of the layer and a serial loop
+    over the rows (docs/SERVING.md "The dense cache on the chip"). It stores
+    the values dynamic_update_slice would, with the same clamp of the start."""
+    import jax.numpy as jnp
+
+    T, t = cache_i.shape[2], val.shape[2]
+    off = jnp.arange(T)[None, :] - jnp.clip(pos_vec, 0, T - t)[:, None]
+    for j in range(t):
+        cache_i = jnp.where((off == j)[:, None, :, None],
+                            val[:, :, j:j + 1], cache_i)
+    return cache_i
+
+
 def _decode_fns(cfg, untied, untied_bias, cache_dtype=None, tp_axis=None,
                 tp_size=1):
     """Pure-jnp decode math shared by sampling and beam search: returns
@@ -468,6 +487,8 @@ def _decode_fns(cfg, untied, untied_bias, cache_dtype=None, tp_axis=None,
     qkv.bias [3, H_loc, hd] (see _tp_param_shard)."""
     import jax
     import jax.numpy as jnp
+
+    from ..ops import kv_store as _kv_store
 
     L, Hh = cfg.num_layers, cfg.num_heads
     hd = cfg.hidden_size // Hh
@@ -504,20 +525,19 @@ def _decode_fns(cfg, untied, untied_bias, cache_dtype=None, tp_axis=None,
         return (vals, scales), (jnp.zeros_like(vals),
                                 jnp.zeros_like(scales))
 
-    def _row_update(cache_i, val, pos_vec):
-        """Row b of `val` [B, KVh, t, hd] lands at its OWN column
-        pos_vec[b] (continuous-batching serving: slots sit at different
-        sequence positions)."""
-        return jax.vmap(lambda row, v, p_: jax.lax.dynamic_update_slice(
-            row, v, (0, p_, 0)))(cache_i, val, pos_vec)
+    def _put(leaf, val, i, pos):
+        """val [B, KVh, t, *] into layer i of a cache leaf at column pos
+        (scalar: the whole batch at one frontier) or pos[b] (per row)."""
+        if jnp.ndim(pos) == 1:
+            if _kv_store.in_place(leaf, val):
+                return _kv_store.store_columns(leaf, val, i, pos)
+            val, pos = _row_update(leaf[i], val, pos), 0
+        return jax.lax.dynamic_update_slice(leaf, val[None],
+                                            (i, 0, 0, pos, 0))
 
     def _store(c, val, i, pos):
-        per_row = jnp.ndim(pos) == 1
         if quant is None:
-            if per_row:
-                return c.at[i].set(_row_update(c[i], val, pos))
-            return jax.lax.dynamic_update_slice(c, val[None],
-                                                (i, 0, 0, pos, 0))
+            return _put(c, val, i, pos)
         qdt, qmax, integer = quant
         vals, scales = c
         s = jnp.maximum(
@@ -526,13 +546,7 @@ def _decode_fns(cfg, untied, untied_bias, cache_dtype=None, tp_axis=None,
         q = val.astype(jnp.float32) / s
         if integer:
             q = jnp.clip(jnp.round(q), -qmax, qmax)
-        q = q.astype(qdt)
-        if per_row:
-            return (vals.at[i].set(_row_update(vals[i], q, pos)),
-                    scales.at[i].set(_row_update(scales[i], s, pos)))
-        return (jax.lax.dynamic_update_slice(vals, q[None], (i, 0, 0, pos, 0)),
-                jax.lax.dynamic_update_slice(scales, s[None],
-                                             (i, 0, 0, pos, 0)))
+        return _put(vals, q.astype(qdt), i, pos), _put(scales, s, i, pos)
 
     def _load(c, i, like):
         if quant is None:

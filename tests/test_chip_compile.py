@@ -172,3 +172,108 @@ def test_nms_4096(chip):
         lambda boxes: nms_pallas.nms_keep_mask_pallas(boxes, 0.5,
                                                       interpret=False),
         chip((4096, 4), jnp.float32)) == 1
+
+
+class TestDecodeStep:
+    """The serving engine's decode steps at gpt2-large's widths (1280 / 20
+    heads / vocabulary 50304; 2 layers, 32 rows, T 1024, bf16, caches
+    donated), compiled for the described chip. The chip keeps a cache leaf
+    T-minor, and a per-step update that slices a layer out of the cache costs
+    relayouts of the layer and a loop over the rows (docs/SERVING.md "The
+    dense cache on the chip"): the compiled step may hold neither, by either
+    form of the store — the in-place kernel a TPU takes (ops/kv_store.py),
+    and the select every other shape and platform takes."""
+
+    LAYERS, ROWS, T = 2, 32, 1024
+
+    @pytest.mark.parametrize("store,kind,cache_dtype", [
+        ("kernel", "greedy", None), ("kernel", "sample", None),
+        ("kernel", "greedy", "int8"), ("select", "greedy", None),
+        ("select", "sample", None), ("select", "greedy", "int8")])
+    def test_no_pass_over_a_cache_layer_but_the_needed(
+            self, chip, monkeypatch, store, kind, cache_dtype):
+        import re
+
+        import paddle_tpu as paddle
+        from paddle_tpu.inference.serving import ServingEngine
+        from paddle_tpu.models import GPTConfig, GPTForCausalLM
+        from paddle_tpu.ops import kv_store
+
+        # this process sees the CPU: steer the store's platform test here
+        # (an engine a case, since jit keeps what it traced)
+        monkeypatch.setattr(kv_store, "on_tpu", lambda: store == "kernel")
+        paddle.seed(0)
+        model = GPTForCausalLM(GPTConfig(
+            vocab_size=50304, hidden_size=1280, num_layers=self.LAYERS,
+            num_heads=20, max_seq_len=self.T, dropout=0.0))
+        model.eval()
+        eng = ServingEngine(model, max_batch=self.ROWS, dtype="bfloat16",
+                            cache_dtype=cache_dtype)
+
+        def on_chip(tree):
+            return jax.tree_util.tree_map(
+                lambda a: chip(a.shape, a.dtype), tree)
+
+        rows = (self.ROWS,)
+        args = [on_chip(eng._params), on_chip(eng._kc), on_chip(eng._vc),
+                chip(rows, jnp.int32), chip(rows, jnp.int32)]
+        step = eng._step_greedy
+        if kind == "sample":
+            step = eng._step_sample
+            args += [chip(rows, jnp.float32), chip(rows, jnp.int32),
+                     chip(rows, jnp.float32), chip(rows, jnp.int32)]
+        compiled = step.lower(*args).compile()
+
+        text = compiled.as_text()
+        entry = re.search(r"^ENTRY .*?^\}", text, re.S | re.M).group(0)
+        # a cache layer, or the whole leaf, in either order of T and hd
+        layer = re.compile(r"\[(\d+,)?32,20,(1024,64|64,1024)\]")
+        results = (re.match(r"\s*(?:ROOT )?\S+ = (.*?) (?:copy|while)\(", line)
+                   for line in entry.splitlines())
+        assert [m.group(0)[:160] for m in results
+                if m and layer.search(m.group(1))] == []
+        # K and V of every layer (an int8 cache's scales take the select)
+        assert text.count("tpu_custom_call") == (
+            2 * self.LAYERS if store == "kernel" else 0)
+
+        def nbytes(tree):
+            return sum(a.size * a.dtype.itemsize
+                       for a in jax.tree_util.tree_leaves(tree))
+
+        weights, cache = nbytes(eng._params), nbytes((eng._kc, eng._vc))
+        accessed = compiled.cost_analysis()["bytes accessed"]
+        # XLA's count: the select reads 1.25 x, the kernel 0.7 x, the store
+        # this replaced 3.7 x. It charges an int8 cache's dequantizing read
+        # (_load, not the store) several times its bytes: 2.2-2.3 x there,
+        # 4.2 x before
+        room = 1.4 if cache_dtype is None else 2.5
+        if kind == "sample":
+            room += 0.2          # two sorts of the [32, 50304] logits
+        assert accessed <= room * (weights + 3 * cache), (
+            accessed / (weights + 3 * cache))
+        mem = compiled.memory_analysis()
+        # (an int8 cache is half the bytes, and its temporaries are the
+        # relaid scales, 21 MB here)
+        assert mem.temp_size_in_bytes < cache / (10 if cache_dtype is None
+                                                 else 5)
+        assert mem.alias_size_in_bytes == cache      # donated, in place
+
+
+@pytest.mark.parametrize("kvh,hd,t_max,dtype", [
+    (5, 64, 1024, BF16),              # gpt2-large's heads over four chips
+    (2, 32, 256, BF16),               # a GQA draft model: 64 lanes of values
+    (12, 64, 384, jnp.float32),
+    (20, 64, 1024, jnp.int8), (8, 128, 512, jnp.float8_e4m3fn),
+], ids=["tp_local_heads", "narrow_gqa", "f32", "int8", "fp8"])
+def test_kv_store_columns(chip, kvh, hd, t_max, dtype):
+    """The in-place store outside gpt2-large's shape: the kernel transposes
+    KVh * hd lanes of new values, whatever their number."""
+    from paddle_tpu.ops import kv_store
+
+    leaf, val = chip((3, 8, kvh, t_max, hd), dtype), chip((8, kvh, 1, hd),
+                                                          dtype)
+    assert kv_store.fits(leaf, val)
+    assert _custom_calls(
+        lambda c, v, pos: kv_store.store_columns(c, v, 1, pos,
+                                                 interpret=False),
+        leaf, val, chip((8,), jnp.int32)) == 1

@@ -902,3 +902,154 @@ def test_combined_serving_knobs_window_gqa_int8():
     assert i8.shape == gen.shape
     agree = (i8[:, 12:] == gen[:, 12:]).mean()
     assert agree > 0.5
+
+
+def _update_slice_a_row(cache_i, val, pos_vec):
+    """The per-row store `_row_update` replaced, kept as these tests' own
+    reference: a vmap of dynamic_update_slice over the rows."""
+    import jax
+
+    return jax.vmap(lambda row, v, p_: jax.lax.dynamic_update_slice(
+        row, v, (0, p_, 0)))(cache_i, val, pos_vec)
+
+
+def _bits(tree):
+    import jax
+    import jax.numpy as jnp
+
+    return [np.asarray(jax.lax.bitcast_convert_type(
+        leaf, {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}[
+            leaf.dtype.itemsize])) for leaf in jax.tree_util.tree_leaves(tree)]
+
+
+class TestPerRowStore:
+    """fwd with per-row positions (the serving engine's decode and verify
+    steps) stores, bit for bit, what the vmap of dynamic_update_slice
+    stored: by the select every platform takes, and by the in-place kernel
+    a TPU takes (ops/kv_store.py), here in interpret mode."""
+
+    T = 128
+
+    def _model(self, kv_heads):
+        paddle.seed(0)
+        m = GPTForCausalLM(GPTConfig(
+            vocab_size=128, hidden_size=128, num_layers=2, num_heads=4,
+            num_kv_heads=kv_heads, max_seq_len=self.T, dropout=0.0))
+        m.eval()
+        return m
+
+    @staticmethod
+    def _take_the_kernel(monkeypatch):
+        """Returns the list the kernel's calls are noted in."""
+        from paddle_tpu.ops import kv_store
+
+        calls, real = [], kv_store.store_columns
+
+        def noted(leaf, *a, **kw):
+            calls.append(leaf.dtype)
+            return real(leaf, *a, **kw)
+
+        # off a TPU the kernel interprets: only the platform test is skipped
+        monkeypatch.setattr(kv_store, "in_place", kv_store.fits)
+        monkeypatch.setattr(kv_store, "store_columns", noted)
+        return calls
+
+    @pytest.mark.parametrize("store,t", [("select", 1), ("select", 3),
+                                         ("kernel", 1)])
+    @pytest.mark.parametrize("kv_heads", [4, 2], ids=["mha", "gqa"])
+    @pytest.mark.parametrize("cache_dtype", [None, "int8", "fp8"],
+                             ids=["bf16", "int8", "fp8"])
+    def test_fwd_stores_what_update_slice_stored(self, monkeypatch,
+                                                 cache_dtype, kv_heads,
+                                                 store, t):
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu.models import gpt
+        from paddle_tpu.ops import kv_store
+
+        model = self._model(kv_heads)
+        _, _, params = gpt._decode_params(model, "the model")
+        params = {n: v.astype(jnp.bfloat16) for n, v in params.items()}
+        fwd, _, cache_init = gpt._decode_fns(model.cfg, False, False,
+                                             cache_dtype=cache_dtype)
+
+        def junk(leaf, n):
+            # a full cache, so a store in the wrong place shows
+            k = jax.random.PRNGKey(n)
+            if leaf.dtype == jnp.int8:
+                return jax.random.randint(k, leaf.shape, -127, 128,
+                                          jnp.int8)
+            return jax.random.uniform(k, leaf.shape, jnp.float32, 0.5,
+                                      1.5).astype(leaf.dtype)
+
+        leaves, tree = jax.tree_util.tree_flatten(
+            cache_init(4, self.T, jnp.bfloat16))
+        kc, vc = jax.tree_util.tree_unflatten(
+            tree, [junk(leaf, n) for n, leaf in enumerate(leaves)])
+        toks = jnp.asarray(np.random.RandomState(3).randint(
+            0, 128, (4, t)).astype(np.int32))
+        pos = jnp.asarray([0, self.T - t, 5, 77], jnp.int32)
+
+        calls = self._take_the_kernel(monkeypatch) \
+            if store == "kernel" else None
+        x, *got = fwd(params, toks, pos, kc, vc)
+        assert calls is None or len(calls) == 4      # K, V of two layers
+        monkeypatch.setattr(gpt, "_row_update", _update_slice_a_row)
+        monkeypatch.setattr(kv_store, "in_place", lambda leaf, val: False)
+        x_ref, *want = fwd(params, toks, pos, kc, vc)
+
+        for g, w in zip(_bits(got), _bits(want)):
+            np.testing.assert_array_equal(g, w)
+        for g, before in zip(_bits(got), _bits((kc, vc))):
+            assert (g != before).any()       # and something was stored
+        np.testing.assert_array_equal(_bits(x)[0], _bits(x_ref)[0])
+
+    @pytest.mark.parametrize("store,t", [("select", 1), ("select", 3),
+                                         ("kernel", 1)])
+    def test_a_start_out_of_range_is_clamped_as_update_slice_clamps(
+            self, store, t):
+        """An idle row's stale position must land where it always did,
+        never outside the cache."""
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu.models import gpt
+        from paddle_tpu.ops import kv_store
+
+        layer = jax.random.normal(jax.random.PRNGKey(0),
+                                  (2, 4, 3, self.T, 16), jnp.float32)
+        val = jax.random.normal(jax.random.PRNGKey(1), (4, 3, t, 16),
+                                jnp.float32)
+        pos = jnp.asarray([self.T - t + 1, self.T + 5, self.T - 1, 0],
+                          jnp.int32)
+        want = layer.at[1].set(_update_slice_a_row(layer[1], val, pos))
+        if store == "kernel":
+            assert kv_store.fits(layer, val)
+            got = kv_store.store_columns(layer, val, 1, pos, interpret=True)
+        else:
+            got = layer.at[1].set(gpt._row_update(layer[1], val, pos))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    @pytest.mark.parametrize("store", ["select", "kernel"])
+    @pytest.mark.parametrize("cache_dtype", [None, "int8", "fp8"],
+                             ids=["f32", "int8", "fp8"])
+    def test_engine_emits_what_generate_emits(self, monkeypatch,
+                                              cache_dtype, store):
+        from paddle_tpu.inference.serving import ServingEngine
+
+        calls = self._take_the_kernel(monkeypatch) \
+            if store == "kernel" else None
+        model = self._model(4)
+        eng = ServingEngine(model, max_batch=3, cache_dtype=cache_dtype)
+        rng = np.random.RandomState(11)
+        prompts = [rng.randint(0, 128, (n,)).astype(np.int32)
+                   for n in (5, 33, 9, 70, 17)]
+        rids = [eng.submit(p, max_new_tokens=10) for p in prompts]
+        res = eng.run_until_complete()
+        for rid, p in zip(rids, prompts):
+            want = np.asarray(model.generate(
+                paddle.to_tensor(p[None]), max_new_tokens=10,
+                temperature=0.0, cache_dtype=cache_dtype)._data)[0, len(p):]
+            np.testing.assert_array_equal(res[rid].tokens, want)
+        assert calls is None or calls
