@@ -471,6 +471,15 @@ class ServingEngine:
                 out_shardings=jax.tree_util.tree_map(
                     lambda s: shard, side_tpl))
 
+        # the width of the tiles a one-token decode step reads each row's
+        # cache in (stats()["kv_tiles_read"] / ["kv_tiles_held"]): the
+        # model's, where its step stops at each row's position, else the
+        # whole row
+        k_side = jax.eval_shape(
+            lambda: cache_init(self.B, self.T, cache_dt))[0]
+        self._kv_tile = dm.kv_read_tile(cfg, k_side, cache_dt,
+                                        tp_size) or self.T
+
         def prefill(p, ids_padded, true_len):
             """ids_padded [1, Pb] right-padded; returns (kc1, vc1,
             last_logits [vocab]). Junk beyond true_len is causally
@@ -806,6 +815,7 @@ class ServingEngine:
                    "spec_accepted": 0,
                    "prefix_hit": 0, "prefix_miss": 0,
                    "occupancy_sum": 0, "occupancy_steps": 0,
+                   "kv_tiles_read": 0, "kv_tiles_held": 0,
                    "queue_wait_ms": _MsSummary(), "ttft_ms": _MsSummary(),
                    "inter_token_ms": _MsSummary()}
         # admissions so far: [requests, prompt tokens] — the serve/step and
@@ -1083,6 +1093,8 @@ class ServingEngine:
             "tokens_generated": m["tokens"],
             "steps": dict(m["steps"]),
             "batch_occupancy_avg": occ,
+            "kv_tiles_read": m["kv_tiles_read"],
+            "kv_tiles_held": m["kv_tiles_held"],
             "prefix_cache": {"hit": m["prefix_hit"],
                              "miss": m["prefix_miss"],
                              "hit_rate": (m["prefix_hit"] / prefix_n
@@ -1719,6 +1731,9 @@ class ServingEngine:
         if slot is not None:
             self._slot_req[slot] = None
             self._prefilling.pop(slot, None)
+            # a free row rides along in every decode step: at column 0 it
+            # costs the step one cache tile, not its last session's
+            self._pos[slot] = 0
             if self._paged:
                 # return the session's frames (shared prefix frames only
                 # deref); no-op for a slot that never reserved
@@ -2046,6 +2061,19 @@ class ServingEngine:
         self._m["occupancy_steps"] += 1
         _OCCUPANCY.set(len(active))
 
+    def _count_kv_tiles(self, disp, one_token=True):
+        """Onto the `serve/decode_dispatch` phase and the running sums: the
+        cache tiles this round's step reads, and the tiles the cache holds.
+        The step walks every row, a free one (position 0) as well. A
+        speculative round's verify (several columns a row) reads all."""
+        held = read = self.B * -(-self.T // self._kv_tile)
+        if one_token:
+            read = int((np.minimum(self._pos, self.T - 1)
+                        // self._kv_tile + 1).sum())
+        disp.counts.update(kv_tiles_read=read, kv_tiles_held=held)
+        self._m["kv_tiles_read"] += read
+        self._m["kv_tiles_held"] += held
+
     def _dispatch_decode(self, active):
         """Enqueue ONE decode program for the active slots (device work
         starts immediately — jax dispatch is asynchronous). Host-side
@@ -2344,6 +2372,7 @@ class ServingEngine:
         with _trace.phase("serve/decode_dispatch") as disp:
             if active:
                 dispatched = self._dispatch_decode(active)
+                self._count_kv_tiles(disp)
         am["dispatch_ms"] += disp.ms
         # ---- overlapped host window: round N+1's admission work runs
         # while round N's decode executes on device. The row copies the
@@ -2417,6 +2446,7 @@ class ServingEngine:
                         self._params_d, self._kc_d, self._vc_d,
                         jnp.asarray(self._last), jnp.asarray(self._pos))
                 next_toks, kind = self._dispatch_decode(active)
+                self._count_kv_tiles(disp)
             with _trace.phase("serve/decode_wait") as wait:
                 next_toks = np.asarray(next_toks)  # lint: allow(step-loop-host-sync)
             self._acc_phase(kind, disp, wait)
@@ -2446,6 +2476,7 @@ class ServingEngine:
             emit, m, self._kc, self._vc = self._verify(
                 self._params, self._kc, self._vc, jnp.asarray(self._last),
                 jnp.asarray(self._pos), props)
+            self._count_kv_tiles(disp, one_token=False)
         with _trace.phase("serve/decode_wait") as wait:
             emit = np.asarray(emit)  # lint: allow(step-loop-host-sync)
             m = np.asarray(m)  # lint: allow(step-loop-host-sync)

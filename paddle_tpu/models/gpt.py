@@ -462,6 +462,23 @@ def _row_update(cache_i, val, pos_vec):
     return cache_i
 
 
+def _live_tile(kc, q, pos, win, key_valid):
+    """The width, in cache columns, of the tiles this step's attention reads
+    each row in, up to the row's position and no further
+    (ops/decode_attention.py), or None where it contracts with all T columns
+    and masks. Chosen from what can be seen: one query a row, each row at
+    its own `pos`, nothing masked but the columns past it, a plain cache
+    side of a shape the kernel takes, a TPU."""
+    import jax.numpy as jnp
+
+    from ..ops import decode_attention
+
+    if (jnp.ndim(pos) == 1 and q.shape[2] == 1 and win is None
+            and key_valid is None and decode_attention.live_only(kc, q)):
+        return decode_attention.LANE
+    return None
+
+
 def _decode_fns(cfg, untied, untied_bias, cache_dtype=None, tp_axis=None,
                 tp_size=1):
     """Pure-jnp decode math shared by sampling and beam search: returns
@@ -488,6 +505,7 @@ def _decode_fns(cfg, untied, untied_bias, cache_dtype=None, tp_axis=None,
     import jax
     import jax.numpy as jnp
 
+    from ..ops import decode_attention as _decode_attention
     from ..ops import kv_store as _kv_store
 
     L, Hh = cfg.num_layers, cfg.num_heads
@@ -622,7 +640,11 @@ def _decode_fns(cfg, untied, untied_bias, cache_dtype=None, tp_axis=None,
         if key_valid is not None:
             self_col = cols == rows                    # keep self: no NaN rows
             mask = mask & (key_valid[:, None, :] | self_col)
-        if g == 1:
+        if _live_tile(kc, q, pos, win, key_valid):
+            # a decode step on the chip: tiles 0..pos[b] // 128 of row b,
+            # and no column beyond them
+            out = _decode_attention.decode_attention(kc, vc, q, i, pos)
+        elif g == 1:
             att = jnp.einsum("bhtd,bhTd->bhtT", q,
                              _load(kc, i, q.dtype)) * scale
             att = jnp.where(mask[:, None], att, -jnp.inf)
@@ -1412,6 +1434,17 @@ class GPTDecodeModel(_decode_model.DecodeModel):
         return _decode_fns(cfg, untied, untied_bias,
                            cache_dtype=cache_dtype, tp_axis=tp_axis,
                            tp_size=tp_size)
+
+    def kv_read_tile(self, cfg, side, dtype, tp_size=1):
+        import jax
+
+        if isinstance(side, tuple):
+            return None
+        rows, hd = side.shape[1], side.shape[4]
+        q = jax.ShapeDtypeStruct((rows, cfg.num_heads // tp_size, 1, hd),
+                                 dtype)
+        return _live_tile(side, q, np.zeros((rows,), np.int32),
+                          getattr(cfg, "attention_window", None), None)
 
     def tp_setup(self, tp_mesh, cfg, params):
         return _tp_setup(tp_mesh, cfg, params)

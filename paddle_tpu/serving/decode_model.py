@@ -41,6 +41,14 @@ The protocol (docs/SERVING.md for the full contract):
 ``cache_spec(cfg)``
     Machine-readable description of the cache pytree (layout string,
     axis names, quantized or not) — the handoff contract in data form.
+``kv_read_tile(cfg, side, dtype, tp_size=1)``
+    Optional: the width, in cache columns, of the tiles a one-token
+    decode step with per-row positions reads each row in, up to the
+    row's position and no further; None (the default) where it reads
+    every column. ``side`` is one cache side as the same ``decode_fns``
+    call's ``cache_init`` makes it (a shard's heads under tensor
+    parallelism), ``dtype`` what the step computes in. The engine's
+    ``kv_tiles_read`` / ``kv_tiles_held`` counts follow it.
 ``lora_init(cfg, n_slots, rank, dtype=None)`` / ``lora_pack(cfg,
   exported, rank)``
     Optional multi-LoRA batched decode (FLAGS_paged_kv engines): the
@@ -99,6 +107,11 @@ class DecodeModel:
         """Default spec: opaque pytree pair, described minimally."""
         return {"kind": "kv_pair", "layout": "adapter-defined",
                 "quantized": None}
+
+    def kv_read_tile(self, cfg, side, dtype, tp_size=1):
+        """The width of the tiles a one-token decode step reads each row
+        of ``side`` in, up to the row's position; None: all of it."""
+        return None
 
     # -- optional (multi-LoRA batched decode, FLAGS_paged_kv engines) ------
     def lora_init(self, cfg, n_slots, rank, dtype=None):

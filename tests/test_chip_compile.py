@@ -182,9 +182,35 @@ class TestDecodeStep:
     relayouts of the layer and a loop over the rows (docs/SERVING.md "The
     dense cache on the chip"): the compiled step may hold neither, by either
     form of the store — the in-place kernel a TPU takes (ops/kv_store.py),
-    and the select every other shape and platform takes."""
+    and the select every other shape and platform takes. On a TPU the
+    attention of a plain cache reads it through a kernel too
+    (ops/decode_attention.py): no operation of XLA's own then takes a cache
+    layer as an operand."""
 
     LAYERS, ROWS, T = 2, 32, 1024
+
+    @staticmethod
+    def _reads_of_a_layer(text, heads):
+        """The fusions, products and convolutions of the compiled step's
+        entry computation with an operand the shape of a cache layer (or
+        of the whole leaf), in either order of T and hd."""
+        import re
+
+        entry = re.search(r"^ENTRY .*?^\}", text, re.S | re.M).group(0)
+        layer = re.compile(r"\[(\d+,)?32,%d,(1024,64|64,1024)\]" % heads)
+        kind = {}
+        for line in entry.splitlines():
+            m = re.match(r"\s*(?:ROOT )?(%\S+) = (\(.*?\)|\S+) ", line)
+            if m:
+                kind[m.group(1)] = m.group(2)
+        found = []
+        for line in entry.splitlines():
+            m = re.match(r"\s*(?:ROOT )?(%\S+) = .*? "
+                         r"(fusion|dot|convolution)\((.*?)\)", line)
+            if m and any(layer.search(kind.get(o.strip(), ""))
+                         for o in m.group(3).split(",")):
+                found.append(line.strip()[:160])
+        return found
 
     @pytest.mark.parametrize("store,kind,cache_dtype", [
         ("kernel", "greedy", None), ("kernel", "sample", None),
@@ -197,11 +223,14 @@ class TestDecodeStep:
         import paddle_tpu as paddle
         from paddle_tpu.inference.serving import ServingEngine
         from paddle_tpu.models import GPTConfig, GPTForCausalLM
-        from paddle_tpu.ops import kv_store
+        from paddle_tpu.ops import decode_attention, kv_store
 
-        # this process sees the CPU: steer the store's platform test here
-        # (an engine a case, since jit keeps what it traced)
+        # this process sees the CPU: steer the platform tests of the store
+        # and of the read here (an engine a case, since jit keeps what it
+        # traced)
         monkeypatch.setattr(kv_store, "on_tpu", lambda: store == "kernel")
+        monkeypatch.setattr(decode_attention, "on_tpu",
+                            lambda: store == "kernel")
         paddle.seed(0)
         model = GPTForCausalLM(GPTConfig(
             vocab_size=50304, hidden_size=1280, num_layers=self.LAYERS,
@@ -232,9 +261,14 @@ class TestDecodeStep:
                    for line in entry.splitlines())
         assert [m.group(0)[:160] for m in results
                 if m and layer.search(m.group(1))] == []
-        # K and V of every layer (an int8 cache's scales take the select)
-        assert text.count("tpu_custom_call") == (
-            2 * self.LAYERS if store == "kernel" else 0)
+        # the store of K and of V of every layer (an int8 cache's scales
+        # take the select) and, over a plain cache, the attention's read
+        calls = {"select": 0, "kernel": 3 if cache_dtype is None else 2}
+        assert text.count("tpu_custom_call") == calls[store] * self.LAYERS
+        if calls[store] == 3:
+            assert self._reads_of_a_layer(text, 20) == []
+        else:       # the einsums: the check above finds what it looks for
+            assert self._reads_of_a_layer(text, 20)
 
         def nbytes(tree):
             return sum(a.size * a.dtype.itemsize
@@ -258,6 +292,55 @@ class TestDecodeStep:
                                                  else 5)
         assert mem.alias_size_in_bytes == cache      # donated, in place
 
+    def test_tensor_parallel_step_over_four_chips(self, topo, monkeypatch):
+        """The engine's greedy step as tensor-parallel serving runs it
+        (shard_map over `mp`, 5 of gpt2-large's 20 heads a chip): each
+        shard stores and reads its heads through the kernels, and nothing
+        else of the compiled step takes a shard's cache layer."""
+        import paddle_tpu as paddle
+        from paddle_tpu.models import GPTConfig, GPTForCausalLM, gpt
+        from paddle_tpu.ops import decode_attention, kv_store
+
+        monkeypatch.setattr(kv_store, "on_tpu", lambda: True)
+        monkeypatch.setattr(decode_attention, "on_tpu", lambda: True)
+        mesh = Mesh(np.array(topo.devices), ("mp",))
+        paddle.seed(0)
+        model = GPTForCausalLM(GPTConfig(
+            vocab_size=50304, hidden_size=1280, num_layers=self.LAYERS,
+            num_heads=20, max_seq_len=self.T, dropout=0.0))
+        model.eval()
+        _, _, params = gpt._decode_params(model, "the model")
+        axis, size, params, specs = gpt._tp_setup(mesh, model.cfg, params)
+        fwd, logits_of, _ = gpt._decode_fns(model.cfg, False, False,
+                                            tp_axis=axis, tp_size=size)
+
+        def step_greedy(p, kc, vc, last_toks, pos_vec):   # the engine's
+            x, kc, vc = fwd(p, last_toks[:, None], pos_vec, kc, vc)
+            logits = logits_of(p, x[:, 0]).astype(jnp.float32)
+            return jnp.argmax(logits, -1).astype(jnp.int32), kc, vc
+
+        cs = P(None, None, "mp", None, None)
+        step = gpt._tp_wrap(step_greedy, mesh, specs, 0, (P(), cs, cs),
+                            in_specs=(specs, cs, cs, P(), P()),
+                            donate=(1, 2))
+
+        def on_chips(shape, dtype, spec):
+            return jax.ShapeDtypeStruct(
+                shape, dtype, sharding=NamedSharding(mesh, spec))
+
+        cache = on_chips((self.LAYERS, self.ROWS, 20, self.T, 64), BF16, cs)
+        rows = on_chips((self.ROWS,), jnp.int32, P())
+        compiled = step.lower(
+            {n: on_chips(v.shape, BF16, specs[n]) for n, v in params.items()},
+            cache, cache, rows, rows).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 3 * self.LAYERS
+        assert self._reads_of_a_layer(text, 5) == []
+        mem = compiled.memory_analysis()
+        shard = 2 * cache.size * 2 // 4              # K and V, a chip
+        assert mem.alias_size_in_bytes == shard
+        assert mem.temp_size_in_bytes < shard / 10
+
 
 @pytest.mark.parametrize("kvh,hd,t_max,dtype", [
     (5, 64, 1024, BF16),              # gpt2-large's heads over four chips
@@ -277,3 +360,26 @@ def test_kv_store_columns(chip, kvh, hd, t_max, dtype):
         lambda c, v, pos: kv_store.store_columns(c, v, 1, pos,
                                                  interpret=False),
         leaf, val, chip((8,), jnp.int32)) == 1
+
+
+@pytest.mark.parametrize("kvh,hd,t_max,dtype", [
+    (20, 64, 1024, BF16),             # gpt2-large
+    (5, 64, 1024, BF16),              # its heads over four chips: 320 lanes
+    (4, 32, 256, BF16),               # a narrow draft model
+    (12, 64, 384, jnp.float32),
+], ids=["gpt2_large", "tp_local_heads", "narrow", "f32"])
+def test_decode_attention(chip, kvh, hd, t_max, dtype):
+    """The decode step's read of the live tiles alone: one kernel, and no
+    copy or relayout of the cache around it."""
+    from paddle_tpu.ops import decode_attention as da
+
+    leaf, q = chip((3, 8, kvh, t_max, hd), dtype), chip((8, kvh, 1, hd),
+                                                        dtype)
+    assert da.fits(leaf, q)
+    text = jax.jit(
+        lambda k, v, q, pos: da.decode_attention(k, v, q, 1, pos,
+                                                 interpret=False)
+    ).lower(leaf, leaf, q, chip((8,), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert f"[3,8,{kvh},{t_max},{hd}]" not in "".join(
+        line for line in text.splitlines() if " copy(" in line)

@@ -87,6 +87,35 @@ def test_a_phase_that_raises_ends_the_script_nonzero(tmp_path):
     assert not any("ok" in ln for ln in lines)
 
 
+def test_a_wrong_served_token_is_no_tie(tmp_path):
+    """On a TPU the engine's tokens may part from generate's at a tie of two
+    logits (the decode step's softmax runs in float32 there, generate's in
+    bf16), never at a token the model's own logits do not bear out; off the
+    chip both run the same einsums and no difference is allowed at all:
+    alter one token of the reference and the script must die naming the
+    gap."""
+    (tmp_path / "sitecustomize.py").write_text(
+        "import numpy as np\n"
+        "import paddle_tpu as paddle\n"
+        "import paddle_tpu.models.gpt as g\n"
+        "real = g.GPTForCausalLM.generate\n"
+        "def altered(self, *a, **k):\n"
+        "    ids = np.asarray(real(self, *a, **k)._data).copy()\n"
+        "    ids[0, -3] = (ids[0, -3] + 7) % 500\n"
+        "    return paddle.to_tensor(ids)\n"
+        "g.GPTForCausalLM.generate = altered\n")
+    rc, lines, err = _run(
+        [SMOKE, "--rehearse"],
+        env={"PYTHONPATH": f"{tmp_path}{os.pathsep}{REPO}"})
+    assert rc != 0
+    assert "differ from model.generate" in err and "logit gap" in err
+    noted = [ln for ln in lines if "logit_gap" in ln]
+    assert noted and noted[0]["first_difference"] == 5
+    assert noted[0]["logit_gap"] > 0.5          # no tie on any platform
+    assert "allowed: 0.0" in err                # both sides ran the einsums
+    assert not any("ok" in ln for ln in lines)
+
+
 def test_multichip_rehearsal_matches_one_chip_and_really_shards():
     rc, lines, err = _run(
         [SMOKE, "--rehearse", "--multichip"],
