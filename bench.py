@@ -123,8 +123,8 @@ _LAST_CACHE_COUNTS = {}
 def _compile_cache_counts():
     """Aggregate compile_cache_total by (event, source) across all sites —
     the per-phase attribution signal: a phase whose heartbeats show only
-    miss_fresh deltas spent its time compiling; one showing hit_disk
-    warmed from FLAGS_jit_cache_dir and its time went to runtime."""
+    miss_fresh deltas spent its time compiling; one showing hit_memory
+    ran warmed programs and its time went to runtime."""
     from paddle_tpu import monitor
 
     out = {}
@@ -143,9 +143,9 @@ def _heartbeat(phase, status="start", **fields):
     FLAGS_monitor_log_path names one) and the flight recorder (where
     FLAGS_blackbox is on): the log's last heartbeat names the phase a run
     died in. Each line carries the compile-cache hit/miss counts by source
-    (memory|disk|fresh) plus the delta since the previous heartbeat, so a
+    (memory|fresh) plus the delta since the previous heartbeat, so a
     phase is attributable to compile vs runtime from the artifact alone."""
-    from paddle_tpu import flags, monitor, trace
+    from paddle_tpu import monitor, trace
 
     counts = _compile_cache_counts()
     delta = {k: v - _LAST_CACHE_COUNTS.get(k, 0)
@@ -161,7 +161,6 @@ def _heartbeat(phase, status="start", **fields):
     monitor.blackbox.note("bench_phase", phase=phase, status=status)
     monitor.log_event("bench_phase", phase=phase, status=status,
                       compile_cache=counts, compile_cache_delta=delta,
-                      jit_cache_dir=flags.get_flag("jit_cache_dir", ""),
                       trace_spans=tsum["spans"], trace_top=tsum["top"],
                       **fields)
 
@@ -753,7 +752,6 @@ def main():
     args = ap.parse_args()
 
     import paddle_tpu as paddle
-    from paddle_tpu import flags
 
     dev = _device()
     if dev["platform"] != "tpu":
@@ -765,13 +763,6 @@ def main():
     print(f"  device: {dev}; jax compile cache: "
           f"{paddle.enable_compile_cache()}", file=sys.stderr)
     _heartbeat("device_init", "done", **dev)
-    # FLAGS_jit_cache_dir (env or set_flags) turns on the framework's own
-    # persistent AOT executable cache as well: every SpmdTrainer/Executor/
-    # ServingEngine compile below loads from it when warm (the aot_warm
-    # tool pre-populates it). Heartbeats carry the hit/miss split.
-    if flags.get_flag("jit_cache_dir", ""):
-        print(f"  AOT executable cache: {flags.get_flag('jit_cache_dir')}",
-              file=sys.stderr)
 
     # goodput accountant (FLAGS_goodput): every leg below opens its own
     # run via _start_leg; the atexit hook finalizes the last one

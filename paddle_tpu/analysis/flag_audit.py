@@ -18,8 +18,8 @@ audits EVERY ``define_flag``/``get_flag`` site in the package at once:
       this since ISSUE 12 — the static form names both sites).
   structural-flag-key-miss: a STRUCTURAL flag (one that changes the
       compiled program or the state layout) whose consumption never
-      reaches an ``_exec_key``/AOT ``extra_key`` expression — toggling
-      it would silently reuse a stale executable.
+      reaches an ``_exec_key`` expression — toggling it would silently
+      reuse a stale executable.
   hot-path-flag-read      : a structural flag re-read inside a per-step
       hot-path function (source_lint.HOT_PATHS) outside the sanctioned
       ``*_active`` cached-one-boolean checkers — construction-consumed
@@ -59,10 +59,14 @@ RULES = {
 }
 
 #: flags whose value changes the compiled program's identity or the
-#: trainer's state layout: each MUST reach an _exec_key / AOT extra_key
-#: expression so a toggle recompiles instead of reusing a stale
-#: executable. Declare new structural flags here (the contract gate
-#: fails until the flag actually joins a key expression).
+#: trainer's state layout: each MUST reach an _exec_key expression (the
+#: key of an in-memory executable store) so a toggle recompiles instead
+#: of reusing a stale executable. Declare new structural flags here (the
+#: contract gate fails until the flag actually joins a key expression).
+#: Not here, because no store of executables outlives their toggle:
+#: paged_kv (each engine builds and holds its own programs, and
+#: _paged_active raises on a post-construction disarm) and
+#: flash_attention_block (a static argument of the kernel's own jit).
 STRUCTURAL_FLAGS = (
     "check_nan_inf",
     "numerics",
@@ -71,17 +75,13 @@ STRUCTURAL_FLAGS = (
     "quantized_allreduce_min_size",
     "shard_weight_update",
     "overlap_grad_comm",
-    "use_bfloat16",
-    "flash_attention_block",
     "mpmd",
-    "paged_kv",
     "elastic",
 )
 
 #: function names whose bodies ARE executable-identity expressions —
-#: anything referenced inside them (or inside an ``extra_key=`` call
-#: keyword) counts as reaching the key
-KEY_FUNCS = ("_exec_key", "_cache_key", "_exec_key_and_example")
+#: anything referenced inside them counts as reaching the key
+KEY_FUNCS = ("_exec_key", "_exec_key_and_example")
 
 _MISSING = object()
 
@@ -138,7 +138,6 @@ class _Scan(ast.NodeVisitor):
         self.defines = []      # (name, lineno, default_literal, help_ok)
         self.reads = []        # (name, lineno, func, in_key, default_lit)
         self.key_refs = set()  # identifiers/strings inside key contexts
-        self.flag_tables = {}  # NAME -> [flag names] (module-level)
         self.carrier_map = {}  # func name -> idents assigned from its call
         self._funcs = []
         self._key_depth = 0
@@ -161,12 +160,6 @@ class _Scan(ast.NodeVisitor):
 
     def _visit_assign(self, node, targets, value):
         if value is not None:
-            # module-level tuple-of-strings flag table (_KEYED_FLAGS)
-            if not self._funcs and isinstance(value, (ast.Tuple, ast.List)) \
-                    and targets and isinstance(targets[0], ast.Name):
-                names = [_literal(el) for el in value.elts]
-                if names and all(isinstance(n, str) for n in names):
-                    self.flag_tables[targets[0].id] = names
             # carrier hop: x, self._y = self._resolve_compress()  — the
             # call's enclosing function already carries the flag; its
             # assignment targets carry it one hop further
@@ -235,9 +228,6 @@ class _Scan(ast.NodeVisitor):
                             "func": self._funcs[-1] if self._funcs
                             else None, "in_key": self._key_depth > 0,
                             "default": _MISSING, "targets": set()})
-        for kw in node.keywords:
-            if kw.arg == "extra_key":
-                self.key_refs |= _refs(kw.value)
         self.generic_visit(node)
 
 
@@ -325,17 +315,6 @@ def audit_inventory(scans, structural=STRUCTURAL_FLAGS, hot_paths=None,
             reads.setdefault(r["name"], []).append((scan, r))
         for fn, targets in scan.carrier_map.items():
             carrier_map.setdefault(fn, set()).update(targets)
-    # flag-name tables (module-level `X_FLAGS = ("a", "b")`) referenced
-    # from a key context count as key-reaching reads of each name — the
-    # aot.py _KEYED_FLAGS loop reads flags with a non-literal name
-    for scan in scans.values():
-        for tname, names in scan.flag_tables.items():
-            if tname in key_refs:
-                for n in names:
-                    reads.setdefault(n, []).append(
-                        (scan, {"name": n, "lineno": 0, "func": None,
-                                "in_key": True, "default": _MISSING,
-                                "targets": set()}))
 
     # hot-path membership is PER FILE: HOT_PATHS keys are paths relative
     # to the paddle_tpu package root while scans carry repo-relative
@@ -452,9 +431,9 @@ def audit_inventory(scans, structural=STRUCTURAL_FLAGS, hot_paths=None,
         if not reached:
             scan, lineno, _, _ = defines[name][0]
             emit("structural-flag-key-miss", scan, lineno,
-                 f"structural FLAGS_{name} never reaches an _exec_key / "
-                 "AOT extra_key expression: toggling it would reuse a "
-                 "stale executable — join it to the key (docs/ANALYSIS.md "
+                 f"structural FLAGS_{name} never reaches an _exec_key "
+                 "expression: toggling it would reuse a stale executable "
+                 "— join it to the key (docs/ANALYSIS.md "
                  "\"Contract auditor\") or remove it from "
                  "STRUCTURAL_FLAGS if it truly cannot change the "
                  "compiled program")

@@ -273,6 +273,10 @@ def branch_collective_mismatch(ctx):
 # partition-spec propagation (implicit replication + resharding churn)
 # ---------------------------------------------------------------------------
 
+#: what a traced jax.jit call is named in a jaxpr: "pjit" in older jax,
+#: "jit" in the installed 0.9.0
+_JIT_PRIMITIVES = ("pjit", "jit")
+
 _UNKNOWN = "unknown"     # no sharding information
 _SHARDED = "sharded"     # derived from sharded data, exact spec unknown
 
@@ -466,7 +470,7 @@ class _SpecFlow:
                            invars[0] if invars else None)
             return
 
-        if p == "pjit":
+        if p in _JIT_PRIMITIVES:
             self._pjit(eqn, here, in_states)
             return
 
@@ -772,7 +776,7 @@ def _donated_of(closed):
     donation-miss pass's ground truth."""
     donated = set()
     for eqn in closed.jaxpr.eqns:
-        if eqn.primitive.name == "pjit":
+        if eqn.primitive.name in _JIT_PRIMITIVES:
             for i, d in enumerate(eqn.params.get("donated_invars", ())):
                 if d:
                     donated.add(i)

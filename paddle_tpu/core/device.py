@@ -37,10 +37,9 @@ def enable_compile_cache():
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set jax already uses it, and no
     directory is set in code; otherwise the cache lives at one fixed path
-    inside the checkout (``<repo>/.jax_cache``). chip_smoke.py, bench.py and
-    the profiling tools share this one function. Not FLAGS_jit_cache_dir —
-    that is the framework's own AOT executable cache (framework/aot.py) and
-    stays off by default."""
+    inside the checkout (``<repo>/.jax_cache``). chip_smoke.py, the
+    benchmark and the profiling tools share this one function: the only
+    place a compile cache that outlives the process is turned on."""
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
         cache_dir = _REPO_CACHE_DIR

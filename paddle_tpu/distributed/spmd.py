@@ -85,8 +85,7 @@ CHECKPOINT_SCHEMA = {
 # module reports under site="trainer" so one snapshot schema covers both
 # train paths
 _COMPILE_MS = _monitor.histogram(
-    "compile_ms", "wall time to obtain an executable (fresh compile, or "
-    "lower+deserialize on an AOT-cache hit)", labelnames=("site",))
+    "compile_ms", "wall time to obtain an executable", labelnames=("site",))
 _STEP_MS = _monitor.histogram(
     "step_latency_ms",
     "Executor.run / train_step wall time (host dispatch; device-complete "
@@ -461,9 +460,9 @@ class SpmdTrainer:
     def _resolve_mpmd(self):
         """Consume FLAGS_mpmd at construction. The data-parallel trainer
         has no stage split — the flag only keys the executables here
-        (exec key + AOT extra_key), so an MPMD-armed process never
-        aliases a cache entry with a plain one; the armed runtime itself
-        lives on PipelineTrainer/DisaggregatedPool."""
+        (exec key), so an MPMD-armed trainer never aliases an executable
+        with a plain one; the armed runtime itself lives on
+        PipelineTrainer/DisaggregatedPool."""
         return bool(_flags.get_flag("mpmd", False))
 
     def _mpmd_active(self):
@@ -483,8 +482,8 @@ class SpmdTrainer:
     # -- elastic training (distributed/elastic.py) -----------------------------
     def _resolve_elastic(self):
         """Consume FLAGS_elastic at construction. Arms resize(mesh) and
-        keys the executables (exec key + AOT extra_key) so an elastic
-        world never aliases a plain cache entry; the supervisor itself
+        keys the executables (exec key) so an elastic world never
+        aliases a plain executable; the supervisor itself
         lives in the manifest-lazy distributed/elastic.py — a plain
         trainer never imports it (tests/test_elastic_gate.py)."""
         return bool(_flags.get_flag("elastic", False))
@@ -1577,12 +1576,12 @@ class SpmdTrainer:
                 self._mpmd_active(), self._elastic_active())
 
     def _aot_compile(self, batch_arrays, lr, rng, force=False):
-        """Build the jitted step for THIS batch signature and obtain its
-        executable — through the persistent AOT cache (framework/aot.py)
-        when FLAGS_jit_cache_dir is set, else the plain lazy jit. Compiled
-        steps are kept per batch signature (a trailing partial batch must
-        not evict or shadow the full-batch executable); batch_arrays may
-        be jax.ShapeDtypeStructs (aot_build: nothing is executed)."""
+        """Build the jitted step for THIS batch signature: the plain lazy
+        jit, or, forced (aot_build, tracing, the perf ledger), its
+        executable compiled now (framework/aot.py). Compiled steps are
+        kept per batch signature (a trailing partial batch must not
+        shadow the full-batch executable); batch_arrays may be
+        jax.ShapeDtypeStructs (aot_build: nothing is executed)."""
         sig = _batch_sig_label(batch_arrays)
         guarded = self._guard_active()
         narmed = self._numerics_active()
@@ -1599,14 +1598,8 @@ class SpmdTrainer:
                 # compile exactly as tracing does: MFU needs the
                 # executable's flops, which a lazy bypass jit never
                 # exposes — same program, so still non-structural
-                site="trainer", force=force or _trace.is_enabled()
-                or self._perf_ledger is not None,
-                extra_key=("trainer", _aot.mesh_fingerprint(self.mesh),
-                           self.dp_axis, self.sharding_stage,
-                           self.accumulate_steps, guarded, narmed,
-                           self._quantized, self._shard_update,
-                           self._qar_bits, self._qar_min_size,
-                           self._overlap_comm, self._mpmd, self._elastic))
+                force=force or _trace.is_enabled()
+                or self._perf_ledger is not None)
         self._compiled_store[self._exec_key(batch_arrays)] = (
             compiled, guarded, narmed, self._quantized)
         self._compiled = compiled  # latest executable (back-compat handle)
@@ -1624,10 +1617,9 @@ class SpmdTrainer:
 
             trainer.aot_build([((8, 128), "int32"), ((8, 128), "int32")])
 
-        With FLAGS_jit_cache_dir set, the executable is loaded from /
-        stored into the persistent cache; without it, the step is still
-        AOT-compiled in memory. Either way the first train_step pays zero
-        compile. Returns where the executable came from (disk|fresh)."""
+        The step is compiled in memory (through jax's persistent cache
+        where paddle.enable_compile_cache() turned it on), so the first
+        train_step pays zero compile. Returns "fresh"."""
         from ..core.generator import default_generator
 
         specs = []
@@ -1947,8 +1939,7 @@ class SpmdTrainer:
         compiled train-step executable itself — forward+backward+update,
         exactly what ran — not an analytic 6·N·tokens formula. None until
         both a step has run and the cost registry holds this batch
-        signature's entry (FLAGS_trace=1, FLAGS_jit_cache_dir, or
-        aot_build() all populate it)."""
+        signature's entry (FLAGS_trace=1 or aot_build() populate it)."""
         # settle deferred guard verdicts first: the skip counters below
         # must reflect every dispatched step (one cheap device_get — by
         # stats() time the steps in question have long completed)
@@ -2113,10 +2104,9 @@ class SpmdTrainer:
         """Elastic topology change in place: drain the in-flight window,
         snapshot the live state at its logical shapes, swap the mesh,
         and re-place everything under the new dp factorization. The next
-        train_step warm-restarts through the AOT disk cache —
-        mesh_fingerprint (already in every key) hashes shape/kind, not
-        device ids, so a replacement slice of the same shape disk-hits
-        while a genuinely different factorization recompiles cleanly.
+        train_step builds the step for the new mesh (the compiled store
+        is cleared; a persistent jax compile cache, where on, is keyed by
+        the program itself).
 
         Requires FLAGS_elastic at construction (the flag is structural);
         localsgd/DGC are rejected — their per-rank replicas/residuals

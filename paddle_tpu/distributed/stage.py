@@ -23,9 +23,7 @@ Three pieces:
   ``collective_bytes_saved_total{op="stage_edge"}`` — wire-vs-logical
   accounting identical to the quantized all-reduce's.
 - :class:`StageProgram` — one pure function + its mesh, compiled through
-  the PR 3 AOT cache with the stage's OWN ``mesh_fingerprint`` (and its
-  name) in the cache key: a warmed ``FLAGS_jit_cache_dir`` disk-hits
-  per stage, per topology.
+  its own ``CachedJit`` (framework/aot.py), labelled with its name.
 - :class:`StageGraph` — the MPMD runner: executes a schedule of
   (stage, thunk) ticks, each under a ``stage_step`` span sharing ONE
   trace_id (a ``stage_graph`` root) and a blackbox progress window, so a
@@ -323,10 +321,8 @@ class StageProgram:
 
     Inputs are committed (replicated, ``P()``) onto the stage's mesh
     before dispatch, so the compiled program belongs to that topology;
-    the AOT cache key joins the stage's ``mesh_fingerprint`` AND its
-    name (via the CachedJit label), giving per-stage disk entries under
-    ``FLAGS_jit_cache_dir`` — two stages with different device counts
-    never share an executable (compile_cache_total{site="stage"}).
+    each stage holds its own CachedJit, labelled with its name — two
+    stages never share an executable (compile_cache_total{site="stage"}).
     """
 
     def __init__(self, name, fn, mesh=None):
@@ -336,8 +332,7 @@ class StageProgram:
         self._sharding = (NamedSharding(mesh, P())
                          if mesh is not None else None)
         self._jit = _aot.cached_jit(
-            fn, site="stage", label=name, record_event="stage/compile",
-            extra_key=("stage", _aot.mesh_fingerprint(mesh)))
+            fn, site="stage", label=name, record_event="stage/compile")
 
     def _commit(self, x):
         if hasattr(x, "shape") and hasattr(x, "dtype"):
@@ -356,9 +351,7 @@ class StageProgram:
     def rebind(self, mesh):
         """Re-pin THIS program to a replacement mesh (the PR 15
         remainder, armed by MpmdPipelineRunner.replace_stage): a fresh
-        CachedJit keyed by the new mesh_fingerprint — which hashes
-        shape/kind, not device ids, so a same-shape replacement slice
-        disk-hits a warmed FLAGS_jit_cache_dir instead of recompiling.
+        CachedJit, so the program is rebuilt for the replacement slice.
         Sibling programs are untouched (their CachedJit objects keep
         their compiled entries)."""
         self.mesh = mesh
@@ -366,8 +359,7 @@ class StageProgram:
                          if mesh is not None else None)
         self._jit = _aot.cached_jit(
             self._fn, site="stage", label=self.name,
-            record_event="stage/compile",
-            extra_key=("stage", _aot.mesh_fingerprint(mesh)))
+            record_event="stage/compile")
         return self
 
 
@@ -618,10 +610,8 @@ class MpmdPipelineRunner:
     def replace_stage(self, k, mesh):
         """Re-bind stage ``k``'s program(s) to a replacement mesh WITHOUT
         recompiling siblings — the MPMD elasticity axis: one stage's
-        slice dies, the other K-1 compiled programs (and their warmed
-        AOT entries) survive untouched. Requires FLAGS_elastic (the
-        structural elastic posture); a same-shape replacement slice
-        disk-hits FLAGS_jit_cache_dir via the mesh fingerprint. Counted
+        slice dies, the other K-1 compiled programs survive untouched.
+        Requires FLAGS_elastic (the structural elastic posture). Counted
         in elastic_resume_total{reason="stage_replace"} and noted on the
         blackbox ring so the recovery is attributable."""
         if not _flags.get_flag("elastic", False):
@@ -670,8 +660,7 @@ class MpmdPipelineRunner:
             out_shardings=(tr.p_shardings, dict(tr.s_shardings)))
         return _aot.cached_jit(
             jit=jitted, site="stage", label="optimizer",
-            record_event="stage/compile",
-            extra_key=("stage", _aot.mesh_fingerprint(tr.mesh)))
+            record_event="stage/compile")
 
     def train_step(self, x_micro, y_micro):
         """One MPMD train step over pre-split [n_micro, mb, ...] batches;

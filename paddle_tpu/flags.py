@@ -103,7 +103,6 @@ define_flag("seed", 0,
             "initial global random seed: seeds the default RNG generator "
             "at process start (core/generator.py); paddle.seed() "
             "overrides it at runtime")
-define_flag("use_bfloat16", True, "prefer bfloat16 matmuls on MXU")
 define_flag("trace_host_sync", "silent",
             "what Tensor._to_host does when a host pull (.numpy()/.item()) "
             "happens inside a jax trace: silent (jax's own tracer error), "
@@ -213,8 +212,7 @@ define_flag("paged_kv", False,
             "batched in the ONE jitted step via a gathered low-rank "
             "delta — no per-adapter programs, no recompiles. Read at "
             "ENGINE CONSTRUCTION — a post-construction toggle under a "
-            "live paged engine raises; the boolean joins the serving AOT "
-            "extra_key so paged executables never alias dense ones. "
+            "live paged engine raises. "
             "Unset, serving/paging.py is never imported (manifest-lazy; "
             "analysis/import_graph.py) and the engine is byte-identical")
 define_flag("blackbox", False,
@@ -240,8 +238,8 @@ define_flag("perf_ledger", False,
             "metric}. DELIBERATELY NON-STRUCTURAL: the ledger only "
             "observes host-side timings and never changes any compiled "
             "program, so it does NOT join the executable keys (armed and "
-            "disarmed runs share AOT cache entries and train "
-            "byte-identically — tests/test_perfledger_gate.py pins it). "
+            "disarmed runs train byte-identically — "
+            "tests/test_perfledger_gate.py pins it). "
             "Unset, the ledger module is never imported and every hook "
             "is one boolean check. Defined here (not in the ledger "
             "module) so trainers can gate on it without importing it")
@@ -274,7 +272,7 @@ define_flag("elastic", False,
             "__qar_residual__ EF residuals onto a DIFFERENT dp/mp "
             "factorization (checkpoint_reshard_total{action}), "
             "SpmdTrainer.resize(mesh) drains and re-places live state "
-            "onto a replacement mesh through the AOT disk cache, "
+            "onto a replacement mesh, "
             "StageProgram.rebind/MpmdPipelineRunner.replace_stage swap "
             "one MPMD stage mesh without recompiling siblings, and "
             "ElasticSupervisor wires CheckpointSaver corrupt-fallback + "
@@ -282,8 +280,8 @@ define_flag("elastic", False,
             "shrunken mesh (elastic_resume_total{reason}). Read at "
             "TRAINER CONSTRUCTION — a post-construction toggle under a "
             "live trainer raises (_elastic_active). STRUCTURAL: the "
-            "boolean joins _exec_key and the AOT extra_key so an "
-            "elastic world never aliases a plain executable. Unset, "
+            "boolean joins _exec_key so an elastic world never aliases "
+            "a plain executable. Unset, "
             "distributed/elastic.py is never imported (manifest-lazy; "
             "analysis/import_graph.py) and training is byte-identical")
 define_flag("goodput", False,

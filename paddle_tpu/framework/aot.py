@@ -1,57 +1,32 @@
-"""Persistent AOT executable cache: compile once per machine, not per process.
+"""Ahead-of-time compilation and compile telemetry for every jit site.
 
-Every jit compile today is paid per-process — the Executor's jit cache
-lives on the Program, SpmdTrainer rebuilds its step on the first
-train_step, ServingEngine re-jits its whole program family on
-construction. On a TPU v5e the GPT-2-small serving family compiles in about
-50 s cold (chip run, PR 22), so a restarted server pays the full XLA
-optimization bill before serving its first token. This module converts that into a
-one-time cost: executables are lowered, compiled ONCE, serialized with
-``jax.experimental.serialize_executable``, and content-addressed on disk;
-every later process (same machine class, same jax) deserializes in
-milliseconds instead of recompiling. Ahead-of-time specialization for
-portability/efficiency is the Tensor Processing Primitives argument
-(arXiv:2104.05755) applied at the executable level instead of the kernel
-level.
+The warm-start entry points — ``Program.aot_compile``,
+``SpmdTrainer.aot_build``, ``ServingEngine.warmup`` — lower a program
+from shape specs and compile it NOW, in memory, so the first live call
+pays no compile. This module is their shared building block:
 
-Cache key: sha256 over the lowered StableHLO text (which already pins the
-program, input avals, shardings, and donation), plus jax version, backend
-platform + platform version, compile-relevant FLAGS (``use_bfloat16``,
-``flash_attention_block``), and per-site extras (mesh topology
-fingerprints, donation tuples, program labels).
+- ``compile_cached(jitted, args, force=)``: not forced, the jit itself
+  comes back untouched (``"bypass"``: it compiles lazily on its first
+  call, exactly as a bare ``jax.jit``); forced, it is lowered and
+  compiled eagerly (``"fresh"``).
+- ``CachedJit`` / ``cached_jit``: a ``jax.jit`` lookalike with
+  ``warm(*specs)`` and an in-process table of executables per call
+  signature. Nothing warmed and FLAGS_trace off: every call goes straight
+  to the wrapped jit.
+- an executable built from specs that rejects a live call
+  (layout/sharding drift) falls back to the plain jit for that signature
+  instead of crashing the caller.
 
-Safety contract:
-
-- ``FLAGS_jit_cache_dir`` unset (the default): NOTHING here runs — call
-  sites get their plain ``jax.jit`` object back untouched; no lowering,
-  no hashing, no disk I/O (tests/test_aot_cache_gate.py pins this).
-- corrupt or stale entries (truncated file, different jax/platform
-  version, undeserializable payload): silently evicted and recompiled —
-  a bad cache file must never crash training or serving.
-- a deserialized executable that rejects its first live call (layout or
-  sharding drift the key missed) falls back to the plain jit for that
-  signature and evicts the entry.
-- writes are single-writer safe for concurrent processes: serialize to a
-  private temp file, ``os.replace`` into place (atomic on POSIX).
-- ``FLAGS_jit_cache_max_bytes`` caps the directory byte size with LRU
-  eviction (mtime recency, bumped on every hit); the newest entry is
-  always kept so one giant executable cannot disable its own cache.
+Nothing here touches the disk. A compile cache that outlives the process
+is jax's own: ``paddle.enable_compile_cache()`` (core/device.py) is the
+one place it is turned on, and an eager compile here reads and feeds it
+like any other (docs/AOT.md has the serve-deploy recipe).
 
 Telemetry (paddle_tpu.monitor): the shared ``compile_cache_total`` family
-carries a ``source`` label — ``memory`` (in-process hit), ``disk``
-(deserialized from this cache), ``fresh`` (real XLA compile) — plus
-``aot_serialize_ms``/``aot_deserialize_ms``/``aot_bytes`` histograms,
-``aot_store_total{site,event}`` and ``aot_evict_total{reason}`` counters.
-
-Warm-start entry points built on this module: ``Program.aot_compile``,
-``SpmdTrainer.aot_build``, ``ServingEngine.warmup``, and the
-``tools/aot_warm.py`` CLI (docs/AOT.md has the serve-deploy recipe).
+carries a ``source`` label — ``memory`` (in-process hit) or ``fresh``
+(an XLA compile was asked for) — beside ``compile_total`` and
+``compile_ms``.
 """
-import os
-import pickle
-import time
-import uuid
-
 import numpy as np
 import jax
 
@@ -64,25 +39,8 @@ from .. import trace as _trace
 from ..monitor import blackbox_lazy as _blackbox  # import-free recorder facade (ISSUE 12)
 from ..profiler import RecordEvent as _RecordEvent
 
-__all__ = ["cache_dir", "enabled", "args_signature", "mesh_fingerprint",
-           "compile_cached", "CachedJit", "cached_jit", "executable_of"]
-
-_flags.define_flag(
-    "jit_cache_dir", "",
-    "persistent AOT executable cache directory shared across processes "
-    "(framework/aot.py); empty = disabled: no lowering, hashing or disk "
-    "I/O on any compile path")
-_flags.define_flag(
-    "jit_cache_max_bytes", 1 << 30,
-    "LRU byte-size cap for FLAGS_jit_cache_dir (oldest entries evicted; "
-    "the newest entry is always kept)")
-
-_FORMAT = 1
-_SUFFIX = ".aotx"
-
-#: flags whose value changes what a trace produces without necessarily
-#: changing the python call signature — part of every cache key
-_KEYED_FLAGS = ("use_bfloat16", "flash_attention_block")
+__all__ = ["args_signature", "mesh_fingerprint", "compile_cached",
+           "CachedJit", "cached_jit", "executable_of"]
 
 # the compile_cache_total/compile_total families are DECLARED by their
 # call sites (static/, distributed/spmd.py) with matching labels; these
@@ -90,55 +48,31 @@ _KEYED_FLAGS = ("use_bfloat16", "flash_attention_block")
 _COMPILE_CACHE = _monitor.counter(
     "compile_cache_total",
     "jit-cache lookups by feed-signature (event: hit|miss; source: "
-    "memory|disk|fresh)", labelnames=("site", "event", "sig", "source"))
+    "memory|fresh)", labelnames=("site", "event", "sig", "source"))
 _COMPILES = _monitor.counter(
-    "compile_total", "fresh XLA compiles (disk/memory cache hits excluded)",
+    "compile_total", "XLA compiles asked for (in-memory hits excluded)",
     labelnames=("site",))
 _COMPILE_MS = _monitor.histogram(
-    "compile_ms", "wall time to obtain an executable (fresh compile, or "
-    "lower+deserialize on an AOT-cache hit)", labelnames=("site",))
-_SER_MS = _monitor.histogram(
-    "aot_serialize_ms", "executable serialize wall time",
-    labelnames=("site",))
-_DES_MS = _monitor.histogram(
-    "aot_deserialize_ms", "executable deserialize wall time",
-    labelnames=("site",))
-_BYTES_BUCKETS = (1 << 10, 1 << 12, 1 << 14, 1 << 16, 1 << 18, 1 << 20,
-                  1 << 22, 1 << 24, 1 << 26, 1 << 28, 1 << 30)
-_AOT_BYTES = _monitor.histogram(
-    "aot_bytes", "serialized executable entry size",
-    labelnames=("site", "event"), buckets=_BYTES_BUCKETS)
-_STORE_TOTAL = _monitor.counter(
-    "aot_store_total", "cache-entry writes by outcome (ok|error); error = "
-    "the executable could not be serialized/written (it still runs, the "
-    "next process just recompiles)", labelnames=("site", "event"))
-_EVICT_TOTAL = _monitor.counter(
-    "aot_evict_total", "cache entries dropped (corrupt|version|lru) and "
-    "executables disabled after rejecting a live call (call; also counts "
-    "in-memory warmed executables with no disk entry)",
-    labelnames=("reason",))
+    "compile_ms", "wall time to obtain an executable", labelnames=("site",))
 
 
 def record_compile(site, sig_label, source):
-    """The ONE compile-cache telemetry mapping every site shares: a disk
-    load is event=hit/source=disk; a memory hit is hit/memory; everything
-    else (fresh compile, or the bypass path's lazy jit that will compile
-    on first call) is miss/fresh and counts in compile_total."""
+    """The ONE compile-cache telemetry mapping every site shares: a
+    memory hit is hit/memory; everything else (an eager compile, or the
+    bypass path's lazy jit that will compile on first call) is miss/fresh
+    and counts in compile_total."""
     if source == "memory":
         if _monitor.is_enabled():
             _COMPILE_CACHE.labels(site=site, event="hit", sig=sig_label,
                                   source="memory").inc()
         return
-    # flight-recorder tag for every non-memory resolution: disk loads and
-    # fresh compiles are exactly the events a stalled run asks about
+    # flight-recorder tag for every non-memory resolution: compiles are
+    # exactly the events a stalled run asks about
     _blackbox.note("compile", site=site, sig=sig_label, source=source)
     if _monitor.is_enabled():
-        _COMPILE_CACHE.labels(
-            site=site, event="hit" if source == "disk" else "miss",
-            sig=sig_label,
-            source="disk" if source == "disk" else "fresh").inc()
-    if source != "disk":
-        _COMPILES.labels(site=site).inc()
+        _COMPILE_CACHE.labels(site=site, event="miss", sig=sig_label,
+                              source="fresh").inc()
+    _COMPILES.labels(site=site).inc()
 
 
 def executable_of(fn):
@@ -148,15 +82,6 @@ def executable_of(fn):
     if isinstance(fn, _GuardedCompiled):
         return fn._compiled
     return None
-
-
-def cache_dir():
-    """The configured cache directory, or '' when the cache is disabled."""
-    return _flags.get_flag("jit_cache_dir", "") or ""
-
-
-def enabled():
-    return bool(cache_dir())
 
 
 def args_signature(args):
@@ -179,9 +104,9 @@ def args_signature(args):
 
 
 def mesh_fingerprint(mesh):
-    """Stable identity of a mesh's topology for cache keys: axis names and
-    sizes, device kinds, device and process counts — an executable
-    compiled for one topology must never be offered to another."""
+    """Stable identity of a mesh's topology: axis names and sizes, device
+    kinds, device and process counts — what the perf ledger keys its
+    baselines on and what a resize or a stage replacement logs."""
     if mesh is None:
         return ("mesh", None)
     devs = list(np.asarray(mesh.devices).ravel())
@@ -193,9 +118,9 @@ def mesh_fingerprint(mesh):
 
 def _canonical_specs(args):
     """Replace array leaves with ShapeDtypeStructs before lowering, so the
-    lowered text (the cache key) is identical however the caller's arrays
-    happen to be placed: a committed single-device array, an uncommitted
-    eager result, and a warmup spec all lower to the same module. Only
+    lowered module is identical however the caller's arrays happen to be
+    placed: a committed single-device array, an uncommitted eager result,
+    and a warmup spec all lower to the same module. Only
     NamedShardings survive (they ARE program semantics — SPMD layouts);
     single-device/positional shardings are placement detail and dropped.
     Non-array leaves (python scalars) pass through and specialize weakly,
@@ -216,178 +141,17 @@ def _canonical_specs(args):
     return jax.tree_util.tree_map(go, args)
 
 
-def _backend():
-    from jax.extend import backend as _jex_backend
-
-    return _jex_backend.get_backend()
-
-
-def _cache_key(lowered, extra_key=()):
-    import hashlib
-
-    be = _backend()
-    h = hashlib.sha256()
-    h.update(lowered.as_text().encode())
-    h.update(jax.__version__.encode())
-    h.update(f"{be.platform}:{be.platform_version}".encode())
-    for name in _KEYED_FLAGS:
-        h.update(f"{name}={_flags.get_flag(name)!r};".encode())
-    for part in extra_key:
-        h.update(repr(part).encode())
-    return h.hexdigest()
-
-
-def _entry_path(key):
-    return os.path.join(cache_dir(), key + _SUFFIX)
-
-
-class _StaleEntry(Exception):
-    """Entry written by a different cache format / jax / platform."""
-
-
-def _evict(path, reason):
-    _EVICT_TOTAL.labels(reason=reason).inc()
-    try:
-        os.remove(path)
-    except OSError:
-        pass
-
-
-def _load_entry(path, site):
-    """Deserialize one cache entry; any failure evicts the file and
-    returns None (silent recompile — never crash on a bad entry)."""
-    try:
-        with open(path, "rb") as f:
-            blob = f.read()
-    except OSError:
-        return None  # plain miss
-    t0 = time.perf_counter()
-    try:
-        # import inside the guard: a jax build without the serializer must
-        # degrade to a silent recompile, not crash the compile path
-        from jax.experimental.serialize_executable import \
-            deserialize_and_load
-
-        entry = pickle.loads(blob)
-        be = _backend()
-        if (not isinstance(entry, dict)
-                or entry.get("format") != _FORMAT
-                or entry.get("jax") != jax.__version__
-                or entry.get("platform") != be.platform
-                or entry.get("platform_version") != be.platform_version):
-            raise _StaleEntry
-        compiled = deserialize_and_load(entry["payload"], entry["in_tree"],
-                                        entry["out_tree"])
-    except Exception as e:
-        _evict(path, "version" if isinstance(e, _StaleEntry) else "corrupt")
-        return None
-    if _monitor.is_enabled():
-        _DES_MS.labels(site=site).observe((time.perf_counter() - t0) * 1e3)
-        _AOT_BYTES.labels(site=site, event="deserialize").observe(len(blob))
-    try:
-        os.utime(path, None)  # LRU recency: a hit is a use
-    except OSError:
-        pass
-    return compiled
-
-
-def _store_entry(key, compiled, site):
-    """Serialize `compiled` into the cache (atomic rename; never raises —
-    a non-serializable executable still runs, the next process just
-    recompiles) and enforce the LRU byte cap. Returns True on success."""
-    d = cache_dir()
-    tmp = None
-    try:
-        from jax.experimental.serialize_executable import serialize
-
-        t0 = time.perf_counter()
-        payload, in_tree, out_tree = serialize(compiled)
-        be = _backend()
-        blob = pickle.dumps(
-            {"format": _FORMAT, "jax": jax.__version__,
-             "platform": be.platform,
-             "platform_version": be.platform_version,
-             "site": site, "key": key, "payload": payload,
-             "in_tree": in_tree, "out_tree": out_tree}, protocol=4)
-        if _monitor.is_enabled():
-            _SER_MS.labels(site=site).observe(
-                (time.perf_counter() - t0) * 1e3)
-            _AOT_BYTES.labels(site=site, event="serialize").observe(
-                len(blob))
-        os.makedirs(d, exist_ok=True)
-        tmp = os.path.join(
-            d, f".tmp-{key[:16]}-{os.getpid()}-{uuid.uuid4().hex[:8]}")
-        with open(tmp, "wb") as f:
-            f.write(blob)
-        os.replace(tmp, _entry_path(key))  # atomic: concurrent writers race
-        tmp = None                         # benignly (same content per key)
-        _STORE_TOTAL.labels(site=site, event="ok").inc()
-        _enforce_lru(d)
-        return True
-    except Exception:
-        _STORE_TOTAL.labels(site=site, event="error").inc()
-        if tmp is not None:
-            try:
-                os.remove(tmp)
-            except OSError:
-                pass
-        return False
-
-
-def _enforce_lru(d):
-    """Evict oldest entries (mtime) until the directory fits the byte cap.
-    The newest entry always survives — one oversized executable must not
-    evict itself into a cache that can never hit."""
-    cap = int(_flags.get_flag("jit_cache_max_bytes", 1 << 30))
-    entries = []
-    try:
-        names = os.listdir(d)
-    except OSError:
-        return
-    now = time.time()
-    for name in names:
-        p = os.path.join(d, name)
-        if name.startswith(".tmp-"):
-            # orphan from a crashed writer (killed between write and
-            # rename): sweep once safely aged past any live write
-            try:
-                if now - os.stat(p).st_mtime > 3600:
-                    os.remove(p)
-            except OSError:
-                pass
-            continue
-        if not name.endswith(_SUFFIX):
-            continue
-        try:
-            st = os.stat(p)
-        except OSError:
-            continue
-        entries.append((st.st_mtime, st.st_size, p))
-    total = sum(size for _, size, _ in entries)
-    entries.sort()
-    for _, size, p in entries[:-1]:  # keep the newest no matter what
-        if total <= cap:
-            break
-        try:
-            os.remove(p)
-        except OSError:
-            continue
-        total -= size
-        _EVICT_TOTAL.labels(reason="lru").inc()
-
-
 class _GuardedCompiled:
-    """A cache-loaded (or spec-warmed) executable with a recompile escape
-    hatch: if it rejects a live call — layout/sharding drift the key
-    missed, machine-feature mismatch — evict the entry and hand the
-    signature back to the plain jit instead of crashing the caller."""
+    """An eagerly compiled executable with a recompile escape hatch: if
+    it rejects a live call — it was built from specs, and the arrays'
+    layout or sharding drifted from them — hand the signature back to
+    the plain jit instead of crashing the caller."""
 
-    __slots__ = ("_compiled", "_jit", "_path")
+    __slots__ = ("_compiled", "_jit")
 
-    def __init__(self, compiled, jitted, path=None):
+    def __init__(self, compiled, jitted):
         self._compiled = compiled
         self._jit = jitted
-        self._path = path
 
     def __call__(self, *args):
         compiled = self._compiled
@@ -398,22 +162,18 @@ class _GuardedCompiled:
         except (TypeError, ValueError):
             # pre-execution REJECTION only (signature/pytree/sharding
             # mismatch — raised before donation consumes any buffer):
-            # drop the entry and fall back to the plain jit. Runtime
-            # failures (XlaRuntimeError, OOM) propagate — retrying them
-            # with already-donated inputs would destroy live state and
-            # mask the real error.
+            # fall back to the plain jit for good. Runtime failures
+            # (XlaRuntimeError, OOM) propagate — retrying them with
+            # already-donated inputs would destroy live state and mask
+            # the real error.
             self._compiled = None
-            if self._path is not None:
-                _evict(self._path, "call")
-            else:
-                _EVICT_TOTAL.labels(reason="call").inc()
             return self._jit(*args)
 
 
 def _goodput_compile():
     """`compile` wall-time attribution (FLAGS_goodput, ISSUE 20): a null
     context unless the goodput accountant is armed. Booked at THE
-    compile chokepoint, so trainer AOT misses, serving warmups, and
+    compile chokepoint, so trainer warm starts, serving warmups, and
     elastic resize warm-restarts all attribute — nested inside the
     trainer's `step` bucket, the compile time pauses it (exclusive
     buckets). One flag read per compile; the disarmed path never imports
@@ -427,64 +187,39 @@ def _goodput_compile():
     return _goodput.bucket("compile")
 
 
-def compile_cached(jitted, example_args, *, site, extra_key=(),
-                   force=False):
-    """Obtain an executable for ``jitted`` at ``example_args`` (real
-    arrays, or jax.ShapeDtypeStructs for data-free warmup), through the
-    on-disk cache when enabled.
+def compile_cached(jitted, example_args, *, force=False):
+    """Obtain a callable for ``jitted`` at ``example_args`` (real arrays,
+    or jax.ShapeDtypeStructs for data-free warmup).
 
     Returns ``(callable, source)``:
 
-    - ``("bypass")`` — FLAGS_jit_cache_dir unset: ``jitted`` itself is
-      returned untouched (no lowering, no disk I/O; jit compiles lazily
-      on first call exactly as before). ``force=True`` — the warm-start
-      APIs — compiles eagerly in memory instead, so warmup works without
-      a cache dir (source ``fresh``, nothing written);
-    - ``("disk")`` — deserialized from the cache;
-    - ``("fresh")`` — lowered and compiled now, then serialized into the
-      cache (best effort).
-
-    Both non-bypass results are wrapped in a call-failure guard: an
-    executable that rejects a live call (pytree/layout/sharding drift the
-    key missed) falls back to the plain jit for good instead of crashing.
+    - ``("bypass")`` — not forced: ``jitted`` itself, untouched (no
+      lowering; jit compiles lazily on its first call);
+    - ``("fresh")`` — ``force=True``, the warm-start APIs: lowered and
+      compiled now, wrapped in a call-failure guard (an executable that
+      rejects a live call falls back to the plain jit for good).
     """
-    if not enabled():
-        if not force:
-            return jitted, "bypass"
-        # the progress window brackets every eager XLA compile: a hung
-        # compile leaves an ACTIVE, non-advancing aot/compile beacon for
-        # the stall sentinel to name (monitor/blackbox.py)
-        with _goodput_compile(), _blackbox.progress("aot/compile"):
-            compiled = jitted.lower(
-                *_canonical_specs(example_args)).compile()
-        return _GuardedCompiled(compiled, jitted), "fresh"
+    if not force:
+        return jitted, "bypass"
+    # the progress window brackets every eager XLA compile: a hung
+    # compile leaves an ACTIVE, non-advancing aot/compile beacon for
+    # the stall sentinel to name (monitor/blackbox.py)
     with _goodput_compile(), _blackbox.progress("aot/compile"):
-        lowered = jitted.lower(*_canonical_specs(example_args))
-        key = _cache_key(lowered, extra_key)
-        compiled = _load_entry(_entry_path(key), site)
-        if compiled is not None:
-            return _GuardedCompiled(compiled, jitted,
-                                    _entry_path(key)), "disk"
-        compiled = lowered.compile()
-        stored = _store_entry(key, compiled, site)
-    # the guard knows the entry path so a call-rejected executable also
-    # removes its own just-written file (a later process must not
-    # deserialize a binary this one already proved uncallable)
-    return _GuardedCompiled(compiled, jitted,
-                            _entry_path(key) if stored else None), "fresh"
+        compiled = jitted.lower(*_canonical_specs(example_args)).compile()
+    return _GuardedCompiled(compiled, jitted), "fresh"
 
 
 class CachedJit:
-    """A ``jax.jit`` lookalike whose compilations go through the
-    persistent cache: per call-signature, lower once, load-or-compile
-    from disk, keep the executable in an in-process map. With
-    FLAGS_jit_cache_dir unset and nothing warmed, every call delegates
-    straight to the wrapped jit after one empty-dict + flag check —
-    behavior and cost identical to plain jit (the tier-1 gate pins it).
-    Once warmed/enabled, each call pays a python-level signature flatten
-    over the arg pytrees (~µs for a params+KV-cache tree) — well under
-    1% of a ms-scale decode step, but measurable; a latency-critical
-    caller that truly has one static signature can hold the plain jit.
+    """A ``jax.jit`` lookalike that can be compiled ahead of time: per
+    call-signature, lower once, compile, keep the executable in an
+    in-process map. With nothing warmed and FLAGS_trace off, every call
+    delegates straight to the wrapped jit after one empty-dict + flag
+    check — behavior and cost identical to plain jit (the tier-1 gate
+    pins it). Once warmed, each call pays a python-level signature
+    flatten over the arg pytrees (~µs for a params+KV-cache tree) — well
+    under 1% of a ms-scale decode step, but measurable; a
+    latency-critical caller that truly has one static signature can hold
+    the plain jit.
 
     ``warm(*specs)`` AOT-compiles one signature from
     ``jax.ShapeDtypeStruct`` specs (plus plain python scalars for
@@ -493,8 +228,7 @@ class CachedJit:
     """
 
     def __init__(self, fn=None, *, site, jit=None, label=None,
-                 donate_argnums=(), sig_label=None, record_event=None,
-                 extra_key=()):
+                 donate_argnums=(), sig_label=None, record_event=None):
         if jit is None:
             jit = jax.jit(fn, donate_argnums=donate_argnums)
         self._jit = jit
@@ -502,7 +236,6 @@ class CachedJit:
         self._label = label or getattr(fn, "__name__", "jit")
         self._sig_label = sig_label  # callable(args) -> str, or None
         self._record_event = record_event or f"{site}/compile"
-        self._extra_key = tuple(extra_key) + (self._label,)
         self._store = {}
         self._cost_entries = {}   # sig -> trace.costs entry (exact per
         #                           signature: bucketed families differ)
@@ -523,17 +256,14 @@ class CachedJit:
     def _compile(self, sig, args):
         with _RecordEvent(self._record_event), \
                 _monitor.timed(_COMPILE_MS.labels(site=self._site)):
-            # force: warm() without a cache dir still AOT-compiles in
-            # memory (a warmed signature must never retrace at call time)
-            compiled, source = compile_cached(
-                self._jit, args, site=self._site,
-                extra_key=self._extra_key, force=True)
+            # a warmed (or traced) signature must never retrace at call
+            # time: always the eager compile
+            compiled, source = compile_cached(self._jit, args, force=True)
         record_compile(self._site, self._label_of(args), source)
-        # device cost registry: every executable this wrapper obtains —
-        # fresh, warmed, or an AOT-cache deserialize hit — lands its
-        # cost_analysis()/memory_analysis() under (site, program label);
-        # the exact per-signature entry is also kept so executions of a
-        # bucketed family account each bucket's own flops
+        # device cost registry: every executable this wrapper obtains
+        # lands its cost_analysis()/memory_analysis() under (site,
+        # program label); the exact per-signature entry is also kept so
+        # executions of a bucketed family account each bucket's own flops
         entry = _costs.record(self._site, self._label_of(args),
                               executable_of(compiled))
         if entry is not None:
@@ -543,8 +273,8 @@ class CachedJit:
 
     def warm(self, *specs):
         """Compile one signature ahead of time from shape specs. Returns
-        True if a compile (or disk load) happened, False if that
-        signature was already warm."""
+        True if a compile happened, False if that signature was already
+        warm."""
         sig = args_signature(specs)
         if sig in self._store:
             return False
@@ -553,15 +283,15 @@ class CachedJit:
 
     def __call__(self, *args):
         store = self._store
-        if not store and not enabled() and not _trace.is_enabled():
+        if not store and not _trace.is_enabled():
             return self._jit(*args)
         sig = args_signature(args)
         compiled = store.get(sig)
         if compiled is None:
-            if not enabled() and not _trace.is_enabled():
+            if not _trace.is_enabled():
                 return self._jit(*args)  # warmed, but not for this sig
-            # FLAGS_trace forces eager AOT (in memory when no cache dir)
-            # so the cost registry sees an executable for every program
+            # FLAGS_trace forces the eager compile so the cost registry
+            # sees an executable for every program
             compiled = self._compile(sig, args)
         else:
             record_compile(self._site, self._label_of(args), "memory")
@@ -574,7 +304,7 @@ class CachedJit:
     def executed(self):
         """THIS wrapper's execution accounting: {"calls", "flops"} summed
         over every signature it dispatched (per-bucket exact). Empty
-        until cost entries exist (FLAGS_trace / cache dir / warm())."""
+        until cost entries exist (FLAGS_trace / warm())."""
         return {"calls": self._exec_calls, "flops": self._exec_flops}
 
 

@@ -161,9 +161,8 @@ class Predictor:
                     raise
                 self._aot = None
         if self._compiled is None:
-            # one wrapper, one per-shape executable map inside; compiles
-            # go through the persistent AOT cache when
-            # FLAGS_jit_cache_dir is set (framework/aot.py)
+            # one wrapper, one per-shape executable map inside
+            # (framework/aot.py)
             self._compiled = _aot.cached_jit(
                 self._pure_fn(), site="predictor", label="predictor_run")
         out = self._compiled(*[jnp.asarray(a) for a in arrs])

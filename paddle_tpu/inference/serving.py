@@ -320,9 +320,8 @@ class ServingEngine:
             raise ValueError(f"max_queue must be >= 1, got {max_queue}")
         self._max_queue = None if max_queue is None else int(max_queue)
         # paged KV + batched multi-LoRA serving (FLAGS_paged_kv, ISSUE 18).
-        # STRUCTURAL and construction-consumed: the boolean read here joins
-        # the AOT extra_key below (paged executables never alias dense
-        # ones), and _paged_active() raises on a post-construction disarm.
+        # STRUCTURAL and construction-consumed: _paged_active() raises on a
+        # post-construction disarm.
         # Armed, the dense [max_batch, max_seq] cache is replaced by a
         # physical block pool + per-slot block tables (serving/paging.py)
         # with whole-budget reservation at admission, refcounted prefix
@@ -618,18 +617,13 @@ class ServingEngine:
                 return (_pick(logits, temps, kvec, pvec, seeds, pos_vec),
                         kp, vp)
 
-        # every program in the family goes through the persistent AOT
-        # compile cache (framework/aot.py): with FLAGS_jit_cache_dir set,
-        # a fresh server process deserializes executables instead of
-        # re-jitting the whole family; warmup() compiles them from shape
-        # specs before traffic. Flag unset = plain jax.jit behavior.
-        _mesh_fp = _aot.mesh_fingerprint(tp_mesh)
-
+        # every program in the family is a CachedJit (framework/aot.py):
+        # warmup() compiles them from shape specs before traffic; never
+        # warmed = plain jax.jit behavior.
         def _cj(fn=None, label=None, jit=None, donate=()):
             return _aot.cached_jit(fn, jit=jit, site="serving", label=label,
                                    donate_argnums=donate,
-                                   record_event="serving/compile",
-                                   extra_key=(_mesh_fp, _paged))
+                                   record_event="serving/compile")
 
         # donate the big cache through admit/step: XLA aliases it in place
         # instead of copying GBs of K/V per token (the loop this engine
@@ -943,10 +937,10 @@ class ServingEngine:
     def warmup(self, batch_shapes=None, sampling=True):
         """Compile the engine's whole jitted program family BEFORE traffic,
         from shape specs only — no real prompts, nothing executed, the KV
-        cache untouched. With FLAGS_jit_cache_dir set the executables load
-        from (or persist into) the on-disk AOT cache, so a fresh server
-        process performs zero XLA compiles; without the flag the programs
-        are still AOT-compiled in memory (submit/step then pay none).
+        cache untouched: the programs are compiled in memory, so
+        submit/step then pay no compile. With jax's persistent cache on
+        (paddle.enable_compile_cache()) a fresh server process finds
+        every one of them there and compiles nothing.
 
         batch_shapes: iterable of prompt lengths to warm prefill buckets
         for (bucketed exactly like submit(); default: every configured
@@ -1167,9 +1161,9 @@ class ServingEngine:
         per-signature accounting — a bucketed prefill family weights
         every bucket's flops, and a second engine in the process cannot
         bleed into this one's numbers). flops fields appear once the
-        program family has executables captured — FLAGS_trace=1,
-        FLAGS_jit_cache_dir, or warmup() all populate them; without them
-        the wall-time split still stands on its own."""
+        program family has executables captured — FLAGS_trace=1 or
+        warmup() populate them; without them the wall-time split still
+        stands on its own."""
         total_ms = sum(st[1] for st in self._m["step_ms"].values())
         kinds = {}
         flops_total = 0.0
@@ -2311,10 +2305,8 @@ class ServingEngine:
     def _paged_active(self):
         """Construction-consumed FLAGS_paged_kv vs the live flag: a
         post-construction disarm under a live paged engine raises (there
-        is no dense cache to fall back to; the cached boolean also joins
-        the AOT extra_key, so a rebuilt engine recompiles rather than
-        aliasing paged executables). Dense engines short-circuit — they
-        never read the flag per step."""
+        is no dense cache to fall back to). Dense engines short-circuit —
+        they never read the flag per step."""
         if self._paged and not _flags.get_flag("paged_kv", False):
             raise RuntimeError(
                 "FLAGS_paged_kv was disarmed under a live paged engine — "

@@ -1,21 +1,19 @@
 """paddle_tpu.jit.aot — user-facing façade over framework/aot.py.
 
-The persistent AOT executable cache lives in ``paddle_tpu.framework.aot``
-(next to the other process-level framework services); this module is the
+Ahead-of-time compilation lives in ``paddle_tpu.framework.aot`` (next to
+the other process-level framework services); this module is the
 jit-namespace surface users reach for::
 
     from paddle_tpu.jit import aot
 
-    paddle.set_flags({"jit_cache_dir": "/var/cache/paddle_tpu_aot"})
-    step = aot.cached_jit(fn, site="user")      # jit + disk-backed compile
+    paddle.enable_compile_cache()               # jax's persistent cache
+    step = aot.cached_jit(fn, site="user")      # jit + warm()
     step.warm(jax.ShapeDtypeStruct((8, 128), "int32"))   # data-free AOT
 
-See docs/AOT.md for the cache-key contents, invalidation rules, and the
-serve-deploy recipe (tools/aot_warm.py -> start engine).
+See docs/AOT.md for the serve-deploy recipe.
 """
 from ..framework.aot import (CachedJit, args_signature,  # noqa: F401
-                             cache_dir, cached_jit, compile_cached,
-                             enabled, mesh_fingerprint)
+                             cached_jit, compile_cached, mesh_fingerprint)
 
-__all__ = ["CachedJit", "cached_jit", "compile_cached", "cache_dir",
-           "enabled", "args_signature", "mesh_fingerprint"]
+__all__ = ["CachedJit", "cached_jit", "compile_cached", "args_signature",
+           "mesh_fingerprint"]

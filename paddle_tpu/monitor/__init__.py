@@ -22,10 +22,9 @@ Instrumented hot paths (each records into the DEFAULT registry):
 - ``distributed.collective.*`` — call count + payload bytes by HLO
   family (analysis/collectives.py naming);
 - ``framework.io.save/load`` — checkpoint count, wall time, bytes;
-- ``framework.aot`` — the persistent AOT compile cache: the shared
-  ``compile_cache_total`` family carries a ``source=memory|disk|fresh``
-  label, plus serialize/deserialize latency + entry-size histograms and
-  store/evict counters (docs/AOT.md).
+- ``framework.aot`` — every jit site's compile telemetry: the shared
+  ``compile_cache_total`` family carries a ``source=memory|fresh``
+  label, beside ``compile_total`` and ``compile_ms`` (docs/AOT.md).
 
 Three exporters, one schema (docs/OBSERVABILITY.md):
 ``snapshot()`` JSON dict -> ``to_json`` / ``to_prometheus`` text /

@@ -468,7 +468,7 @@ def _registry_collectives():
 
 
 def _registry_compile():
-    """compile_cache_total by source (memory|disk|fresh) + the compile
+    """compile_cache_total by source (memory|fresh) + the compile
     wall-ms digest when those families exist."""
     from .. import monitor as _monitor
 
@@ -481,13 +481,12 @@ def _registry_compile():
             lab = ",".join(f"{k}={v}" for k, v in sorted(s.labels.items()))
             srcs[lab or "total"] = s.value
         out["cache"] = srcs
-    for fam in ("compile_ms", "aot_deserialize_ms"):
-        met = reg.get(fam)
-        if met is not None and met.kind == "histogram":
-            try:
-                out[fam] = _agg_summary(met)
-            except Exception:
-                pass
+    met = reg.get("compile_ms")
+    if met is not None and met.kind == "histogram":
+        try:
+            out["compile_ms"] = _agg_summary(met)
+        except Exception:
+            pass
     return out
 
 

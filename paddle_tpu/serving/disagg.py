@@ -116,12 +116,11 @@ class PrefillWorker:
                 x, true_len - 1, 1, axis=1)[:, 0]
             return kc1, vc1, logits_of(p, x_last).astype(jnp.float32)[0]
 
-        # the same AOT-cache site/label family as the engine's prefill, so
-        # a warmed disk cache serves both sides of the split
+        # the same site/label family as the engine's prefill: one
+        # telemetry series for both sides of the split
         self._prefill = _aot.cached_jit(
             prefill, site="serving", label="prefill",
-            record_event="serving/compile",
-            extra_key=(_aot.mesh_fingerprint(None),))
+            record_event="serving/compile")
         self._m = {"prefills": 0, "prefill_ms": 0.0}
 
     def _bucket(self, n):

@@ -42,8 +42,7 @@ _STATIC_MODE = [False]
 # framework/aot.py's record_compile — one mapping for every site; this
 # module reports under site="executor" with the feed-signature label
 _COMPILE_MS = _monitor.histogram(
-    "compile_ms", "wall time to obtain an executable (fresh compile, or "
-    "lower+deserialize on an AOT-cache hit)", labelnames=("site",))
+    "compile_ms", "wall time to obtain an executable", labelnames=("site",))
 _STEP_MS = _monitor.histogram(
     "step_latency_ms",
     "Executor.run / train_step wall time (host dispatch; device-complete "
@@ -312,8 +311,7 @@ class Program:
     def aot_compile(self, feed_specs, fetch_list=None):
         """Warm-start: compile the EXACT executable Executor.run would jit
         for this feed signature — from shape specs, no real batch — and
-        park it in the program's jit cache (plus the on-disk AOT cache
-        when FLAGS_jit_cache_dir is set).
+        park it in the program's jit cache.
 
             prog.aot_compile({"x": ((8, 13), "float32"),
                               "y": ((8, 1), "float32")},
@@ -324,8 +322,7 @@ class Program:
         last recorded op's outputs — pass the same fetch_list the serving
         run will use, since the cache key includes the fetch set. A
         program with an optimizer attached compiles the TRAIN step.
-        Works without the disk flag too (in-memory AOT). Returns where
-        the executable came from: "memory"|"disk"|"fresh"."""
+        Returns where the executable came from: "memory"|"fresh"."""
         feed = {}
         for name in sorted(feed_specs):
             spec = feed_specs[name]
@@ -658,18 +655,14 @@ class Executor:
 
     def _compile(self, program, feed_names, fetch_ids, train, example_args,
                  force=False):
-        """jit the pure replay; with FLAGS_jit_cache_dir set, compile it
-        eagerly through the persistent executable cache (framework/aot.py).
-        Returns (callable, source: bypass|disk|fresh); `example_args` may
-        mix live arrays and jax.ShapeDtypeStructs. force=True (aot_compile)
-        compiles eagerly even without a cache dir — warm-start must never
-        hand back a lazy jit."""
+        """jit the pure replay. Returns (callable, source: bypass|fresh);
+        `example_args` may mix live arrays and jax.ShapeDtypeStructs.
+        force=True (aot_compile, tracing) compiles eagerly — warm-start
+        must never hand back a lazy jit (framework/aot.py)."""
         _failpoints.failpoint("exe/compile")
         jitted = jax.jit(_build_program_fn(program, feed_names, fetch_ids,
                                            train))
-        return _aot.compile_cached(jitted, example_args, site="executor",
-                                   extra_key=("executor", train),
-                                   force=force)
+        return _aot.compile_cached(jitted, example_args, force=force)
 
 
 def _build_program_fn(program, feed_names, fetch_ids, train):

@@ -1010,15 +1010,6 @@ class TestFlagAudit:
         fs = _flag_findings({"m.py": src}, structural=("structural_ok",))
         assert fs == []
 
-    def test_structural_flag_via_extra_key_is_clean(self):
-        src = ('define_flag("structural_ek", False, "h")\n'
-               'def consume(self):\n'
-               '    self._ek = get_flag("structural_ek", False)\n'
-               'def compile(self):\n'
-               '    c = compile_cached(f, extra_key=("t", self._ek))\n')
-        fs = _flag_findings({"m.py": src}, structural=("structural_ek",))
-        assert fs == []
-
     def test_structural_flag_via_carrier_hop_is_clean(self):
         # the spmd.py shape: _resolve() consumes the flag, its result is
         # assigned to self._q, and self._q joins the key

@@ -14,7 +14,6 @@ import json
 import os
 import subprocess
 import sys
-import time
 
 import jax
 import numpy as np
@@ -152,27 +151,6 @@ class TestInertByDefault:
         assert len(tr._compiled_store) == 1
         assert tr._pending_verdicts == []   # no guard, nothing pending
         assert tr._verdict_fetches == 0
-
-    def test_disarmed_flag_checks_under_5us(self):
-        """The flag-unset per-step additions — _async_active and the
-        tpp_kernels get_flag — are one registry lookup each, bounded at
-        the same bar as every other disabled fast path."""
-        from paddle_tpu import nn
-
-        paddle.seed(0)
-        net = nn.Linear(4, 2)
-        opt = paddle.optimizer.SGD(learning_rate=0.1,
-                                   parameters=net.parameters())
-        mesh = build_mesh((1,), ("dp",), devices=jax.devices()[:1])
-        tr = SpmdTrainer(net, opt, loss_fn=nn.MSELoss(), mesh=mesh)
-        n = 100_000
-        t0 = time.perf_counter()
-        for _ in range(n):
-            tr._async_active()
-            flags.get_flag("tpp_kernels", False)
-        per_call_us = (time.perf_counter() - t0) / (2 * n) * 1e6
-        assert per_call_us < 5.0, (
-            f"disarmed async/tpp flag check costs {per_call_us:.2f}us")
 
     def test_flags_defined_with_defaults(self):
         assert flags.get_flag("async_dispatch") is False

@@ -10,7 +10,6 @@ import json
 import os
 import sys
 import threading
-import time
 
 import numpy as np
 import pytest
@@ -47,25 +46,6 @@ def _tiny_model():
 
 
 class TestInertByDefault:
-    def test_disabled_beacon_under_5us(self):
-        """Same bar and method as the monitor/failpoint/trace gates: a
-        disabled beacon is one boolean check."""
-        n = 100_000
-        t0 = time.perf_counter()
-        for _ in range(n):
-            blackbox.beacon("gate")
-        per_call_us = (time.perf_counter() - t0) / n * 1e6
-        assert per_call_us < 5.0, (
-            f"disabled beacon costs {per_call_us:.2f}us/call — the "
-            "one-boolean fast path regressed")
-        t0 = time.perf_counter()
-        for _ in range(n):
-            blackbox.note("gate", a=1)
-        per_call_us = (time.perf_counter() - t0) / n * 1e6
-        assert per_call_us < 5.0
-        assert blackbox.beacons() == {}
-        assert blackbox.ring() == []
-
     def test_no_sentinel_thread_with_flag_unset(self):
         """The sentinel thread only exists once armed: a default process
         must never grow a watcher thread."""

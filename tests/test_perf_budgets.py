@@ -707,40 +707,6 @@ def test_async_window_cuts_verdict_fetches():
         paddle.set_flags({"check_nan_inf": old["FLAGS_check_nan_inf"]})
 
 
-def test_monitor_disabled_overhead():
-    """Tier-1 overhead gate (ISSUE 2): with the monitor disabled every
-    instrumented call site must cost ONE boolean check — bounded here
-    absolutely (5us/call is ~25x the expected cost, far under any real
-    per-step budget, yet two orders of magnitude below a lock+dict-hit
-    implementation that forgot the fast path). Device-side cost is
-    already gated by the FLOPs/bytes budgets above: the instrumentation
-    is host-side only, so a compiled-program regression would trip them."""
-    import time
-
-    from paddle_tpu import monitor
-
-    c = monitor.counter("overhead_probe_total")
-    h = monitor.histogram("overhead_probe_ms")
-    bound = monitor.counter("overhead_probe_labeled_total",
-                            labelnames=("site",)).labels(site="x")
-    n = 100_000
-    monitor.disable()
-    try:
-        t0 = time.perf_counter()
-        for _ in range(n):
-            c.inc()
-            h.observe(1.0)
-            bound.inc()
-        per_call_us = (time.perf_counter() - t0) / (3 * n) * 1e6
-    finally:
-        monitor.enable()
-    assert per_call_us < 5.0, (
-        f"monitor-disabled instrumentation costs {per_call_us:.2f}us/call "
-        "— the disabled fast path regressed")
-    # and disabled mode recorded NOTHING
-    assert c.value == 0 and h.count == 0 and bound.value == 0
-
-
 if __name__ == "__main__":
     if "--record" in sys.argv:
         import jax

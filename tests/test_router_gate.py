@@ -15,7 +15,6 @@ import importlib.util
 import os
 import subprocess
 import sys
-import time
 
 import numpy as np
 import pytest
@@ -94,24 +93,6 @@ class TestZeroOverheadSingleEngine:
         # DecodeModel registry changed no instrumentation)
         assert {"request", "queue_wait", "prefill", "decode"} <= names
         assert eng.stats()["requests"]["handoff"] == 0
-
-    def test_idle_step_host_cost(self):
-        """An idle engine step is pure host bookkeeping; the handoff
-        queue must not add measurable work to it. 500us/step is ~100x
-        the expected cost — loose enough for CI noise, far below any
-        real decode step."""
-        m = _model()
-        eng = ServingEngine(m, max_batch=2)
-        eng.step()   # one-time lazies out of the way
-        n = 2000
-        t0 = time.perf_counter()
-        for _ in range(n):
-            eng.step()
-        per_step_us = (time.perf_counter() - t0) / n * 1e6
-        assert per_step_us < 500.0, (
-            f"idle step costs {per_step_us:.1f}us — the single-engine "
-            "hot path regressed")
-
 
 def _load_tool(name):
     spec = importlib.util.spec_from_file_location(

@@ -221,46 +221,6 @@ class TestSchedulesAndMeshes:
         assert (saved + wire) / wire >= 3.5  # logical/wire at d=64
 
 
-class TestPerStageAotCache:
-    def test_disk_entries_keyed_by_each_stages_mesh_fingerprint(
-            self, mpmd, tmp_path):
-        """Satellite 5: each stage program compiles through the PR 3 AOT
-        cache with ITS OWN mesh fingerprint in the key — a rebuilt
-        trainer replays every stage program from disk (hit/disk), and
-        the two stages' fingerprints genuinely differ."""
-        from paddle_tpu.framework import aot
-
-        old = flags.get_flag("jit_cache_dir", "")
-        paddle.set_flags({"jit_cache_dir": str(tmp_path)})
-        try:
-            _losses(_pipeline(), steps=1)
-            monitor.reset()
-            tr = _pipeline()
-            _losses(tr, steps=1)
-            flat = monitor.flatten(monitor.snapshot())
-            disk_hits = {k: v for k, v in flat.items()
-                         if k.startswith("compile_cache_total")
-                         and "site=stage" in k and "source=disk" in k}
-            assert disk_hits, f"no stage disk hits: {sorted(flat)}"
-            # every stage program (fwd0/bwd0/last1 + optimizer) replays
-            sigs = {k.split("sig=")[1].split(",")[0].rstrip("}")
-                    for k in disk_hits}
-            assert {"fwd0", "bwd0", "last1", "optimizer"} <= sigs
-            # each program's cache key carries ITS stage's fingerprint
-            runner = tr._mpmd_runner
-            for k, prog_name in ((0, "fwd0"), (1, "last1")):
-                fp = aot.mesh_fingerprint(runner.stage_meshes[k])
-                assert fp in runner.programs[prog_name]._jit._extra_key
-            # the fingerprint is a topology identity: same-width stage
-            # meshes share it (executables are offerable across them),
-            # different widths never alias
-            wide = build_mesh((3,), ("stage",), devices=jax.devices()[:3])
-            assert aot.mesh_fingerprint(wide) != \
-                aot.mesh_fingerprint(runner.stage_meshes[0])
-        finally:
-            paddle.set_flags({"jit_cache_dir": old})
-
-
 class TestStageSpans:
     def test_stage_step_spans_share_one_trace_id(self, mpmd):
         tr = _pipeline()

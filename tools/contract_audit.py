@@ -14,7 +14,7 @@ Targets:
 
   flags         : analysis/flag_audit.py — orphan/undocumented flags,
                   conflicting defaults, structural flags missing from
-                  _exec_key/AOT extra_key, hot-path flag re-reads
+                  _exec_key, hot-path flag re-reads
   imports       : analysis/import_graph.py — manifest-lazy modules must
                   be unreachable from the plain trainer/engine closure
   observability : analysis/obs_audit.py — metric/span inventory vs the

@@ -3,7 +3,6 @@ candidate flag-sets and fail loudly (exit 1, naming the diverging step and
 stat) when a pair leaves its declared tolerance band.
 
     python tools/parity_check.py --ab check_nan_inf        # PR 4 guard: exact
-    python tools/parity_check.py --ab use_bfloat16         # flag A/B: exact
     python tools/parity_check.py --ab amp_bf16             # bf16 amp: banded
     python tools/parity_check.py --ab quantized_allreduce  # int8 reduce: banded
     python tools/parity_check.py --ab shard_weight_update  # ZeRO-ish: EXACT
@@ -103,13 +102,6 @@ def _batches(steps, batch=2, seq=12):
 #: each target declares ITS tolerance — exact for program-identical or
 #: bit-exact-by-contract A/Bs, a written band for genuinely lossy ones
 AB_TARGETS = {
-    # FLAGS_use_bfloat16 keys the AOT cache today and grows real lowering
-    # the day ROADMAP item 3 widens MXU coverage — the A/B pins EXACT
-    # parity now and becomes the alarm that rings then
-    "use_bfloat16": dict(
-        reference_flags={"use_bfloat16": False},
-        candidate_flags={"use_bfloat16": True},
-        loss_rtol=0.0, loss_atol=0.0, stat_rtol=0.0, stat_atol=0.0),
     # the PR 4 guard rebuilds the step with the fused finiteness verdict
     # + where-selects; on finite data its contract is BIT-exact
     "check_nan_inf": dict(
