@@ -88,8 +88,9 @@ HOT_PATHS = {
         "train_step", "_train_step_impl", "_run_step", "_unpack_step",
         "_finish_step", "_drain_verdicts"},
     os.path.join("inference", "serving.py"): {
-        "step", "_step_inner", "_step_inner_sync", "_step_inner_async",
-        "_admit_phase", "_step_speculative", "_advance_prefill", "_activate",
+        "step", "_step_inner", "_step_inner_sync", "_step_inner_lookahead",
+        "_dispatch_ahead", "_emit_round", "_emit_token", "_admit_phase",
+        "_step_speculative", "_advance_prefill", "_activate",
         "_admit_one_inner", "_advance_and_admit", "_dispatch_decode",
         "_apply_decode"},
 }

@@ -155,13 +155,12 @@ define_flag("shard_weight_update", False,
 define_flag("async_dispatch", False,
             "double-buffered step dispatch (docs/PERF.md): SpmdTrainer "
             "returns a lazy StepHandle (distributed/async_dispatch.py), "
-            "the non-finite guard verdict is fetched in windows of "
-            "FLAGS_async_window steps instead of per step, and "
-            "ServingEngine.step overlaps admission/bookkeeping for the "
-            "next round with the current round's device compute. Read at "
-            "TRAINER/ENGINE CONSTRUCTION — a post-construction toggle "
-            "under a live trainer raises. Unset, the async module is "
-            "never imported and behavior is byte-identical")
+            "and the non-finite guard verdict is fetched in windows of "
+            "FLAGS_async_window steps instead of per step. The trainer's "
+            "alone (ServingEngine keeps a decode step in flight by "
+            "itself). Read at TRAINER CONSTRUCTION — a post-construction "
+            "toggle under a live trainer raises. Unset, the async module "
+            "is never imported and behavior is byte-identical")
 define_flag("async_window", 8,
             "with FLAGS_async_dispatch: how many steps the host may run "
             "ahead of the deferred non-finite-guard verdict fetch (the "

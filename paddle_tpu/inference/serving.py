@@ -18,7 +18,12 @@ one. This engine is that recipe, TPU-shaped:
   before attention each step);
 - finished slots (eos / max_new_tokens / capacity) free immediately and
   the next queued request takes the slot on the following step() —
-  continuous batching, not static batching.
+  continuous batching, not static batching;
+- one decode step is kept IN FLIGHT (dense engines without a draft
+  model; docs/SERVING.md "The decode loop"): the token vector lives on
+  the device, step N+1 is dispatched from what step N leaves there, and
+  the host reads step N's tokens while N+1 runs. Token streams do not
+  change by it; a token may show one step() call later.
 
 Per-request decoding knobs: temperature=0 (default) is greedy with EXACT
 parity vs a solo `model.generate(temperature=0)` (asserted in tests);
@@ -70,6 +75,7 @@ request's trace, and `admit_prefilled()` accepts a KV row prefilled by a
 `serving.PrefillWorker` — the prefill/decode disaggregation handoff,
 bit-identical to local admission.
 """
+import collections
 import time
 
 import numpy as np
@@ -145,6 +151,12 @@ _DEADLINE = _monitor.counter(
     "request_deadline_exceeded_total",
     "requests finished with reason='deadline' (per-request deadline_ms "
     "elapsed before completion)")
+
+
+# the lookahead loop's decode step dispatched and not read yet: its device
+# tokens, the [(slot, request)] it was dispatched for, its kind, and the
+# `serve/decode_dispatch` phase it was dispatched in
+_Flight = collections.namedtuple("_Flight", "toks rows kind disp")
 
 
 class _MsSummary:
@@ -276,12 +288,14 @@ def _blackbox_request_table(eng):
         "handoff": [e[0].rid for e in eng._handoff],
         "prefilling": {s: e[0].rid for s, e in eng._prefilling.items()},
         "running": running,
+        # slot already free, last token on its way with the step in flight
+        "unread": [r.rid for r in eng._unread()],
         "finished": len(eng._finished),
     }
     table["in_flight"] = sorted(
         set(table["queued"]) | set(table["handoff"])
         | set(table["prefilling"].values())
-        | {r["rid"] for r in running})
+        | {r["rid"] for r in running} | set(table["unread"]))
     return table
 
 
@@ -672,9 +686,37 @@ class ServingEngine:
         # fine over the head-sharded cache
         self._admit = _cj(admit, "admit", donate=(0,))
         # the prefill token goes through the SAME pick as decode steps
-        self._pick1 = _cj(lambda lg, t, k, tp, s, p_: _pick(
-            lg[None], t[None], k[None], tp[None], s[None], p_[None])[0],
-            "pick1")
+        def pick1(lg, t, k, tp, s, p_):
+            return _pick(lg[None], t[None], k[None], tp[None], s[None],
+                         p_[None])[0]
+
+        def pick1_put(toks, slot, lg, t, k, tp, s, p_):
+            """The admission's first token, picked on the device and laid
+            into row `slot` of the token vector the next decode step
+            reads; the token alone comes back too, for the host to read
+            when it reads the round's others."""
+            tok = pick1(lg, t, k, tp, s, p_)
+            return tok, toks.at[slot].set(tok)
+
+        # one decode step of lookahead (_step_inner_lookahead): dense
+        # engines without a draft keep a step in flight and read its
+        # tokens one step() call behind. Paged engines (admission mutates
+        # the pool whose tables the dispatched step snapshotted) and
+        # speculative engines (the draft round's host orchestration IS the
+        # dispatch) keep the serial loop (_step_inner_sync).
+        self._lookahead = draft_model is None and not _paged
+        if not self._lookahead:
+            self._pick1 = _cj(pick1, "pick1")
+        elif tp_mesh is None:
+            self._pick1_put = _cj(pick1_put, "pick1_put")
+        else:
+            # the token vector stays what the tp step returns and takes:
+            # replicated over the mesh
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            rep = NamedSharding(tp_mesh, P())
+            self._pick1_put = _cj(jit=jax.jit(
+                pick1_put, out_shardings=(rep, rep)), label="pick1_put")
 
         self._chunk = None if prefill_chunk is None else int(prefill_chunk)
         if tp_mesh is None:
@@ -790,18 +832,6 @@ class ServingEngine:
                     in_specs=(tp_specs, cs, cs, P(), P(), P()),
                     donate=(1, 2)), label="verify")
 
-        # async double-buffered rounds (FLAGS_async_dispatch, docs/
-        # PERF.md): consumed at ENGINE CONSTRUCTION like the trainer's
-        # copy of the flag. Armed, step() dispatches round N's decode
-        # FIRST and runs round N+1's admission/bookkeeping while the
-        # device computes, fetching tokens last — the host work hides
-        # behind device compute. Speculative engines keep the sync step
-        # (the draft round's host orchestration is itself the dispatch).
-        self._async = bool(_flags.get_flag("async_dispatch", False))
-        self._async_ms = ({"dispatch_ms": 0.0, "overlap_ms": 0.0,
-                           "fetch_ms": 0.0, "rounds": 0}
-                          if self._async else None)
-
         # engine-local observability accumulators (the module-level monitor
         # metrics aggregate across engines; stats() reports THIS engine)
         self._m = {"submitted": 0, "finished": {}, "tokens": 0,
@@ -810,6 +840,8 @@ class ServingEngine:
                    "prefix_hit": 0, "prefix_miss": 0,
                    "occupancy_sum": 0, "occupancy_steps": 0,
                    "kv_tiles_read": 0, "kv_tiles_held": 0,
+                   "lookahead": {"rounds": 0, "rounds_overlapped": 0,
+                                 "tokens_discarded": 0},
                    "queue_wait_ms": _MsSummary(), "ttft_ms": _MsSummary(),
                    "inter_token_ms": _MsSummary()}
         # admissions so far: [requests, prompt tokens] — the serve/step and
@@ -818,8 +850,22 @@ class ServingEngine:
 
         # host-side slot state
         self._slot_req = [None] * self.B        # Request or None
-        self._pos = np.zeros(self.B, np.int32)  # next write column
-        self._last = np.zeros(self.B, np.int32)
+        # next write column; the lookahead loop advances it when a step
+        # is DISPATCHED, the serial loop when its tokens are read
+        self._pos = np.zeros(self.B, np.int32)
+        # each slot's last token: ON THE DEVICE under the lookahead loop (a
+        # decode step's output is the next step's input; admissions lay
+        # their first token over it there, pick1_put), on the host under
+        # the serial one
+        if self._lookahead:
+            self._toks = jnp.zeros(self.B, jnp.int32)
+        else:
+            self._last = np.zeros(self.B, np.int32)
+        # the lookahead loop's step dispatched and not yet read (_Flight),
+        # and this round's admissions whose first token is not read yet:
+        # (slot, request, device token)
+        self._flight = None
+        self._firsts = []
         self._temps = np.zeros(self.B, np.float32)   # 0 = greedy
         self._topk = np.full(self.B, self.cfg.vocab_size, np.int32)
         self._topp = np.ones(self.B, np.float32)     # 1.0 = no nucleus
@@ -990,7 +1036,7 @@ class ServingEngine:
             return counts
         kc, vc = aval(self._kc), aval(self._vc)
         kc1, vc1 = jax.eval_shape(lambda: self._prefill_start())
-        lg_spec = f32((V,))
+        lg_spec, toks_spec = f32((V,)), i32((B,))
         if self._tp_mesh is not None:
             # eval_shape drops out_shardings: re-attach the head-sharded
             # side-cache placement (same every-leaf recipe as the ctor's
@@ -1005,9 +1051,9 @@ class ServingEngine:
                 lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
                                                sharding=sh), t)
             kc1, vc1 = reshard(kc1), reshard(vc1)
-            lg_spec = jax.ShapeDtypeStruct(
-                (V,), jnp.float32,
-                sharding=NamedSharding(self._tp_mesh, P()))
+            rep = NamedSharding(self._tp_mesh, P())
+            lg_spec = jax.ShapeDtypeStruct((V,), jnp.float32, sharding=rep)
+            toks_spec = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=rep)
         lens = (list(batch_shapes) if batch_shapes is not None
                 else list(self._buckets))
         buckets = sorted({self._bucket(int(n)) for n in lens})
@@ -1017,7 +1063,11 @@ class ServingEngine:
         if sampling:
             warm(self._step_sample, p, kc, vc, i32((B,)), i32((B,)),
                  f32((B,)), i32((B,)), f32((B,)), i32((B,)))
-        warm(self._pick1, lg_spec, f32(), i32(), f32(), i32(), i32())
+        if self._lookahead:
+            warm(self._pick1_put, toks_spec, i32(), lg_spec, f32(), i32(),
+                 f32(), i32(), i32())
+        else:
+            warm(self._pick1, lg_spec, f32(), i32(), f32(), i32(), i32())
         # slot index rides as a weakly-typed python int, exactly as the
         # live _activate call passes it
         warm(self._admit, kc, kc1, 0)
@@ -1052,10 +1102,11 @@ class ServingEngine:
     def _acc_phase(self, kind, *phases):
         """Book one step-kind slice of stats()['breakdown']: the summed
         wall time of the closed step phases that cover it (a decode kind
-        is its dispatch + wait phases — in the async step the admission
-        window between them is booked under its own kinds, and counting
-        it twice would make the kinds sum past real wall time). The
-        phases' clock reads are the only ones taken."""
+        is its dispatch + wait phases — under the lookahead loop the
+        dispatch of one step() call and the wait of the next; what passes
+        between them is booked under its own kinds, and counting it twice
+        would make the kinds sum past real wall time). The phases' clock
+        reads are the only ones taken."""
         st = self._m["step_ms"].setdefault(kind, [0, 0.0])
         st[0] += 1
         st[1] += sum(ph.end_ns - ph.start_ns for ph in phases) / 1e6
@@ -1080,15 +1131,25 @@ class ServingEngine:
                          "prefilling": len(self._prefilling),
                          # decoding slots only: mid-prefill slots hold a
                          # _slot_req reservation but belong to "prefilling"
+                         # (and rows whose slot is free again with their
+                         # last token still on its way: _unread)
                          "running": sum(1 for s in range(self.B)
                                         if self._slot_req[s] is not None
-                                        and s not in self._prefilling),
+                                        and s not in self._prefilling)
+                         + len(self._unread()),
                          "finished": dict(m["finished"])},
             "tokens_generated": m["tokens"],
             "steps": dict(m["steps"]),
             "batch_occupancy_avg": occ,
             "kv_tiles_read": m["kv_tiles_read"],
             "kv_tiles_held": m["kv_tiles_held"],
+            # how often the lookahead loop engages: decode steps
+            # dispatched, those dispatched while the one before was still
+            # unread, and columns computed for rows that had finished
+            # (eos, cancel, deadline, error: what the host learns a step
+            # late). All 0 on an engine that keeps the serial loop.
+            "lookahead": dict(m["lookahead"],
+                              in_flight=int(self._flight is not None)),
             "prefix_cache": {"hit": m["prefix_hit"],
                              "miss": m["prefix_miss"],
                              "hit_rate": (m["prefix_hit"] / prefix_n
@@ -1200,17 +1261,6 @@ class ServingEngine:
                 flops_known = True
             kinds[kind] = row
         out = {"kinds": kinds, "wall_ms_total": total_ms}
-        if self._async_ms is not None:
-            # async rounds: how much of the decode wall time was host
-            # dispatch vs the overlapped admission window vs the token
-            # fetch — the dispatch-vs-sync fraction the async path
-            # exists to shrink (docs/PERF.md)
-            a = dict(self._async_ms)
-            covered = a["dispatch_ms"] + a["overlap_ms"] + a["fetch_ms"]
-            a["dispatch_fraction"] = (
-                (a["dispatch_ms"] + a["overlap_ms"]) / covered
-                if covered else 0.0)
-            out["async_overlap"] = a
         if flops_known:
             out["device_flops_total"] = flops_total
             peak = _costs.peak_flops()
@@ -1239,6 +1289,9 @@ class ServingEngine:
         for entry in self._handoff:
             if entry[0].rid == rid:
                 return entry[0]
+        for req in self._unread():
+            if req.rid == rid:
+                return req
         if rid in self._finished:
             return self._finished[rid]
         raise KeyError(f"unknown request id {rid}")
@@ -1385,7 +1438,9 @@ class ServingEngine:
         executable.
 
         Sessions already in flight keep decoding — each finishes under
-        the replacement weights but CARRIES its submission-time version
+        the replacement weights (a decode step already dispatched, whose
+        tokens the next step() reads, ran under the old ones: the device
+        orders the swap behind it) but CARRIES its submission-time version
         stamp, so its completion is attributable to the lineage it
         started on (and counts ``serving_stale_sessions_total`` under
         FLAGS_goodput). Requests submitted after the swap carry the
@@ -1699,7 +1754,11 @@ class ServingEngine:
         outcome, records it, and (slot given) frees the slot + any
         in-flight prefill reservation. Freed rows need no scrubbing — the
         next admission's row copy overwrites them (the invariant the whole
-        engine rides on)."""
+        engine rides on). A slot the lookahead loop released ahead of the
+        request's last token (_release) may be another request's by now:
+        it is left alone."""
+        if slot is not None and self._slot_req[slot] not in (req, None):
+            slot = None
         req.finished = True
         req.finish_reason = reason
         req.finish_time = time.perf_counter()
@@ -1734,9 +1793,6 @@ class ServingEngine:
                 self._pool.free_slot(slot)
                 self._adapter_slot[slot] = 0
 
-    def _finish(self, slot, reason):
-        self._finish_req(self._slot_req[slot], reason, slot=slot)
-
     def _note_error(self):
         self._last_error_step = self._step_no
 
@@ -1762,7 +1818,12 @@ class ServingEngine:
         for slot in range(self.B):
             req = self._slot_req[slot]
             if req is not None and req.rid == rid:
+                # a column the step in flight computes for it is discarded
                 self._finish_req(req, "cancelled", slot=slot)
+                return True
+        for req in self._unread():
+            if req.rid == rid:
+                self._finish_req(req, "cancelled")
                 return True
         if rid in self._finished:
             return False
@@ -1806,7 +1867,9 @@ class ServingEngine:
         """Finish every overdue request (reason="deadline") wherever it
         lives — queue, mid-prefill, or an active slot. Batch-mates are
         untouched: a freed slot is just another don't-care row until the
-        next admission overwrites it."""
+        next admission overwrites it. A request whose last token is on
+        its way with the step in flight (_unread) is left to finish by
+        it: under the serial loop it had finished a call ago."""
         if not self._deadline_live:
             return   # nothing carries a deadline: keep step() O(1) here
         now = time.perf_counter()
@@ -1836,7 +1899,8 @@ class ServingEngine:
 
     def _activate(self, slot, req, kc1, vc1, logits, draft_caches=None):
         """Shared admission tail: copy the side cache(s) into the slot's
-        row and emit the first generated token through the standard pick."""
+        row and put the first generated token through the standard pick.
+        Under the lookahead loop nothing here waits for the device."""
         n = len(req.prompt_ids)
         if self._paged:
             # HANDOFF_SCHEMA "kv_page_admit" producer site: the prefilled
@@ -1857,20 +1921,48 @@ class ServingEngine:
         topk = np.int32(req.top_k or self.cfg.vocab_size)
         topp = np.float32(1.0 if req.top_p is None else req.top_p)
         seed = np.int32(req.seed)
-        # fold value = index of the context's last token (n-1), matching
-        # the decode step's schedule (each emission folds a unique value)
-        with _trace.phase("serve/prefill_wait"):
-            tok = int(self._pick1(logits, temp, topk, topp, seed,
-                                  np.int32(n - 1)))
         self._slot_req[slot] = req
         self._pos[slot] = n
-        self._last[slot] = tok
         self._temps[slot] = temp
         self._topk[slot] = topk
         self._topp[slot] = topp
         self._seeds[slot] = seed
+        # fold value = index of the context's last token (n-1), matching
+        # the decode step's schedule (each emission folds a unique value)
+        if self._lookahead:
+            # the device half only: the host reads the token with the
+            # round's others (_emit_round), and the next decode step takes
+            # it from the device's vector
+            tok, self._toks = self._pick1_put(
+                self._toks, np.int32(slot), logits, temp, topk, topp, seed,
+                np.int32(n - 1))
+            self._firsts.append((slot, req, tok))
+            if req.max_new_tokens == 1:
+                self._release(slot)     # nothing more to dispatch for it
+            return
+        with _trace.phase("serve/prefill_wait"):
+            tok = int(self._pick1(logits, temp, topk, topp, seed,
+                                  np.int32(n - 1)))
+        self._last[slot] = tok
         req.output_ids.append(tok)
         self._after_emit(slot, req)
+
+    def _release(self, slot):
+        """The lookahead loop has dispatched the last step the slot's
+        request needs (`length` and `capacity` follow from counts the host
+        has): the slot is free for the next admission at once, whose row
+        copy the device orders behind that step; the request lives on in
+        `_flight` / `_firsts` until its last token is read (_unread)."""
+        self._slot_req[slot] = None
+        self._pos[slot] = 0
+
+    def _unread(self):
+        """Requests that hold no slot any more and whose last token is on
+        its way with the step in flight."""
+        if self._flight is None:
+            return []
+        return [req for slot, req in self._flight.rows
+                if not req.finished and self._slot_req[slot] is not req]
 
     def _note_admission(self, req):
         """Queue wait ends when admission work starts (prefill or slot
@@ -2100,23 +2192,31 @@ class ServingEngine:
                     self._lora, aids)
             self._count_step(kind)
             return next_toks, kind
+        def up(a):
+            # a COPY goes up: the host writes these vectors again (the
+            # positions right after this dispatch, the knobs at the next
+            # admission) while the step may not have read them yet, and a
+            # CPU backend's device array can alias the numpy buffer
+            return jnp.asarray(a.copy())
+
+        # the lookahead loop's input tokens never left the device
+        last = self._toks if self._lookahead else up(self._last)
         if any(self._temps[s] > 0 for s in active):
             kind = "decode_sample"
             next_toks, self._kc, self._vc = self._step_sample(
-                self._params, self._kc, self._vc,
-                jnp.asarray(self._last), jnp.asarray(self._pos),
-                jnp.asarray(self._temps), jnp.asarray(self._topk),
-                jnp.asarray(self._topp), jnp.asarray(self._seeds))
+                self._params, self._kc, self._vc, last, up(self._pos),
+                up(self._temps), up(self._topk), up(self._topp),
+                up(self._seeds))
         else:
             kind = "decode_greedy"
             next_toks, self._kc, self._vc = self._step_greedy(
-                self._params, self._kc, self._vc,
-                jnp.asarray(self._last), jnp.asarray(self._pos))
+                self._params, self._kc, self._vc, last, up(self._pos))
         self._count_step(kind)
         return next_toks, kind
 
     def _apply_decode(self, active, next_toks, kind, t0_ns, t1_ns):
-        """Emit one fetched round's tokens slot by slot. Per-slot
+        """The serial loop's emit (the lookahead loop's is _emit_round):
+        one fetched round's tokens slot by slot. Per-slot
         failures isolate (the failing request finishes reason="error");
         the slot-level decode span attributes the batched device step's
         window to each request."""
@@ -2139,9 +2239,9 @@ class ServingEngine:
                 self._note_error()
 
     def _advance_and_admit(self):
-        """The round's admission window, shared by the sync and async
-        steps: advance every in-flight chunked prefill ONE chunk (so
-        active decodes never wait for a whole long prefill), then admit
+        """The round's admission window, shared by both loops: advance
+        every in-flight chunked prefill ONE chunk (so active decodes
+        never wait for a whole long prefill), then admit
         queued/handoff requests into free slots. Per-request failures
         isolate: the failing request finishes reason="error" and the
         pass continues."""
@@ -2252,15 +2352,24 @@ class ServingEngine:
             self._m["inter_token_ms"].add(gap_ms)
             _ITL_MS.observe(gap_ms)
         if self.eos is not None and req.output_ids[-1] == self.eos:
-            self._finish(slot, "eos")
+            reason = "eos"
         elif len(req.output_ids) >= req.max_new_tokens:
-            self._finish(slot, "length")
-        elif self._pos[slot] >= self.T:   # next write column out of cache
-            self._finish(slot, "capacity")
+            reason = "length"
+        elif len(req.prompt_ids) + len(req.output_ids) > self.T:
+            reason = "capacity"     # next write column out of cache
+        else:
+            return
+        self._finish_req(req, reason, slot=slot)
 
     def step(self):
         """Admit queued requests into free slots, then run ONE decode step
         for every active slot. Returns requests finished this step.
+
+        A dense engine without a draft model keeps one decode step in
+        flight (_step_inner_lookahead): the step this call dispatches is
+        read by the next call, and the tokens this call emits are those of
+        the step the call before dispatched. Streams, counts and finish
+        reasons do not change by it; a token may show one call later.
 
         Per-request failure isolation: host-side per-slot work (admission,
         chunked-prefill advance, token emission) that throws finishes ONLY
@@ -2320,13 +2429,8 @@ class ServingEngine:
             # frames untouched for page_cold_steps sweeps compress to int8
             # host pages (host bookkeeping; no device sync)
             self._pool.sweep()
-        # FLAGS_async_dispatch (construction-consumed): overlap round
-        # N+1's host admission/bookkeeping with round N's device compute.
-        # Speculative engines stay on the sync step (see __init__);
-        # paged engines too (their admission mutates the pool the
-        # dispatched step's tables were snapshotted from).
-        if self._async and self._draft is None and not self._paged:
-            return self._step_inner_async(root)
+        if self._lookahead:
+            return self._step_inner_lookahead(root)
         return self._step_inner_sync(root)
 
     def _admit_phase(self):
@@ -2339,65 +2443,134 @@ class ServingEngine:
                              prompt_tokens=self._admitted[1] - tokens0)
         return ph
 
-    def _step_inner_async(self, root):
-        """The async round (docs/PERF.md): dispatch the decode program
-        for the slots active at entry (device starts immediately — jax
-        dispatch is asynchronous), then run the HOST work of the next
-        round — chunked-prefill advances and queue admissions — while
-        the device computes, and only then fetch the round's tokens.
-        Per-request token streams are bit-identical to the sync step
-        (each slot's decode depends only on its own cache row/position);
-        a request admitted this round starts decoding next round instead
-        of this one, so drains may take one extra step() call."""
+    def _step_inner_lookahead(self, root):
+        """One round with a decode step kept in flight (docs/SERVING.md
+        "The decode loop"): the engine's only loop for a dense cache
+        without a draft model. A call that finds step N running does this
+        round's admissions, dispatches step N+1 from the token vector
+        step N leaves ON THE DEVICE (admissions lay their first token over
+        it there), and only then reads and emits step N's tokens: when it
+        returns, N+1 is running, under the caller's bookkeeping too. A
+        call that finds nothing in flight dispatches one step first, so
+        every call has a round to emit.
+
+        What the host can foresee it does not compute: a row that the
+        step in flight brings to `max_new_tokens` or the cache's capacity
+        is left out of the next dispatch and its slot released at once
+        (_release). What it cannot foresee (eos, cancel, deadline, a
+        per-slot error) it learns one step late: that row's column of the
+        step in flight is computed and DISCARDED, never emitted, and lands
+        in a freed row, which is don't-care until an admission overwrites
+        it (the row copy is ordered on the device behind the step).
+
+        Per-request token streams, counts and finish reasons are those of
+        the serial loop bit for bit (a row's step depends on its own cache
+        row, position, last token and knobs, and the positions stay the
+        host's); a request admitted while a step is in flight sees its
+        tokens one step() call later than there."""
         _fp.failpoint("serving/step")
         self._step_no += 1
         before = set(self._finished)
+        # after the snapshot: deadline expiries belong to THIS step's
+        # returned finishes, same as error/eos/length
         self._expire_deadlines()
-        active = [s for s in range(self.B)
-                  if self._slot_req[s] is not None
-                  and s not in self._prefilling]
-        self._note_occupancy(active)
-        root.counts["active"] = len(active)
-        am = self._async_ms
-        am["rounds"] += 1
-        dispatched = wait = None
-        with _trace.phase("serve/decode_dispatch") as disp:
-            if active:
-                dispatched = self._dispatch_decode(active)
-                self._count_kv_tiles(disp)
-        am["dispatch_ms"] += disp.ms
-        # ---- overlapped host window: round N+1's admission work runs
-        # while round N's decode executes on device. The row copies the
-        # admissions enqueue (_admit) sequence AFTER the in-flight decode
-        # on its output cache — device-ordered, rows disjoint.
-        admit = self._admit_phase()
-        am["overlap_ms"] += admit.ms
-        if dispatched is not None:
-            next_toks, kind = dispatched
-            # THE round's one host sync: everything admission needed to
-            # do already happened while the device was busy
-            with _trace.phase("serve/decode_wait") as wait:
-                next_toks = np.asarray(next_toks)  # lint: allow(step-loop-host-sync)
-            am["fetch_ms"] += wait.ms
-            # the kind's wall slice = dispatch + fetch windows; the
-            # overlapped admission window is already booked under its
-            # own kinds by _advance_and_admit
-            self._acc_phase(kind, disp, wait)
-            with _trace.phase("serve/emit"):
-                self._apply_decode(active, next_toks, kind, disp.start_ns,
-                                   wait.end_ns)
-        if _trace.is_enabled():
-            # the PR 5 dispatch-vs-sync breakdown, span-attributed from
-            # the phases' clock reads: the admission window rides INSIDE
-            # the device-compute window
-            _trace.emit("dispatch/decode", disp.start_ns, disp.end_ns,
-                        subsystem="serving", slots=len(active))
-            _trace.emit("dispatch/overlap", admit.start_ns, admit.end_ns,
-                        subsystem="serving")
-            if wait is not None:
-                _trace.emit("dispatch/fetch", wait.start_ns, wait.end_ns,
-                            subsystem="serving")
+        self._admit_phase()
+        if self._flight is None:
+            self._flight = self._dispatch_ahead()
+        flight, self._flight = self._flight, self._dispatch_ahead()
+        firsts, self._firsts = self._firsts, []
+        rows = () if flight is None else flight.rows
+        self._note_occupancy(rows)
+        root.counts["active"] = len(rows)
+        self._emit_round(flight, firsts)
         return [self._finished[r] for r in set(self._finished) - before]
+
+    def _decoding_slots(self):
+        return [s for s in range(self.B)
+                if self._slot_req[s] is not None
+                and s not in self._prefilling]
+
+    def _dispatch_ahead(self):
+        """Dispatch one decode step for the rows with a token still to
+        come and advance the host's positions past it; returns what
+        `_flight` holds, or None with no such row. Nothing here waits for
+        the device."""
+        active = self._decoding_slots()
+        if not active:
+            return None
+        ahead = int(self._flight is not None)
+        with _trace.phase("serve/decode_dispatch", in_flight=ahead) as disp:
+            self._toks, kind = self._dispatch_decode(active)
+            self._count_kv_tiles(disp)
+        la = self._m["lookahead"]
+        la["rounds"] += 1
+        la["rounds_overlapped"] += ahead
+        rows = [(s, self._slot_req[s]) for s in active]
+        for s, req in rows:
+            self._pos[s] += 1
+            n_tokens = self._pos[s] - len(req.prompt_ids) + 1
+            if n_tokens >= req.max_new_tokens or self._pos[s] >= self.T:
+                self._release(s)    # `length` or `capacity`, foreseen
+        return _Flight(self._toks, rows, kind, disp)
+
+    def _emit_round(self, flight, firsts):
+        """The lookahead round's reads and its emit: the tokens of the
+        step dispatched a round ago (`serve/decode_wait`) and of this
+        round's admissions (`serve/prefill_wait`), then every token
+        through the standard _after_emit, a request's first before its
+        next. Per-slot failures isolate (the failing request finishes
+        reason="error")."""
+        import jax
+
+        if flight is not None:
+            # THE round's host sync, with the next step already running
+            with _trace.phase("serve/decode_wait") as wait:
+                toks = np.asarray(flight.toks).tolist()  # lint: allow(step-loop-host-sync)
+            self._acc_phase(flight.kind, flight.disp, wait)
+            decode = (flight.disp.start_ns, wait.end_ns, flight.kind)
+        if firsts:
+            with _trace.phase("serve/prefill_wait"):
+                first_toks = jax.device_get(  # lint: allow(step-loop-host-sync)
+                    [tok for _, _, tok in firsts])
+        discarded = 0
+        with _trace.phase("serve/emit") as emit:
+            for i, (slot, req, _) in enumerate(firsts):
+                if not req.finished:    # (its admission failed half-way)
+                    self._emit_token(slot, req, int(first_toks[i]))
+            for slot, req in (() if flight is None else flight.rows):
+                if req.finished:
+                    discarded += 1
+                else:
+                    self._emit_token(slot, req, toks[slot], decode)
+            ahead = self._flight
+            if ahead is not None and all(r.finished for _, r in ahead.rows):
+                # nobody is left to read the step just dispatched (the
+                # last rows ended by eos or an error): a drain ends with
+                # nothing in flight
+                discarded += len(ahead.rows)
+                self._flight = None
+            emit.counts["discarded"] = discarded
+        self._m["lookahead"]["tokens_discarded"] += discarded
+
+    def _emit_token(self, slot, req, tok, decode=None):
+        """One read token to its request (`decode`: the step's window and
+        kind, for the slot-level span that attributes the batched device
+        step to each request)."""
+        try:
+            if decode is not None:
+                _fp.failpoint("serving/slot")
+            req.output_ids.append(tok)
+            if decode is not None and req._span is not None:
+                _trace.emit("decode", decode[0], decode[1],
+                            subsystem="serving", parent=req._span,
+                            slot=slot, kind=decode[2], token=tok,
+                            pos=len(req.prompt_ids) + len(req.output_ids)
+                            - 1)
+            self._after_emit(slot, req)
+        except Exception:
+            if not req.finished:
+                self._finish_req(req, "error", slot=slot)
+            self._note_error()
 
     def _step_inner_sync(self, root):
         import jax.numpy as jnp
@@ -2412,9 +2585,7 @@ class ServingEngine:
         # decodes below never wait for a whole long prefill
         self._admit_phase()
 
-        active = [s for s in range(self.B)
-                  if self._slot_req[s] is not None
-                  and s not in self._prefilling]
+        active = self._decoding_slots()
         self._note_occupancy(active)
         root.counts["active"] = len(active)
         if active:
@@ -2516,7 +2687,8 @@ class ServingEngine:
 
     def has_work(self):
         return bool(self._queue) or bool(self._handoff) \
-            or any(r is not None for r in self._slot_req)
+            or any(r is not None for r in self._slot_req) \
+            or bool(self._unread())     # tokens computed and not read
 
     def run_until_complete(self, max_steps=100_000):
         """Drain the queue; returns {rid: Request}. Non-convergence fails
@@ -2553,6 +2725,10 @@ class ServingEngine:
                     if req is not None:
                         self._finish_req(req, "engine_stalled", slot=slot)
                         stalled.append(req.rid)
+                for req in self._unread():
+                    self._finish_req(req, "engine_stalled")
+                    stalled.append(req.rid)
+                self._flight = None     # its columns are nobody's now
                 raise RuntimeError(
                     "serving engine did not converge within "
                     f"{max_steps} steps; failed in-flight requests "

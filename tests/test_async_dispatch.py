@@ -207,7 +207,11 @@ class TestServingAsync:
         m.eval()
         return m
 
-    def test_async_engine_tokens_bit_exact_and_overlap_attributed(self):
+    def test_engine_tokens_bit_exact_with_a_step_in_flight(self):
+        """The engine's one decode loop keeps a step in flight (PR 33; it
+        does what FLAGS_async_dispatch's serving arm did and more, and
+        that arm is gone): tokens are generate()'s, the flag changes
+        nothing, and stats()["lookahead"] says how often it engaged."""
         from paddle_tpu import trace
         from paddle_tpu.inference.serving import ServingEngine
 
@@ -216,8 +220,8 @@ class TestServingAsync:
         prompts = [rng.randint(0, 64, (n,)).astype(np.int32)
                    for n in (5, 9, 4)]
 
-        def run(async_on):
-            paddle.set_flags({"async_dispatch": async_on})
+        def run(flag):
+            paddle.set_flags({"async_dispatch": flag})
             try:
                 eng = ServingEngine(m, max_batch=2)
                 rids = [eng.submit(p, max_new_tokens=6) for p in prompts]
@@ -226,21 +230,27 @@ class TestServingAsync:
             finally:
                 paddle.set_flags({"async_dispatch": False})
 
-        _, sync_tokens = run(False)
+        want = [np.asarray(m.generate(
+            paddle.to_tensor(p[None]), max_new_tokens=6,
+            temperature=0.0)._data)[0, len(p):].tolist() for p in prompts]
+        eng0, tokens = run(False)
+        assert list(tokens.values()) == want
         trace.clear()
         trace.enable()
         try:
-            eng, async_tokens = run(True)
+            eng, flagged = run(True)
         finally:
             trace.disable()
-        assert sync_tokens == async_tokens
-        bd = eng.stats()["breakdown"]["async_overlap"]
-        assert bd["rounds"] > 0
-        assert bd["dispatch_ms"] >= 0 and bd["overlap_ms"] >= 0
+        assert flagged == tokens
+        la = eng.stats()["lookahead"]
+        assert la == eng0.stats()["lookahead"]
+        assert la["rounds"] == eng.stats()["steps"]["decode_greedy"] > 0
+        # all but the first step went out with the one before it unread
+        assert la["rounds_overlapped"] == la["rounds"] - 1
+        assert la["tokens_discarded"] == 0 and la["in_flight"] == 0
         names = {s.name for s in trace.spans()}
-        assert "dispatch/decode" in names
-        assert "dispatch/overlap" in names
-        assert "dispatch/fetch" in names
+        assert "decode" in names
+        assert not [n for n in names if n.startswith("dispatch/")]
 
     def test_plain_engine_has_no_async_breakdown_or_spans(self):
         from paddle_tpu import trace
@@ -258,6 +268,13 @@ class TestServingAsync:
         assert "async_overlap" not in eng.stats()["breakdown"]
         assert not [s.name for s in trace.spans()
                     if s.name.startswith("dispatch/")]
+        assert not hasattr(eng, "_async") and not eng.has_work()
+        # 3 tokens: the admission's and two decode steps', both dispatched
+        # by the one call that found nothing in flight
+        assert eng.stats()["lookahead"] == {
+            "rounds": 2, "rounds_overlapped": 1, "tokens_discarded": 0,
+            "in_flight": 0}
+        assert eng.stats()["health"]["steps"] == 2
 
 
 class TestOverlapGradComm:

@@ -292,6 +292,52 @@ class TestDecodeStep:
                                                  else 5)
         assert mem.alias_size_in_bytes == cache      # donated, in place
 
+    @pytest.mark.parametrize("kind", ["greedy", "sample"])
+    def test_the_steps_token_vector_stays_on_the_chip(self, chip, kind):
+        """The decode loop keeps one step in flight: a step's tokens are the
+        next step's input as they lie on the chip, and an admission lays its
+        first token over them in its own program (`pick1_put`). Both compile
+        for the described chip from each other's output: a `[32]` int32
+        vector, no host transfer in either."""
+        import paddle_tpu as paddle
+        from paddle_tpu.inference.serving import ServingEngine
+        from paddle_tpu.models import GPTConfig, GPTForCausalLM
+
+        paddle.seed(0)
+        model = GPTForCausalLM(GPTConfig(
+            vocab_size=50304, hidden_size=64, num_layers=1, num_heads=2,
+            max_seq_len=128, dropout=0.0))
+        model.eval()
+        eng = ServingEngine(model, max_batch=self.ROWS, dtype="bfloat16")
+        rows, f32, i32 = (self.ROWS,), jnp.float32, jnp.int32
+        knobs = [chip((), f32), chip((), i32), chip((), f32), chip((), i32),
+                 chip((), i32)]
+        if kind == "greedy":
+            knobs[0] = np.float32(0.0)      # as _activate hands them over
+        put = eng._pick1_put.lower(
+            chip(rows, i32), chip((), i32), chip((50304,), f32),
+            *knobs).compile()
+        tok, toks = put.out_info
+        assert (tok.shape, toks.shape, toks.dtype) == ((), rows, i32)
+
+        def on_chip(tree):
+            return jax.tree_util.tree_map(
+                lambda a: chip(a.shape, a.dtype), tree)
+
+        args = [on_chip(eng._params), on_chip(eng._kc), on_chip(eng._vc),
+                chip(rows, i32), chip(rows, i32)]
+        step = eng._step_greedy
+        if kind == "sample":
+            step = eng._step_sample
+            args += [chip(rows, f32), chip(rows, i32), chip(rows, f32),
+                     chip(rows, i32)]
+        compiled = step.lower(*args).compile()
+        for text in (put.as_text(), compiled.as_text()):
+            assert "outfeed" not in text and "infeed" not in text
+            assert " send(" not in text and " recv(" not in text
+        out = jax.tree_util.tree_leaves(compiled.out_info)[0]
+        assert (out.shape, out.dtype) == (rows, i32)
+
     def test_tensor_parallel_step_over_four_chips(self, topo, monkeypatch):
         """The engine's greedy step as tensor-parallel serving runs it
         (shard_map over `mp`, 5 of gpt2-large's 20 heads a chip): each

@@ -185,38 +185,38 @@ def _tiny_gpt():
     return m
 
 
-def _serve(async_on=False, chunk=None, n_requests=3):
+def _serve(chunk=None, n_requests=3, eos=None):
     from paddle_tpu.inference.serving import ServingEngine
 
     m = _tiny_gpt()
     rng = np.random.RandomState(0)
-    paddle.set_flags({"async_dispatch": async_on})
-    try:
-        kw = {} if chunk is None else {"prefill_chunk": chunk}
-        eng = ServingEngine(m, max_batch=2, **kw)
-        trace.clear()
-        for n in (5, 9, 4)[:n_requests]:
-            eng.submit(rng.randint(0, 64, (n,)).astype(np.int32),
-                       max_new_tokens=6)
-        eng.run_until_complete()
-    finally:
-        paddle.set_flags({"async_dispatch": False})
+    kw = {} if chunk is None else {"prefill_chunk": chunk}
+    eng = ServingEngine(m, max_batch=2, eos_token_id=eos, **kw)
+    trace.clear()
+    for n in (5, 9, 4)[:n_requests]:
+        eng.submit(rng.randint(0, 64, (n,)).astype(np.int32),
+                   max_new_tokens=6)
+    eng.run_until_complete()
     return eng, trace.phases()[0]
 
 
 class TestServingPhases:
-    def test_sync_step_tree_counts_and_breakdown(self):
+    def test_step_tree_counts_and_breakdown(self):
         eng, rows = _serve()
         by = _by_name(rows)
         assert set(by) == {"serve/step", "serve/admit", "serve/prefill",
                            "serve/prefill_wait", "serve/decode_dispatch",
                            "serve/decode_wait", "serve/emit"}
         assert {r[3] for r in by["serve/step"]} == {None}
+        # the first tokens of a round's admissions are waited for once,
+        # beside the decode step's, not inside each admission
         for child in ("serve/admit", "serve/decode_dispatch",
-                      "serve/decode_wait", "serve/emit"):
+                      "serve/decode_wait", "serve/prefill_wait",
+                      "serve/emit"):
             assert {r[3] for r in by[child]} == {"serve/step"}
         assert {r[3] for r in by["serve/prefill"]} == {"serve/admit"}
-        assert {r[3] for r in by["serve/prefill_wait"]} == {"serve/prefill"}
+        assert len(by["serve/prefill_wait"]) == len(
+            [r for r in by["serve/admit"] if r[5]["admitted"]])
         steps = by["serve/step"]
         assert len(steps) == eng.stats()["health"]["steps"]
         assert len(by["serve/admit"]) == len(steps)
@@ -248,9 +248,10 @@ class TestServingPhases:
         assert bd["kinds"]["prefill"]["count"] == 3
         assert bd["kinds"]["prefill"]["wall_ms"] == \
             pytest.approx(_ms(by["serve/prefill"]), rel=1e-9)
+        # a step's slice: its dispatch in one call, its wait in the next
         decode = bd["kinds"]["decode_greedy"]
         assert decode["count"] == len(by["serve/decode_wait"]) == \
-            st["steps"]["decode_greedy"]
+            len(by["serve/decode_dispatch"]) == st["steps"]["decode_greedy"]
         assert decode["wall_ms"] == pytest.approx(
             _ms(by["serve/decode_dispatch"]) + _ms(by["serve/decode_wait"]),
             rel=1e-9)
@@ -270,53 +271,61 @@ class TestServingPhases:
         assert {r[3] for r in chunks} == {"serve/admit"}
         assert len(chunks) == eng.stats()["steps"]["prefill_chunk"]
         assert all(set(r[5]) == {"slot", "offset", "width"} for r in chunks)
-        assert {r[3] for r in by["serve/prefill_wait"]} == \
-            {"serve/prefill_chunk"}
+        assert {r[3] for r in by["serve/prefill_wait"]} == {"serve/step"}
         bd = eng.stats()["breakdown"]["kinds"]["prefill_chunk"]
         assert bd["count"] == len(chunks)
         assert bd["wall_ms"] == pytest.approx(_ms(chunks), rel=1e-9)
 
-    def test_async_split_is_the_phases_and_spans_share_their_reads(self):
+    def test_lookahead_round_is_the_phases_in_order_with_their_counts(self):
         trace.enable()
         try:
-            eng, rows = _serve(async_on=True)
+            eng, rows = _serve(eos=9)     # the second request's 2nd token
             spans = trace.spans()
         finally:
             trace.disable()
         by = _by_name(rows)
-        a = eng.stats()["breakdown"]["async_overlap"]
-        assert set(a) == {"dispatch_ms", "overlap_ms", "fetch_ms", "rounds",
-                          "dispatch_fraction"}
-        assert a["rounds"] == len(by["serve/step"])
-        assert a["dispatch_ms"] == pytest.approx(
-            _ms(by["serve/decode_dispatch"]), rel=1e-9)
-        assert a["overlap_ms"] == pytest.approx(_ms(by["serve/admit"]),
-                                                rel=1e-9)
-        assert a["fetch_ms"] == pytest.approx(_ms(by["serve/decode_wait"]),
-                                              rel=1e-9)
-        covered = a["dispatch_ms"] + a["overlap_ms"] + a["fetch_ms"]
-        assert a["dispatch_fraction"] == pytest.approx(
-            (a["dispatch_ms"] + a["overlap_ms"]) / covered)
-        # in the async round the admission window sits between dispatch
-        # and wait, inside the same root
-        for adm in by["serve/admit"]:
-            disp = [r for r in by["serve/decode_dispatch"]
-                    if r[4] == adm[4]]
-            assert len(disp) == 1 and disp[0][2] <= adm[1]
-        # the dispatch/* spans carry the phases' own two clock reads
-        for span_name, phase_name in (
-                ("dispatch/decode", "serve/decode_dispatch"),
-                ("dispatch/overlap", "serve/admit"),
-                ("dispatch/fetch", "serve/decode_wait")):
-            got = sorted((s.start_ns, s.end_ns) for s in spans
-                         if s.name == span_name)
-            assert got == sorted((r[1], r[2]) for r in by[phase_name])
-        # a decode kind's slice: dispatch + wait of the rounds that decoded
-        decoded = {r[4] for r in by["serve/decode_wait"]}
+        assert "async_overlap" not in eng.stats()["breakdown"]
+        assert not [sp for sp in spans if sp.name.startswith("dispatch/")]
+        la = eng.stats()["lookahead"]
+        assert set(la) == {"rounds", "rounds_overlapped",
+                           "tokens_discarded", "in_flight"}
+        disp = by["serve/decode_dispatch"]
+        assert la["rounds"] == len(disp) == eng.stats()["steps"][
+            "decode_greedy"]
+        assert la["rounds_overlapped"] == sum(r[5]["in_flight"]
+                                              for r in disp)
+        assert la["tokens_discarded"] == sum(r[5]["discarded"]
+                                             for r in by["serve/emit"]) == 1
+        assert la["in_flight"] == 0 and eng._flight is None
+        # inside a round: admissions, the NEXT step's dispatch, and only
+        # then the read of the step dispatched a round ago, the read of
+        # the admissions' first tokens, the emit
+        order = ["serve/admit", "serve/decode_dispatch",
+                 "serve/decode_wait", "serve/prefill_wait", "serve/emit"]
+        for root in by["serve/step"]:
+            inside = sorted((r for r in rows
+                             if r[4] == root[4] and r[3] == "serve/step"),
+                            key=lambda r: r[1])
+            names = [r[0] for r in inside]
+            assert names == sorted(names, key=order.index)
+            assert all(a[2] <= b[1] for a, b in zip(inside, inside[1:]))
+            ds = [r for r in inside if r[0] == "serve/decode_dispatch"]
+            # a call that finds nothing in flight dispatches twice, the
+            # first with nothing ahead of it; every other dispatch goes
+            # out with the step before it still unread
+            assert [r[5]["in_flight"] for r in ds] in ([1], [0, 1], [0], [])
+        first = min(by["serve/step"], key=lambda r: r[1])
+        assert [r[5]["in_flight"] for r in disp if r[4] == first[4]] == \
+            [0, 1]
+        # a decode kind's slice: the dispatches that were read + the waits
+        # (a step dispatched for rows that all ended by eos is dropped
+        # unread: it has a dispatch phase, no wait, and is not booked)
         decode = eng.stats()["breakdown"]["kinds"]["decode_greedy"]
-        assert decode["wall_ms"] == pytest.approx(
-            a["fetch_ms"] + _ms([r for r in by["serve/decode_dispatch"]
-                                 if r[4] in decoded]), rel=1e-9)
+        assert decode["count"] == len(by["serve/decode_wait"])
+        assert len(disp) - decode["count"] in (0, 1)
+        assert decode["wall_ms"] <= _ms(disp) + _ms(
+            by["serve/decode_wait"]) + 1e-9
+        assert decode["wall_ms"] >= _ms(by["serve/decode_wait"])
 
 
 class TestTrainerPhases:
