@@ -15,6 +15,13 @@ autocast, dropout 0; random weights from --seed), in ONE process:
          greedy output equals model.generate on the same prompt (on the
          chip: the decode steps read live cache tiles only, and the output
          may part from generate's at a tie of two logits, TIE_GAP).
+  serve_hybrid  the solar_open2 family (3 KDA linear-attention layers to 1
+         gated GQA layer, routed experts with a shared one) at the widths of
+         the benchmark's cell, 16 slots, a dozen prompts of 32..255 tokens,
+         16 new tokens each, through the same ServingEngine. Checks: the
+         lookahead loop engaged, every request ran to its length, and every
+         served token lies within the cell's own `token_gap` limit of the
+         best logit of the benchmark's plain float32 reference.
 
 With no arguments it needs one TPU chip and refuses to run without one (exit
 2, nothing on stdout — never a smaller model on the CPU). Any phase that
@@ -242,6 +249,88 @@ def phase_serve(cfg_kw, args, events, on_chip):
                 f"{results[rids[0]].tokens.tolist()} vs {ref.tolist()}")
 
 
+def phase_serve_hybrid(args, events, on_chip):
+    """A dozen requests through the solar_open2 family at the benchmark
+    cell's widths (benchmark/configs/solar-open2-250b.json: one period of 3
+    KDA layers to 1 GQA layer, experts 0-39 of 320, an eighth of the
+    vocabulary; bf16, 16 slots, T 1024), by the normal path: the Layer's
+    constructor, ServingEngine.submit/step through the lookahead loop. Every
+    served token is held to the benchmark's plain reference by the cell's
+    own limit (`token_gap`: how far its float32 reference logit lies below
+    the reference's best)."""
+    import gc
+    import os
+
+    import jax.numpy as jnp
+
+    from benchmark import compare, hybrid_weights
+    from benchmark.reference import solar_open2 as reference
+    from benchmark.runners.serve_hybrid import program_config
+    from paddle_tpu.models import SolarOpen2ForCausalLM
+
+    here = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "benchmark")
+
+    def load(*parts):
+        with open(os.path.join(here, *parts)) as f:
+            return json.load(f)
+
+    rehearse = args.rehearse
+    cfg = load("configs", "solar-open2-tiny-rehearsal.json" if rehearse
+               else "solar-open2-250b.json")
+    limit = load("workloads", ("rehearsal-serve-hybrid-tiny.json" if rehearse
+                               else "solar-open2-250b.serve-backlog-2k.json")
+                 )["limits"]["token_gap"]
+    T, slots, buckets, new_tokens = (128, 4, (16, 32, 64), 6) if rehearse \
+        else (1024, 16, (64, 128, 256), 16)
+    t0 = time.perf_counter()
+    model = SolarOpen2ForCausalLM(
+        program_config(cfg, T), dtype="bfloat16",
+        initializer=hybrid_weights.initializer(cfg, args.seed,
+                                               round_to="bfloat16"))
+    eng = ServingEngine(model, max_batch=slots, dtype="bfloat16",
+                        prompt_buckets=buckets)
+    rng = np.random.RandomState(args.seed)
+    prompts = [rng.randint(0, cfg["vocab_size"],
+                           (int(rng.randint(buckets[0] // 2, buckets[-1])),)
+                           ).astype(np.int32) for _ in range(12)]
+    rids = [eng.submit(p, max_new_tokens=new_tokens) for p in prompts]
+    results = eng.run_until_complete()
+    served = [np.asarray(results[r].tokens) for r in rids]
+    st = eng.stats()
+    serve_s = time.perf_counter() - t0
+    if st["lookahead"]["rounds_overlapped"] < st["lookahead"]["rounds"] - 2:
+        raise AssertionError("serve_hybrid: the lookahead loop did not "
+                             f"engage: {st['lookahead']}")
+    del eng, model, results
+    gc.collect()
+    t0 = time.perf_counter()
+    P = hybrid_weights.flat(cfg, args.seed, round_to="bfloat16")
+    D = reference.dims_of(cfg)
+    worst = 0.0
+    for prompt, toks in zip(prompts, served):
+        ids = np.zeros((buckets[-1] + new_tokens,), np.int32)
+        n = len(prompt) + len(toks)
+        ids[:n] = np.concatenate([prompt, toks])
+        logits = np.asarray(reference.sequence_logits(P, jnp.asarray(ids),
+                                                      D))
+        worst = max(worst, float(
+            compare.token_gaps(logits, len(prompt), toks).max()))
+    say(phase="serve_hybrid", prompt_lens=[len(p) for p in prompts],
+        new_tokens=new_tokens, serve_s=round(serve_s, 2),
+        reference_s=round(time.perf_counter() - t0, 2),
+        token_gap=round(worst, 5), limit=limit,
+        moe={k: st[k] for k in st if k.startswith("moe_")},
+        state_bytes_held=st["state_bytes"]["held"], **events.take())
+    if len(served) != 12 or any(len(t) != new_tokens for t in served):
+        raise AssertionError("serve_hybrid: a request did not run to its "
+                             f"length: {[len(t) for t in served]}")
+    if not worst <= limit:
+        raise AssertionError(
+            f"serve_hybrid: a served token lies {worst:.4f} under the "
+            f"reference's best logit (the cell's limit: {limit})")
+
+
 def first_difference(model, prompt, got, ref):
     """Where two greedy continuations of `prompt` first differ, and how far
     apart the two picks lie by the model's own float32 forward over what was
@@ -336,6 +425,7 @@ def main(argv=None):
     else:
         phase_train(cfg_kw, batch, args, events, on_chip)
         phase_serve(cfg_kw, args, events, on_chip)
+        phase_serve_hybrid(args, events, on_chip)
     stats = dev.memory_stats() or {}
     say(total_s=round(time.perf_counter() - t0, 2),
         peak_bytes_in_use=stats.get("peak_bytes_in_use"))

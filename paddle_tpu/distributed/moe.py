@@ -14,6 +14,15 @@ TPU-native design (not a port of any CUDA MoE):
 
 All functions here are pure jnp functions over raw arrays (usable under jit/vjp);
 `paddle_tpu.nn.MoELayer` wraps them for the Layer API.
+
+The DROPLESS form (`sigmoid_topk_routing`, `moe_dropless`; `paddle_tpu.nn.DroplessMoELayer`)
+is the serving one: no capacity, no token ever dropped under any imbalance. A layer is told
+which experts it HOLDS (`held=(first, count)` of the router's `num_experts`: one chip's share
+of an expert-parallel deployment), routes over all of them and computes its own experts'
+part of the result. Assignments are sorted by expert, each held expert's run is padded to
+whole tiles of `tile` rows, and one loop walks the tiles that exist: work grows with the
+assignments that land on held experts, never with tokens x k x experts. Its trip count is
+data, so it is a forward-only form; training keeps the capacity form above.
 """
 import functools
 import math
@@ -21,6 +30,8 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+from ..core.device import on_tpu
 
 
 def compute_capacity(num_tokens, num_experts, k, capacity_factor, multiple_of=4):
@@ -147,3 +158,141 @@ def expert_parallel_moe(x, gate_w, w1, b1, w2, b2, mesh, k=2, capacity_factor=2.
         out_specs=(P(axis_name, None), P()),
     )
     return fn(x, gate_w, w1, b1, w2, b2)
+
+
+# -- the dropless form ---------------------------------------------------------
+
+def f32_operands(a, b):
+    """The operands of a product that is accumulated and returned in float32. The chip
+    takes bfloat16 operands as they are; XLA's CPU runtime has no bfloat16 x bfloat16 ->
+    float32 product for any but the smallest shapes, so off the chip (tests, rehearsals)
+    they are widened."""
+    if a.dtype != jnp.float32 and not on_tpu():
+        return a.astype(jnp.float32), b.astype(jnp.float32)
+    return a, b
+
+
+def dot_f32(a, b):
+    """a @ b accumulated and returned in float32."""
+    return jnp.dot(*f32_operands(a, b), preferred_element_type=jnp.float32)
+
+
+def sigmoid_topk_routing(x, router_w, select_bias, k, normalize=True, scale=1.0):
+    """Sigmoid scores with a selection bias, in float32: x [T, d], router_w [d, E],
+    select_bias [E] or None. The k experts of a token are the top k of `s + bias`; their
+    weights are the scores `s` themselves (the bias only selects), divided by their sum
+    where `normalize`, times `scale`. Returns (experts [T, k] int32, weights [T, k] f32)."""
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST))
+    pick = s if select_bias is None else s + select_bias.astype(jnp.float32)
+    _, experts = jax.lax.top_k(pick, k)
+    weights = jnp.take_along_axis(s, experts, axis=-1)
+    if normalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return experts.astype(jnp.int32), weights * scale
+
+
+def dropless_tile(num_assignments, num_experts):
+    """Rows a tile of the grouped products holds: a power of two from 8 to 256 near twice
+    the assignments an expert expects under even routing, so that a decode step's few rows
+    an expert are not padded to a prefill's tile and a prefill's many do not re-read an
+    expert's weights once every 8 rows."""
+    want = max(1, 2 * num_assignments // max(num_experts, 1))
+    return int(min(256, max(8, 1 << (want - 1).bit_length())))
+
+
+def moe_dropless(x, experts, weights, w_gate, w_up, w_down, held=None, num_experts=None,
+                 tile=None, activation=jax.nn.silu):
+    """The held experts' part of a gated-MLP expert layer, no token dropped.
+
+    x [T, d]; experts, weights [T, k] from the router (over all `num_experts`); w_gate,
+    w_up [count, d, f] and w_down [count, f, d] are the held experts' weights, expert
+    `first + i` at row i. held=(first, count), default all. Returns (y [T, d] float32:
+    sum over a token's held experts of weight * down(act(gate x) * up x), zero for a token
+    with none; counts: int32 scalars `assignments_held`, `rows_computed` (tiles walked x
+    rows a tile) and `experts_touched` (held experts with an assignment))."""
+    T, d = x.shape
+    k = experts.shape[1]
+    count = w_gate.shape[0]
+    first = 0 if held is None else int(held[0])
+    if held is not None and int(held[1]) != count:
+        raise ValueError(f"held={held} names {held[1]} experts, the weights hold {count}")
+    E = int(num_experts or count)
+    N = T * k
+    tm = int(tile or dropless_tile(N, E))
+    max_tiles = -(-N // tm) + count
+    P_rows = max_tiles * tm
+
+    flat_e = experts.reshape(N) - first
+    is_held = (flat_e >= 0) & (flat_e < count)
+    group = jnp.where(is_held, flat_e, count)             # the rest sorts last
+    sizes = jnp.sum(group[:, None] == jnp.arange(count, dtype=jnp.int32)[None, :],
+                    axis=0, dtype=jnp.int32)
+    tiles = (sizes + tm - 1) // tm                        # tiles an expert's run takes
+    tile_end = jnp.cumsum(tiles)
+    n_tiles = tile_end[-1]
+    tile_start = tile_end - tiles
+    run_start = jnp.cumsum(sizes) - sizes
+    # gathers only (a sort and its inverse), no scatter: the assignments in expert order,
+    # then for every padded row the assignment it carries, if any
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        tile_end, jnp.arange(max_tiles, dtype=jnp.int32), side="right"),
+        count - 1).astype(jnp.int32)
+    p_row = jnp.arange(P_rows, dtype=jnp.int32)
+    tau = p_row // tm
+    e_p = tile_expert[tau]
+    r_p = (tau - tile_start[e_p]) * tm + p_row % tm
+    carries = (tau < n_tiles) & (r_p < sizes[e_p])
+    a_p = order[jnp.clip(run_start[e_p] + r_p, 0, N - 1)]
+    src = jnp.where(carries, a_p // k, T)
+    x_pad = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[src]   # [P_rows, d]
+    # and for every assignment the padded row that carries it
+    safe = jnp.minimum(group, count - 1)
+    rank = jnp.argsort(order).astype(jnp.int32) - run_start[safe]
+    row = jnp.clip(tile_start[safe] * tm + rank, 0, P_rows - 1)
+
+    def body(t, y_pad):
+        e = tile_expert[t]
+        xt = jax.lax.dynamic_slice_in_dim(x_pad, t * tm, tm, 0)
+        wg = jax.lax.dynamic_index_in_dim(w_gate, e, 0, keepdims=False)
+        wu = jax.lax.dynamic_index_in_dim(w_up, e, 0, keepdims=False)
+        wd = jax.lax.dynamic_index_in_dim(w_down, e, 0, keepdims=False)
+        a = activation(dot_f32(xt, wg)) * dot_f32(xt, wu)
+        yt = dot_f32(a.astype(x.dtype), wd)
+        return jax.lax.dynamic_update_slice_in_dim(y_pad, yt.astype(x.dtype), t * tm, 0)
+
+    y_pad = jax.lax.fori_loop(0, n_tiles, body, jnp.zeros((P_rows, d), x.dtype))
+    picked = y_pad[row].astype(jnp.float32)               # [N, d]
+    w_flat = jnp.where(is_held, weights.reshape(N), 0.0)
+    y = jnp.sum((picked * w_flat[:, None]).reshape(T, k, d), axis=1)
+    counts = {"assignments_held": jnp.sum(sizes), "rows_computed": n_tiles * tm,
+              "experts_touched": jnp.sum(sizes > 0, dtype=jnp.int32)}
+    return y, counts
+
+
+def gated_mlp(x, w_gate, w_up, w_down, activation=jax.nn.silu):
+    """down(act(gate x) * up x): one expert over all rows (a shared expert), float32."""
+    a = activation(dot_f32(x, w_gate)) * dot_f32(x, w_up)
+    return dot_f32(a.astype(x.dtype), w_down)
+
+
+def moe_dropless_layer(x, router_w, select_bias, w_gate, w_up, w_down, k, shared=None,
+                       held=None, normalize=True, scale=1.0, tile=None,
+                       activation=jax.nn.silu, router_x=None):
+    """Router, the held experts' part and the shared expert (`shared`: its (gate, up,
+    down) or None), x [T, d]. The router's width is `num_experts`, whatever is held.
+    `router_x`: the router's own input where the caller has x wider than the experts take
+    it (the float32 it rounded to bfloat16: a router decides by differences of a hundredth
+    between scores, and should not decide by a rounding). Returns (y [T, d] float32,
+    counts: `assignments` (static), `assignments_held`, `rows_computed`,
+    `experts_touched`)."""
+    experts, weights = sigmoid_topk_routing(x if router_x is None else router_x, router_w,
+                                            select_bias, k, normalize, scale)
+    y, counts = moe_dropless(x, experts, weights, w_gate, w_up, w_down, held=held,
+                             num_experts=router_w.shape[1], tile=tile,
+                             activation=activation)
+    if shared is not None:
+        y = y + gated_mlp(x, *shared, activation=activation)
+    counts = dict(counts, assignments=jnp.int32(x.shape[0] * k))
+    return y, counts

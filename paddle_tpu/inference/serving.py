@@ -156,7 +156,7 @@ _DEADLINE = _monitor.counter(
 # the lookahead loop's decode step dispatched and not read yet: its device
 # tokens, the [(slot, request)] it was dispatched for, its kind, and the
 # `serve/decode_dispatch` phase it was dispatched in
-_Flight = collections.namedtuple("_Flight", "toks rows kind disp")
+_Flight = collections.namedtuple("_Flight", "toks rows kind disp counts")
 
 
 class _MsSummary:
@@ -319,6 +319,37 @@ class ServingEngine:
         self._dm = dm
         cfg = model.cfg
         dm.check_config(cfg)
+        # the cache as the adapter describes it: every leaf's kind (`kv`
+        # grows with the context and is written at `pos`; `recurrent` and
+        # `conv` have a fixed size and are replaced every step), its slot
+        # axis and its layers. A family with fixed-size state is served
+        # by the dense engine alone: refused here, by name, where another
+        # engine is asked for
+        self._state_leaves = _dm_registry.state_leaves(dm.cache_spec(cfg))
+        self._fixed_state = any(leaf["kind"] != "kv"
+                                for leaf in self._state_leaves)
+        #: names of the counts a decode step of this family returns
+        #: beside its tokens (device values, read with the tokens)
+        self._count_names = tuple(getattr(dm, "step_counts", ()))
+        if self._fixed_state:
+            asked = [what for what, on in (
+                ("FLAGS_paged_kv (a paged pool holds `kv` pages only)",
+                 _flags.get_flag("paged_kv", False)),
+                ("draft_model= (a speculative round would have to take "
+                 "back the state its rejected tokens changed)",
+                 draft_model is not None),
+                ("tp_mesh= (its experts and state are not sharded over "
+                 "'mp')", tp_mesh is not None),
+                ("max_adapters= / lora_rank= (no LoRA sites)",
+                 max_adapters is not None or lora_rank is not None),
+                ("cache_dtype= (its recurrent state is float32 by the "
+                 "configuration)", cache_dtype is not None)) if on]
+            if asked:
+                raise ValueError(
+                    f"decode model {dm.name!r} keeps fixed-size state "
+                    "(recurrent, conv) beside its keys and values and is "
+                    "served by the dense engine with its lookahead loop; "
+                    "it does not compose with " + "; ".join(asked))
         self.cfg = cfg
         self.B = int(max_batch)
         self.T = cfg.max_seq_len
@@ -484,6 +515,16 @@ class ServingEngine:
                 out_shardings=jax.tree_util.tree_map(
                     lambda s: shard, side_tpl))
 
+        # bytes of state the engine holds, by kind, and what one decode
+        # step has to move of them: a `kv` leaf's live columns read and
+        # one column a row written, a fixed-size leaf read and written
+        # whole (stats()["state_bytes"]; the `state_bytes_*` counts of
+        # `serve/decode_dispatch`). A paged engine's pool keeps its own.
+        self._state_held = {} if _paged else _dm_registry.bytes_by_kind(
+            self._state_leaves, (self._kc, self._vc))
+        self._kv_col_bytes = self._state_held.get("kv", 0) // (self.B
+                                                               * self.T)
+
         # the width of the tiles a one-token decode step reads each row's
         # cache in (stats()["kv_tiles_read"] / ["kv_tiles_held"]): the
         # model's, where its step stops at each row's position, else the
@@ -493,12 +534,33 @@ class ServingEngine:
         self._kv_tile = dm.kv_read_tile(cfg, k_side, cache_dt,
                                         tp_size) or self.T
 
+        fixed_state, count_names = self._fixed_state, self._count_names
+
+        def valid(n):
+            """A whole-sequence call of a family with fixed-size state is
+            told how many of its positions are the sequence's own: the
+            padded ones leave that state exactly as it was (a K/V column
+            past the end is junk nobody sees; a recurrent state changed
+            by padding is changed for good)."""
+            return {"valid_len": n} if fixed_state else {}
+
+        def decode(p, toks, pos_vec, kc, vc):
+            """One token a row through the stack: (x, kc, vc, extra), extra
+            the family's own counts of the step as a 1-tuple, or ()."""
+            if count_names:
+                x, kc, vc, counts = fwd(p, toks, pos_vec, kc, vc,
+                                        counts=True)
+                return x, kc, vc, (counts,)
+            return fwd(p, toks, pos_vec, kc, vc) + ((),)
+
         def prefill(p, ids_padded, true_len):
             """ids_padded [1, Pb] right-padded; returns (kc1, vc1,
             last_logits [vocab]). Junk beyond true_len is causally
-            invisible and later overwritten by the decode loop."""
+            invisible and later overwritten by the decode loop; fixed-size
+            state comes back as it stood AT true_len (`valid`)."""
             kc1, vc1 = cache_init(1, self.T, cache_dt)
-            x, kc1, vc1 = fwd(p, ids_padded, 0, kc1, vc1)
+            x, kc1, vc1 = fwd(p, ids_padded, 0, kc1, vc1,
+                              **valid(true_len))
             x_last = jax.lax.dynamic_slice_in_dim(
                 x, true_len - 1, 1, axis=1)[:, 0]
             return kc1, vc1, logits_of(p, x_last).astype(jnp.float32)[0]
@@ -511,22 +573,38 @@ class ServingEngine:
             side cache; returns updated cache + the logits at
             last_in_chunk (only meaningful on the final chunk — junk
             columns beyond it are causally invisible/overwritten)."""
-            x, kc1, vc1 = fwd(p, chunk_ids, offset, kc1, vc1)
+            x, kc1, vc1 = fwd(p, chunk_ids, offset, kc1, vc1,
+                              **valid(last_in_chunk + 1))
             x_last = jax.lax.dynamic_slice_in_dim(
                 x, last_in_chunk, 1, axis=1)[:, 0]
             return kc1, vc1, logits_of(p, x_last).astype(jnp.float32)[0]
 
-        def admit(big, row, r):
-            """Copy a 1-row cache into row r of the big cache (r traced —
-            one compile covers every slot)."""
+        def make_admit(slot_axes):
+            """`slot_axes`: one half of the cache pair's tree with every
+            leaf's slot axis in the leaf's place."""
 
-            def put(b_leaf, r_leaf):
-                return jax.lax.dynamic_update_slice(
-                    b_leaf, r_leaf, (0, r, 0, 0, 0))
+            def admit(big, row, r):
+                """Copy a 1-row cache into row r of the big cache (r
+                traced — one compile covers every slot): every leaf's
+                slot, whole, along the axis its description names. For a
+                `kv` leaf that may leave junk past the new request's
+                columns; a fixed-size leaf is replaced whole, which is
+                what frees a slot of its last request's state."""
 
-            if isinstance(big, tuple):
-                return (put(big[0], row[0]), put(big[1], row[1]))
-            return put(big, row)
+                def put(b_leaf, r_leaf, axis):
+                    return jax.lax.dynamic_update_slice(
+                        b_leaf, r_leaf, tuple(
+                            r if i == axis else 0
+                            for i in range(b_leaf.ndim)))
+
+                return jax.tree_util.tree_map(put, big, row, slot_axes)
+
+            return admit
+
+        row_tpl = jax.eval_shape(lambda: cache_init(1, self.T, cache_dt))
+        self._row_template = row_tpl
+        axes_k, axes_v = (_dm_registry.slot_axes(self._state_leaves, i, t)
+                          for i, t in enumerate(row_tpl))
 
         vocab = cfg.vocab_size
 
@@ -570,17 +648,20 @@ class ServingEngine:
             """One decode step for ALL slots at their own positions —
             argmax only (the default workload keeps its lean hot loop:
             no sort/categorical machinery compiled in)."""
-            x, kc, vc = fwd(p, last_toks[:, None], pos_vec, kc, vc)
+            x, kc, vc, extra = decode(p, last_toks[:, None], pos_vec, kc,
+                                      vc)
             logits = logits_of(p, x[:, 0]).astype(jnp.float32)
-            return jnp.argmax(logits, -1).astype(jnp.int32), kc, vc
+            return (jnp.argmax(logits, -1).astype(jnp.int32), kc, vc) + extra
 
         def step_sample(p, kc, vc, last_toks, pos_vec, temps, kvec,
                         pvec, seeds):
             """Decode step with per-request sampling knobs [B] (used only
             while at least one active request has temperature > 0)."""
-            x, kc, vc = fwd(p, last_toks[:, None], pos_vec, kc, vc)
+            x, kc, vc, extra = decode(p, last_toks[:, None], pos_vec, kc,
+                                      vc)
             logits = logits_of(p, x[:, 0]).astype(jnp.float32)
-            return _pick(logits, temps, kvec, pvec, seeds, pos_vec), kc, vc
+            return (_pick(logits, temps, kvec, pvec, seeds, pos_vec), kc,
+                    vc) + extra
 
         if _paged:
             _paging_mod = self._paging
@@ -684,7 +765,11 @@ class ServingEngine:
                 donate=(3, 4)), label="prefill_chunk")
         # admit slices only the batch axis: a plain jit partitions it
         # fine over the head-sharded cache
-        self._admit = _cj(admit, "admit", donate=(0,))
+        self._admit = _cj(make_admit(axes_k), "admit", donate=(0,))
+        # the pair's second half: the same program where it is described
+        # as the first is (a K/V pair), its own where it holds other kinds
+        self._admit_second = self._admit if axes_k == axes_v else _cj(
+            make_admit(axes_v), "admit_fixed", donate=(0,))
         # the prefill token goes through the SAME pick as decode steps
         def pick1(lg, t, k, tp, s, p_):
             return _pick(lg[None], t[None], k[None], tp[None], s[None],
@@ -840,6 +925,8 @@ class ServingEngine:
                    "prefix_hit": 0, "prefix_miss": 0,
                    "occupancy_sum": 0, "occupancy_steps": 0,
                    "kv_tiles_read": 0, "kv_tiles_held": 0,
+                   "state_bytes_moved": {k: 0 for k in self._state_held},
+                   "step_counts": {n: 0 for n in self._count_names},
                    "lookahead": {"rounds": 0, "rounds_overlapped": 0,
                                  "tokens_discarded": 0},
                    "queue_wait_ms": _MsSummary(), "ttft_ms": _MsSummary(),
@@ -866,6 +953,7 @@ class ServingEngine:
         # (slot, request, device token)
         self._flight = None
         self._firsts = []
+        self._step_counts = None    # the newest dispatched step's own
         self._temps = np.zeros(self.B, np.float32)   # 0 = greedy
         self._topk = np.full(self.B, self.cfg.vocab_size, np.int32)
         self._topp = np.ones(self.B, np.float32)     # 1.0 = no nucleus
@@ -1072,6 +1160,9 @@ class ServingEngine:
         # live _activate call passes it
         warm(self._admit, kc, kc1, 0)
         warm(self._copy_cache, kc1)
+        if self._admit_second is not self._admit:
+            warm(self._admit_second, vc, vc1, 0)
+            warm(self._copy_cache, vc1)
         if self._chunk is not None:
             warm(self._prefill_chunk, p, i32((1, self._chunk)), i32(),
                  kc1, vc1, i32())
@@ -1143,6 +1234,11 @@ class ServingEngine:
             "batch_occupancy_avg": occ,
             "kv_tiles_read": m["kv_tiles_read"],
             "kv_tiles_held": m["kv_tiles_held"],
+            # bytes of state by kind (kv, recurrent, conv): what the engine
+            # holds, and what its decode steps so far had to move of it
+            "state_bytes": {"held": dict(self._state_held),
+                            "moved": dict(m["state_bytes_moved"])},
+            **m["step_counts"],
             # how often the lookahead loop engages: decode steps
             # dispatched, those dispatched while the one before was still
             # unread, and columns computed for rows that had finished
@@ -1714,18 +1810,24 @@ class ServingEngine:
         from ..analysis import handoff_schema as _hs
         from ..serving.disagg import HANDOFF_SCHEMA
 
-        side = self._kc[0] if isinstance(self._kc, tuple) else self._kc
-        dims = {}
-        if getattr(side, "ndim", 0) == 5:
-            L, _, KVh, T, hd = side.shape
-            dims = {"L": int(L), "KVh": int(KVh), "T": int(T),
-                    "hd": int(hd)}
-        vocab = getattr(self.cfg, "vocab_size", None)
-        if vocab:
-            dims["V"] = int(vocab)
-        _hs.validate(HANDOFF_SCHEMA,
-                     {"kc": kc1, "vc": vc1, "logits": logits},
-                     dims=dims, dtypes={"cache": str(side.dtype)})
+        if self._fixed_state:
+            # a described tree of state kinds: the row is held to the
+            # one-slot tree this engine's own prefill makes, leaf by leaf
+            _dm_registry.check_row(self._state_leaves, self._row_template,
+                                   (kc1, vc1))
+        else:
+            side = self._kc[0] if isinstance(self._kc, tuple) else self._kc
+            dims = {}
+            if getattr(side, "ndim", 0) == 5:
+                L, _, KVh, T, hd = side.shape
+                dims = {"L": int(L), "KVh": int(KVh), "T": int(T),
+                        "hd": int(hd)}
+            vocab = getattr(self.cfg, "vocab_size", None)
+            if vocab:
+                dims["V"] = int(vocab)
+            _hs.validate(HANDOFF_SCHEMA,
+                         {"kc": kc1, "vc": vc1, "logits": logits},
+                         dims=dims, dtypes={"cache": str(side.dtype)})
         # the bound check runs AFTER validation (matching submit()): an
         # unservable request must fail permanently (ValueError), never
         # masquerade as retryable backpressure
@@ -1912,7 +2014,7 @@ class ServingEngine:
                 req.adapter)
         else:
             self._kc = self._admit(self._kc, kc1, slot)
-            self._vc = self._admit(self._vc, vc1, slot)
+            self._vc = self._admit_second(self._vc, vc1, slot)
         if draft_caches is not None:
             kc1d, vc1d = draft_caches
             self._kc_d = self._admit(self._kc_d, kc1d, slot)
@@ -2053,7 +2155,7 @@ class ServingEngine:
             tokens=n, bucket=pb)
         try:
             with _trace.phase("serve/prefill", slot=slot, tokens=n,
-                              bucket=pb) as ph:
+                              bucket=pb, true_len=n) as ph:
                 padded = np.zeros((1, pb), np.int32)
                 padded[0, :n] = req.prompt_ids
                 kc1, vc1, logits = self._prefill(self._params,
@@ -2127,7 +2229,7 @@ class ServingEngine:
             tokens=n, bucket=pb, paged=True)
         try:
             with _trace.phase("serve/prefill", slot=slot, tokens=n,
-                              bucket=pb) as ph:
+                              bucket=pb, true_len=n) as ph:
                 padded = np.zeros((1, pb), np.int32)
                 padded[0, :n] = req.prompt_ids
                 kc1, vc1, logits = self._prefill_pg(
@@ -2153,12 +2255,31 @@ class ServingEngine:
         The step walks every row, a free one (position 0) as well. A
         speculative round's verify (several columns a row) reads all."""
         held = read = self.B * -(-self.T // self._kv_tile)
+        cols = np.minimum(self._pos, self.T - 1)
         if one_token:
-            read = int((np.minimum(self._pos, self.T - 1)
-                        // self._kv_tile + 1).sum())
+            read = int((cols // self._kv_tile + 1).sum())
         disp.counts.update(kv_tiles_read=read, kv_tiles_held=held)
         self._m["kv_tiles_read"] += read
         self._m["kv_tiles_held"] += held
+        moved = self._m["state_bytes_moved"]
+        for kind, nbytes in self._state_held.items():
+            # live columns read and one a row written; fixed-size state
+            # read and written whole
+            step = (int(cols.sum()) + 2 * self.B) * self._kv_col_bytes \
+                if kind == "kv" else 2 * nbytes
+            disp.counts["state_bytes_" + kind] = step
+            moved[kind] += step
+
+    def _note_step_counts(self, disp, counts):
+        """A decode step's own counts (the adapter's `step_counts`), read
+        once its tokens are: onto the `serve/decode_dispatch` phase that
+        dispatched it (the ring holds the phase's dict) and the sums of
+        stats()."""
+        values = np.asarray(counts).tolist()  # lint: allow(step-loop-host-sync)
+        sums = self._m["step_counts"]
+        for name, v in zip(self._count_names, values):
+            disp.counts[name] = v
+            sums[name] += v
 
     def _dispatch_decode(self, active):
         """Enqueue ONE decode program for the active slots (device work
@@ -2203,14 +2324,17 @@ class ServingEngine:
         last = self._toks if self._lookahead else up(self._last)
         if any(self._temps[s] > 0 for s in active):
             kind = "decode_sample"
-            next_toks, self._kc, self._vc = self._step_sample(
+            next_toks, self._kc, self._vc, *counts = self._step_sample(
                 self._params, self._kc, self._vc, last, up(self._pos),
                 up(self._temps), up(self._topk), up(self._topp),
                 up(self._seeds))
         else:
             kind = "decode_greedy"
-            next_toks, self._kc, self._vc = self._step_greedy(
+            next_toks, self._kc, self._vc, *counts = self._step_greedy(
                 self._params, self._kc, self._vc, last, up(self._pos))
+        # a family's own counts of the step (device values: read with the
+        # tokens, _note_step_counts)
+        self._step_counts = counts[0] if counts else None
         self._count_step(kind)
         return next_toks, kind
 
@@ -2511,7 +2635,7 @@ class ServingEngine:
             n_tokens = self._pos[s] - len(req.prompt_ids) + 1
             if n_tokens >= req.max_new_tokens or self._pos[s] >= self.T:
                 self._release(s)    # `length` or `capacity`, foreseen
-        return _Flight(self._toks, rows, kind, disp)
+        return _Flight(self._toks, rows, kind, disp, self._step_counts)
 
     def _emit_round(self, flight, firsts):
         """The lookahead round's reads and its emit: the tokens of the
@@ -2526,6 +2650,9 @@ class ServingEngine:
             # THE round's host sync, with the next step already running
             with _trace.phase("serve/decode_wait") as wait:
                 toks = np.asarray(flight.toks).tolist()  # lint: allow(step-loop-host-sync)
+                if flight.counts is not None:
+                    # computed by the same program: there with the tokens
+                    self._note_step_counts(flight.disp, flight.counts)
             self._acc_phase(flight.kind, flight.disp, wait)
             decode = (flight.disp.start_ns, wait.end_ns, flight.kind)
         if firsts:

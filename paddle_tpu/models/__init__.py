@@ -32,3 +32,18 @@ from .hf_bridge import (  # noqa: F401
     gpt2_from_huggingface,
     gpt2_to_huggingface,
 )
+
+
+# the solar_open2 family is imported when first asked for: serving GPT-2 pays
+# nothing for it at start-up (PERF.md: PR 27's imports cost 7.9 % of setup_s)
+_LAZY = {"SolarOpen2Config": "solar_open2",
+         "SolarOpen2ForCausalLM": "solar_open2"}
+
+
+def __getattr__(name):
+    if name in _LAZY:
+        import importlib
+
+        return getattr(importlib.import_module("." + _LAZY[name], __name__),
+                       name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -1457,10 +1457,15 @@ class GPTDecodeModel(_decode_model.DecodeModel):
     def cache_spec(self, cfg):
         KVh = getattr(cfg, "num_kv_heads", None) or cfg.num_heads
         hd = cfg.hidden_size // cfg.num_heads
+        side = {"kind": "kv", "slot_axis": 1, "layers": cfg.num_layers}
         return {"kind": "kv_pair",
                 "layout": "[L, B, KVh, T, hd]",
                 "axes": {"L": cfg.num_layers, "KVh": KVh,
                          "T": cfg.max_seq_len, "hd": hd},
+                # the pair as state kinds (serving/decode_model.py): two
+                # leaves that grow with the context, slots on axis 1 (a
+                # quantized side's values and scales both lie under it)
+                "leaves": [dict(side, path=(0,)), dict(side, path=(1,))],
                 "quantized": "per-side (values, scales) tuple when the "
                              "engine's cache_dtype is int8/fp8"}
 

@@ -55,7 +55,7 @@ from .layer.transformer import (  # noqa: E402,F401
     MultiHeadAttention, Transformer, TransformerDecoder, TransformerDecoderLayer,
     TransformerEncoder, TransformerEncoderLayer,
 )
-from .layer.moe import MoELayer  # noqa: E402,F401
+from .layer.moe import DroplessMoELayer, MoELayer  # noqa: E402,F401
 from .clip import ClipGradByGlobalNorm, ClipGradByNorm, ClipGradByValue  # noqa: E402,F401
 from .utils_weight_norm import remove_weight_norm, weight_norm  # noqa: E402,F401
 

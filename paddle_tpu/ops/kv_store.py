@@ -37,13 +37,17 @@ _VMEM_BUDGET = 12 << 20
 def fits(leaf, val):
     """Can `store_columns` take this store? One new column a row, T a whole
     number of lane tiles, hd a whole number of sublane tiles (a quantized
-    cache's [.., T, 1] scales are not: the select stores them), and a
-    [KVh, hd, 128] block that fits."""
+    cache's [.., T, 1] scales are not: the select stores them) and short of
+    a lane tile (from 128 on the chip keeps a leaf hd-minor, and the T-minor
+    view this kernel stores through would copy the whole cache in and out:
+    the same line ops/decode_attention.py draws), and a [KVh, hd, 128]
+    block that fits."""
     _, rows, kvh, t_max, hd = leaf.shape
     item = jnp.dtype(leaf.dtype).itemsize
     vmem = kvh * hd * (4 * LANE * item + 2 * LANE * 4 + rows * 4)
     return (val.shape[2] == 1 and t_max % LANE == 0
-            and hd % (32 // item) == 0 and vmem <= _VMEM_BUDGET)
+            and hd % (32 // item) == 0 and hd < LANE
+            and vmem <= _VMEM_BUDGET)
 
 
 def in_place(leaf, val):

@@ -41,6 +41,24 @@ The protocol (docs/SERVING.md for the full contract):
 ``cache_spec(cfg)``
     Machine-readable description of the cache pytree (layout string,
     axis names, quantized or not) — the handoff contract in data form.
+    Its ``"leaves"`` describe the pair as a tree of STATE KINDS, one dict
+    a leaf: ``path`` (from the pair's root: ``(0,)`` is the first half;
+    a dict key or a tuple index a step; a path covers every leaf under
+    it), ``kind`` (``"kv"``: grows with the context, written at ``pos``,
+    a slot's junk past its request's columns is never seen;
+    ``"recurrent"`` and ``"conv"``: a fixed size whatever the context,
+    replaced every step), ``slot_axis`` and ``layers``. The engine's
+    admission, hand-off rows, row copies and byte counts go by it
+    (:func:`state_leaves`, :func:`slot_axes`, :func:`bytes_by_kind`,
+    :func:`check_row`). A spec without ``"leaves"`` is a K/V pair with
+    slots on axis 1. An adapter that declares a fixed-size kind is handed
+    ``valid_len=`` by every whole-sequence call (docs/SERVING.md "State
+    kinds").
+``step_counts``
+    Optional attribute: names of int32 counts a decode step returns
+    beside its tokens. ``fwd(..., counts=True)`` then returns a fourth
+    value, an int32 vector of them; the engine reads it with the step's
+    tokens and sums it into ``stats()``.
 ``kv_read_tile(cfg, side, dtype, tp_size=1)``
     Optional: the width, in cache columns, of the tiles a one-token
     decode step with per-row positions reads each row in, up to the
@@ -65,7 +83,8 @@ adapter delegates, it never re-implements math.
 import importlib
 
 __all__ = ["DecodeModel", "register_decode_model", "get_decode_model",
-           "registered_decode_models", "resolve", "cache_row_bytes"]
+           "registered_decode_models", "resolve", "cache_row_bytes",
+           "state_leaves", "slot_axes", "bytes_by_kind", "check_row"]
 
 
 class DecodeModel:
@@ -136,7 +155,8 @@ class DecodeModel:
 # import; the _LAZY table lets the serving tier resolve a bundled family
 # without the caller having imported its module first.
 _REGISTRY = {}
-_LAZY = {"gpt": "paddle_tpu.models.gpt"}
+_LAZY = {"gpt": "paddle_tpu.models.gpt",
+         "solar_open2": "paddle_tpu.models.solar_open2"}
 
 
 def register_decode_model(adapter, clobber=False):
@@ -203,3 +223,74 @@ def cache_row_bytes(row):
 
     return int(sum(x.size * x.dtype.itemsize
                    for x in jax.tree_util.tree_leaves(row)))
+
+
+# -- the cache as a described tree of state kinds ------------------------------
+
+_PAIR = ({"path": (0,), "kind": "kv", "slot_axis": 1},
+         {"path": (1,), "kind": "kv", "slot_axis": 1})
+
+
+def state_leaves(spec):
+    """The leaf descriptions of a ``cache_spec``; a spec that has none is
+    a K/V pair with its slots on axis 1."""
+    return tuple(spec.get("leaves") or _PAIR)
+
+
+def _describe(leaves, path):
+    """The description covering the leaf at ``path``: the longest
+    described path that is a prefix of it."""
+    best = None
+    for leaf in leaves:
+        lp = tuple(leaf["path"])
+        if path[:len(lp)] == lp and (best is None
+                                     or len(lp) > len(best["path"])):
+            best = leaf
+    if best is None:
+        raise KeyError(f"cache leaf {path} has no description in the "
+                       "adapter's cache_spec")
+    return best
+
+
+def _with_paths(tree, root=()):
+    import jax
+
+    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    keys = [root + tuple(getattr(k, "key", getattr(k, "idx", None))
+                         for k in path) for path, _ in flat]
+    return keys, [leaf for _, leaf in flat], treedef
+
+
+def slot_axes(leaves, half, tree):
+    """``tree`` (half ``half`` of the cache pair) with every leaf's slot
+    axis in the leaf's place."""
+    keys, _, treedef = _with_paths(tree, (half,))
+    return treedef.unflatten([int(_describe(leaves, k)["slot_axis"])
+                              for k in keys])
+
+
+def bytes_by_kind(leaves, pair):
+    """{kind: device bytes} of a cache pair."""
+    keys, arrays, _ = _with_paths(tuple(pair))
+    out = {}
+    for k, a in zip(keys, arrays):
+        kind = _describe(leaves, k)["kind"]
+        out[kind] = out.get(kind, 0) + int(a.size * a.dtype.itemsize)
+    return out
+
+
+def check_row(leaves, template, row):
+    """Hold a hand-off row to the one-slot tree ``template`` (shapes and
+    dtypes, e.g. from ``jax.eval_shape``): the same tree, every leaf's
+    shape and dtype. Raises ``ValueError`` naming the leaf."""
+    want_k, want, want_def = _with_paths(tuple(template))
+    _, got, got_def = _with_paths(tuple(row))
+    if want_def != got_def:
+        raise ValueError(f"hand-off row is a {got_def}, this engine's state "
+                         f"is a {want_def}")
+    for k, w, g in zip(want_k, want, got):
+        if tuple(w.shape) != tuple(g.shape) or w.dtype != g.dtype:
+            kind = _describe(leaves, k)["kind"]
+            raise ValueError(
+                f"hand-off row leaf {k} ({kind}): {g.dtype}{tuple(g.shape)}"
+                f", this engine holds {w.dtype}{tuple(w.shape)}")

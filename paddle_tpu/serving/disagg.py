@@ -108,10 +108,15 @@ class PrefillWorker:
         fwd, logits_of, cache_init = dm.decode_fns(cfg, aux,
                                                    cache_dtype=cache_dtype)
         cache_dt = self._compute_dtype or jnp.float32
+        # fixed-size state comes back as it stood at true_len, as the
+        # engine's own prefill returns it (docs/SERVING.md "State kinds")
+        fixed_state = any(leaf["kind"] != "kv" for leaf in
+                          _dm_registry.state_leaves(dm.cache_spec(cfg)))
 
         def prefill(p, ids_padded, true_len):
             kc1, vc1 = cache_init(1, self.T, cache_dt)
-            x, kc1, vc1 = fwd(p, ids_padded, 0, kc1, vc1)
+            x, kc1, vc1 = fwd(p, ids_padded, 0, kc1, vc1, **(
+                {"valid_len": true_len} if fixed_state else {}))
             x_last = jax.lax.dynamic_slice_in_dim(
                 x, true_len - 1, 1, axis=1)[:, 0]
             return kc1, vc1, logits_of(p, x_last).astype(jnp.float32)[0]

@@ -392,11 +392,12 @@ class TestDecodeStep:
     (5, 64, 1024, BF16),              # gpt2-large's heads over four chips
     (2, 32, 256, BF16),               # a GQA draft model: 64 lanes of values
     (12, 64, 384, jnp.float32),
-    (20, 64, 1024, jnp.int8), (8, 128, 512, jnp.float8_e4m3fn),
+    (20, 64, 1024, jnp.int8), (8, 96, 512, jnp.float8_e4m3fn),
 ], ids=["tp_local_heads", "narrow_gqa", "f32", "int8", "fp8"])
 def test_kv_store_columns(chip, kvh, hd, t_max, dtype):
     """The in-place store outside gpt2-large's shape: the kernel transposes
-    KVh * hd lanes of new values, whatever their number."""
+    KVh * hd lanes of new values, whatever their number (heads short of a
+    lane tile: from 128 on `fits` refuses, PR 34)."""
     from paddle_tpu.ops import kv_store
 
     leaf, val = chip((3, 8, kvh, t_max, hd), dtype), chip((8, kvh, 1, hd),

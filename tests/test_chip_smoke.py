@@ -44,7 +44,10 @@ def test_rehearsal_runs_train_and_serve_and_never_says_ok():
     rc, lines, err = _run([SMOKE, "--rehearse"])
     assert rc == 0, err[-2000:]
     phases = [ln["phase"] for ln in lines if "phase" in ln]
-    assert phases == ["train", "serve"]
+    assert phases == ["train", "serve", "serve_hybrid"]
+    hybrid = [ln for ln in lines if ln.get("phase") == "serve_hybrid"][0]
+    assert hybrid["token_gap"] <= hybrid["limit"]
+    assert hybrid["moe"]["moe_assignments_held"] > 0
     train = lines[1]
     assert train["losses"][-1] < train["losses"][0]
     assert lines[-1] == {"ok": False, "rehearsal": "passed",

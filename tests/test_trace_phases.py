@@ -236,7 +236,8 @@ class TestServingPhases:
         assert sum(a["prompt_tokens"] for a in admits) == 5 + 9 + 4
         assert sorted(r[5]["tokens"] for r in by["serve/prefill"]) == \
             [4, 5, 9]
-        assert all(set(r[5]) == {"slot", "tokens", "bucket"}
+        assert all(set(r[5]) == {"slot", "tokens", "bucket", "true_len"}
+                   and r[5]["true_len"] == r[5]["tokens"]
                    for r in by["serve/prefill"])
         # stats()["breakdown"]: the keys it had, the values of the phases
         bd = st["breakdown"]
