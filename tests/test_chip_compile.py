@@ -62,9 +62,9 @@ def _custom_calls(fn, *args):
         "tpu_custom_call")
 
 
-def _flash_loss(window=None, mesh=None):
+def _flash_loss(window=None, mesh=None, causal=True):
     def loss(q, k, v):
-        out = fa.flash_attention(q, k, v, causal=True, interpret=False,
+        out = fa.flash_attention(q, k, v, causal=causal, interpret=False,
                                  window=window, mesh=mesh)
         return out.astype(jnp.float32).sum()
 
@@ -92,6 +92,23 @@ class TestFlash:
         q = chip((1, 16384, 12, 64))
         assert _custom_calls(
             jax.grad(_flash_loss(window=4096), argnums=(0, 1, 2)),
+            q, q, q) == 3
+
+    @pytest.mark.parametrize("shape,dtype,causal", [
+        ((4, 1024, 16, 64), BF16, True),
+        ((4, 1024, 16, 64), BF16, False),
+        ((2, 2048, 8, 128), BF16, True),
+        ((4, 1024, 16, 64), jnp.float32, True),
+    ], ids=["the_training_cell", "non_causal", "heads_of_128", "float32"])
+    def test_every_arm_of_the_dtype_and_block_rules(self, chip, shape, dtype,
+                                                    causal):
+        """gpt2-medium.train-1k's own call, and the arms it does not take:
+        operands as loaded (bfloat16) or float32, the causal block rule and
+        the non-causal one, a head as wide as the MXU. Forward and backward,
+        with the blocks the rule picks: 3 custom calls."""
+        q = chip(shape, dtype)
+        assert _custom_calls(
+            jax.grad(_flash_loss(causal=causal), argnums=(0, 1, 2)),
             q, q, q) == 3
 
     def test_fwd_bwd_under_a_dp_mp_mesh(self, topo):

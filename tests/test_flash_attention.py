@@ -1,5 +1,7 @@
 """Flash-attention kernel tests (interpret mode on CPU): fwd + custom-VJP bwd
 against the naive softmax(QK^T)V reference."""
+import contextlib
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -16,6 +18,18 @@ def _naive(q, k, v, causal):
         scores = jnp.where(mask[None, None], scores, -1e30)
     p = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+@contextlib.contextmanager
+def _forced_block(blk):
+    """FLAGS_flash_attention_block = blk for the calls inside."""
+    from paddle_tpu import flags
+
+    flags.set_flags({"flash_attention_block": blk})
+    try:
+        yield
+    finally:
+        flags.set_flags({"flash_attention_block": 0})
 
 
 def _qkv(b=1, s=256, h=2, d=64, seed=0):
@@ -74,6 +88,109 @@ class TestFlashBackward:
         g = jax.grad(loss)(q)
         assert g.dtype == jnp.bfloat16
         assert np.isfinite(np.asarray(g, np.float32)).all()
+
+
+def _windowed_naive(q, k, v, window):
+    s_ = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    n = q.shape[1]
+    qp = jnp.arange(n)[:, None]
+    kp = jnp.arange(n)[None, :]
+    keep = (qp >= kp) & ((qp - kp) < window)
+    s_ = jnp.where(keep[None, None], s_, -1e30)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s_, -1), v)
+
+
+class TestBf16Parity:
+    """bfloat16 calls feed the MXU the blocks as loaded (off the chip:
+    widened, which gives the same products) and round p and dS to bfloat16
+    once; every statistic and accumulator is float32. Held to the naive
+    float32 form ON THE SAME bfloat16 VALUES: what is left is the rounding
+    of p / dS and of the bfloat16 outputs, a few parts in a thousand of
+    each tensor's largest entry. s = 2 x block: one diagonal tile, one tile
+    wholly under the diagonal (which skips the mask), one never computed."""
+
+    @staticmethod
+    def _run(blk, d, mode, window=None):
+        s = 2 * blk
+        q, k, v = [x.astype(jnp.bfloat16)
+                   for x in _qkv(b=1, s=s, h=1, d=d, seed=blk + d)]
+        wt = jnp.asarray(np.random.RandomState(9).randn(1, s, 1, d)
+                         .astype(np.float32))
+        causal = mode != "full"
+
+        def ref(q, k, v):
+            if window is not None:
+                return _windowed_naive(q, k, v, window)
+            return _naive(q, k, v, causal)
+
+        def f(q, k, v):
+            o = flash_attention(q, k, v, causal=causal, interpret=True,
+                                window=window)
+            return jnp.sum(o.astype(jnp.float32) * wt), o
+
+        def fr(q, k, v):
+            o = ref(q, k, v)
+            return jnp.sum(o * wt), o
+
+        with _forced_block(blk):
+            (_, o), g = jax.value_and_grad(f, argnums=(0, 1, 2),
+                                           has_aux=True)(q, k, v)
+        f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+        (_, o_ref), g_ref = jax.value_and_grad(fr, argnums=(0, 1, 2),
+                                               has_aux=True)(*f32)
+        return (o,) + tuple(g), (o_ref,) + tuple(g_ref)
+
+    @pytest.mark.parametrize("d", [64, 128])
+    @pytest.mark.parametrize("blk", [128, 256, 512])
+    @pytest.mark.parametrize("mode", ["full", "causal", "window"])
+    def test_fwd_and_grads_match_naive_f32(self, mode, blk, d):
+        # the window ends inside the tile under the diagonal: both live
+        # tiles of the second q block are masked ones
+        window = blk + blk // 2 if mode == "window" else None
+        got, want = self._run(blk, d, mode, window)
+        for a, b, name in zip(got, want, ("o", "dq", "dk", "dv")):
+            assert a.dtype == jnp.bfloat16
+            b = np.asarray(b)
+            np.testing.assert_allclose(
+                np.asarray(a.astype(jnp.float32)), b, rtol=0,
+                atol=2.0 ** -6 * np.abs(b).max(),
+                err_msg=f"{name} mismatch ({mode}, block {blk}, d {d})")
+
+    @pytest.mark.parametrize("tile", ["diagonal", "under_the_diagonal"])
+    def test_each_kind_of_live_tile_gives_the_masked_result(self, tile):
+        """The first q block meets only its diagonal tile; the second meets
+        the tile under the diagonal first (no mask applied there) and then
+        its own diagonal tile. A key or value no row may see must not move
+        any output bit; one every row of the second block sees must."""
+        blk = 128
+        q, k, v = [x.astype(jnp.bfloat16)
+                   for x in _qkv(b=1, s=2 * blk, h=1, d=64, seed=11)]
+
+        def run(k, v):
+            with _forced_block(blk):
+                return np.asarray(flash_attention(
+                    q, k, v, causal=True, interpret=True)
+                    .astype(jnp.float32))
+
+        out = run(k, v)
+        ref = np.asarray(_naive(*[x.astype(jnp.float32) for x in (q, k, v)],
+                                True))
+        rows = slice(0, blk) if tile == "diagonal" else slice(blk, 2 * blk)
+        np.testing.assert_allclose(out[:, rows], ref[:, rows], rtol=0,
+                                   atol=2.0 ** -6 * np.abs(ref).max())
+        if tile == "diagonal":
+            # position blk - 1 is the last the first block's last row sees:
+            # everything after it is masked inside the diagonal tile
+            k2 = k.at[:, blk:].set(50.0)
+            v2 = v.at[:, blk:].set(-50.0)
+            np.testing.assert_array_equal(run(k2, v2)[:, :blk],
+                                          out[:, :blk])
+        else:
+            # column 0 sits in the tile under the diagonal, seen unmasked
+            # by every row of the second block
+            v2 = v.at[:, 0].add(8.0)
+            moved = np.abs(run(k, v2) - out)[:, blk:].max(axis=(0, 2, 3))
+            assert (moved > 0).all()
 
 
 class TestFlashUnderMesh:
@@ -214,35 +331,22 @@ class TestSlidingWindow:
     compute scales O(s*W); in-band positions mask exactly."""
 
     def _ref(self, q, k, v, w):
-        s_ = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
-        n = q.shape[1]
-        qp = jnp.arange(n)[:, None]
-        kp = jnp.arange(n)[None, :]
-        keep = (qp >= kp) & ((qp - kp) < w)
-        s_ = jnp.where(keep[None, None], s_, -1e30)
-        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s_, -1), v)
+        return _windowed_naive(q, k, v, w)
 
     @pytest.mark.parametrize("window", [1, 64, 100, 256, 1000])
     def test_matches_windowed_reference_multiblock(self, window):
         """s=512 at the forced 128 block -> a 4x4 block grid: the band
         skip predicate, the clip index maps, and the masked-block
         alpha-wipe all execute (a single-block grid tests none of them)."""
-        from paddle_tpu import flags
-
         q, k, v = _qkv(s=512, seed=5)
-        try:
-            flags.set_flags({"flash_attention_block": 128})
+        with _forced_block(128):
             out = flash_attention(q, k, v, causal=True, interpret=True,
                                   window=window)
-        finally:
-            flags.set_flags({"flash_attention_block": 0})
         np.testing.assert_allclose(np.asarray(out),
                                    np.asarray(self._ref(q, k, v, window)),
                                    atol=2e-5, rtol=2e-5)
 
     def test_grads_match_windowed_reference_multiblock(self):
-        from paddle_tpu import flags
-
         q, k, v = _qkv(s=512, seed=6)
         wt = jnp.asarray(np.random.RandomState(7)
                          .randn(*q.shape).astype(np.float32))
@@ -254,11 +358,8 @@ class TestSlidingWindow:
         def fr(q, k, v):
             return jnp.sum(self._ref(q, k, v, 100) * wt)
 
-        try:
-            flags.set_flags({"flash_attention_block": 128})
+        with _forced_block(128):
             g = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-        finally:
-            flags.set_flags({"flash_attention_block": 0})
         gr = jax.grad(fr, argnums=(0, 1, 2))(q, k, v)
         for a, b in zip(g, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -278,3 +379,66 @@ class TestSlidingWindow:
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(self._ref(q, k, v, 64)),
             atol=2e-5, rtol=2e-5)
+
+
+class TestTileCounts:
+    """The static count the audit manifest carries: what a (batch, head)'s
+    grid computes against what the mask leaves, by the kernels' own
+    predicates (_tile_kind, _parts)."""
+
+    @pytest.mark.parametrize("s,causal,window,want", [
+        # 2 x 2 tiles of 512: one is never run, one is clear, two are
+        # diagonal and worked in strips: 36 of 64 cells, all of them needed
+        (1024, True, None, dict(block=512, grid_steps=4, live_tiles=3,
+                                masked_tiles=2, cells_computed=36,
+                                cells_needed=36)),
+        (1024, False, None, dict(block=512, grid_steps=4, live_tiles=4,
+                                 masked_tiles=0, cells_computed=64,
+                                 cells_needed=64)),
+        (8192, True, None, dict(block=512, grid_steps=256, live_tiles=136,
+                                masked_tiles=16, cells_computed=2080,
+                                cells_needed=2080)),
+        # a windowed tile the diagonal or the far edge crosses is worked
+        # whole: 336 cells more than the band needs
+        (16384, True, 4096, dict(block=512, grid_steps=1024, live_tiles=252,
+                                 masked_tiles=56, cells_computed=4032,
+                                 cells_needed=3696)),
+    ], ids=["cell", "non_causal", "8k", "16k_window"])
+    def test_counts(self, s, causal, window, want):
+        from paddle_tpu.ops.flash_attention import tile_counts
+
+        assert tile_counts(s, causal, window) == want
+
+    @pytest.mark.parametrize("by", ["q", "k"])
+    def test_a_diagonal_tile_is_cut_at_the_diagonal(self, by):
+        from paddle_tpu.ops.flash_attention import _parts
+
+        assert _parts(False, 512, by) == [(slice(0, 512), slice(0, 512))]
+        assert _parts(True, 128, by) == [(slice(0, 128), slice(0, 128))]
+        parts = _parts(True, 512, by)
+        assert len(parts) == 4
+        seen = np.zeros((512, 512), bool)
+        for rows, cols in parts:        # (q rows, k rows)
+            assert not seen[rows, cols].any()   # no cell twice
+            seen[rows, cols] = True
+        q, k = np.mgrid[:512, :512]
+        assert seen[q >= k].all()               # every visible cell
+        # and nothing of the corner's three dead 128-cells a side
+        assert seen.sum() == 10 * 128 * 128
+
+    def test_the_manifest_says_what_the_kernels_do(self):
+        from paddle_tpu.ops.flash_attention import audit_manifest
+
+        entries = audit_manifest()
+        assert len(entries) == 2 * 3 * 3    # dtypes x configs x kernels
+        for e in entries:
+            # the MXU is fed the operands' own type; every accumulator
+            # (acc, m, l, dq, dk, dv) is float32
+            assert e["mxu_dtype"] == e["in_dtype"]
+            assert e["acc_dtype"] == "float32"
+            assert all(b["dtype"] == "float32" for b in e["buffers"]
+                       if "scratch" in b["name"])
+            assert e["tiles"]["cells_computed"] >= e["tiles"]["cells_needed"]
+        cell = next(e for e in entries
+                    if e["kernel"] == "flash.fwd[s=1024,d=64,bfloat16,causal]")
+        assert cell["tiles"]["cells_computed"] == 36
