@@ -37,7 +37,10 @@ int8 KV cache (cache_dtype="int8").
 tokens per step() with decode steps for active slots running in between,
 so an arriving 1024-token prompt stalls inter-token latency by one chunk's
 compute, not one full prefill (the whole-prompt path remains the default;
-outputs are identical either way — asserted in tests).
+outputs are identical either way — asserted in tests). ONE slot prefills at
+a time and a round carries ONE chunk: the slot holds a one-row side cache
+until its last chunk, and the queue's head waits for it, so a burst of
+arrivals neither multiplies the stall nor holds a side row an arrival.
 
 `register_prefix(ids)` caches a shared prefix's KV ONCE (system prompts):
 requests submitted with `prefix_id=` start from a copy of that cache and
@@ -322,34 +325,37 @@ class ServingEngine:
         # the cache as the adapter describes it: every leaf's kind (`kv`
         # grows with the context and is written at `pos`; `recurrent` and
         # `conv` have a fixed size and are replaced every step), its slot
-        # axis and its layers. A family with fixed-size state is served
-        # by the dense engine alone: refused here, by name, where another
-        # engine is asked for
-        self._state_leaves = _dm_registry.state_leaves(dm.cache_spec(cfg))
+        # axis and its layers
+        spec = dm.cache_spec(cfg)
+        self._state_leaves = _dm_registry.state_leaves(spec)
+        #: the adapter's cache is a tree of its own and no K/V pair of
+        #: heads: a hand-off row is held to that tree, not to the pair's
+        #: declared schema
+        self._described = spec.get("kind") == "state_tree"
         self._fixed_state = any(leaf["kind"] != "kv"
                                 for leaf in self._state_leaves)
         #: names of the counts a decode step of this family returns
         #: beside its tokens (device values, read with the tokens)
         self._count_names = tuple(getattr(dm, "step_counts", ()))
-        if self._fixed_state:
-            asked = [what for what, on in (
-                ("FLAGS_paged_kv (a paged pool holds `kv` pages only)",
-                 _flags.get_flag("paged_kv", False)),
-                ("draft_model= (a speculative round would have to take "
-                 "back the state its rejected tokens changed)",
-                 draft_model is not None),
-                ("tp_mesh= (its experts and state are not sharded over "
-                 "'mp')", tp_mesh is not None),
-                ("max_adapters= / lora_rank= (no LoRA sites)",
-                 max_adapters is not None or lora_rank is not None),
-                ("cache_dtype= (its recurrent state is float32 by the "
-                 "configuration)", cache_dtype is not None)) if on]
-            if asked:
-                raise ValueError(
-                    f"decode model {dm.name!r} keeps fixed-size state "
-                    "(recurrent, conv) beside its keys and values and is "
-                    "served by the dense engine with its lookahead loop; "
-                    "it does not compose with " + "; ".join(asked))
+        # an engine other than the dense one is refused here, by name,
+        # where the adapter declares it does not serve it (`not_served`:
+        # a family whose `kv` leaves are all the paged pool could hold may
+        # still have no pages of K/V heads to give it)
+        not_served = getattr(dm, "not_served", None) or {}
+        asked = [f"{what} ({not_served[key]})" for key, what, on in (
+            ("paged_kv", "FLAGS_paged_kv",
+             _flags.get_flag("paged_kv", False)),
+            ("draft_model", "draft_model=", draft_model is not None),
+            ("tp_mesh", "tp_mesh=", tp_mesh is not None),
+            ("lora", "max_adapters= / lora_rank=",
+             max_adapters is not None or lora_rank is not None),
+            ("cache_dtype", "cache_dtype=", cache_dtype is not None))
+            if on and key in not_served]
+        if asked:
+            raise ValueError(
+                f"decode model {dm.name!r} is served by the dense engine "
+                "with its lookahead loop; it does not compose with "
+                + "; ".join(asked))
         self.cfg = cfg
         self.B = int(max_batch)
         self.T = cfg.max_seq_len
@@ -1810,7 +1816,7 @@ class ServingEngine:
         from ..analysis import handoff_schema as _hs
         from ..serving.disagg import HANDOFF_SCHEMA
 
-        if self._fixed_state:
+        if self._described:
             # a described tree of state kinds: the row is held to the
             # one-slot tree this engine's own prefill makes, leaf by leaf
             _dm_registry.check_row(self._state_leaves, self._row_template,
@@ -2081,6 +2087,30 @@ class ServingEngine:
             req._qspan.end(wait_ms=wait_ms)
             req._qspan = None
 
+    def _chunk_plan(self, req):
+        """(first column, chunk width) where a dense engine admits the
+        request in chunks, None where it prefills the prompt whole.
+
+        A request with a registered prefix starts at the prefix's end, in
+        chunks of the engine's `prefill_chunk` or a default for prefix
+        users; any other request of an engine with `prefill_chunk` starts
+        at 0. Either falls back to the whole prompt (recomputing the
+        prefix: slower but correct near the capacity edge) where the
+        schedule's fixed-width last write would cross max_seq_len
+        (dynamic_update_slice CLAMPS out-of-range starts, which would
+        silently shift tokens onto valid columns)."""
+        n = len(req.prompt_ids)
+        if req.prefix_len and req.prefix_id in self._prefixes:
+            # (unregistered while the request sat in the queue: the
+            # combined prompt is already in prompt_ids)
+            C = self._chunk or min(64, self.T)
+            if req.prefix_len + -(-(n - req.prefix_len) // C) * C <= self.T:
+                return req.prefix_len, C
+        if self._chunk is not None and \
+                -(-n // self._chunk) * self._chunk <= self.T:
+            return 0, self._chunk
+        return None
+
     def _admit_one(self, slot, req):
         with _blackbox.progress("serving/admit"):
             self._admit_one_inner(slot, req)
@@ -2090,52 +2120,40 @@ class ServingEngine:
 
         if self._paged:
             return self._admit_one_paged(slot, req)
-        prefix_len = req.prefix_len
         n = len(req.prompt_ids)
-        if prefix_len and req.prefix_id not in self._prefixes:
-            # prefix unregistered while this request sat in the queue: the
-            # combined prompt is already in prompt_ids — whole-prefill it
-            prefix_len = 0
+        plan = self._chunk_plan(req)
         self._note_admission(req)
-        if prefix_len:
+        if plan is not None and plan[0]:
             # suffix-only prefill from a COPY of the cached prefix KV
-            # (the chunk program donates its cache args); chunk width =
-            # the engine's prefill_chunk or a default for prefix users
-            C = self._chunk or min(64, self.T)
-            end = prefix_len + -(-(n - prefix_len) // C) * C
-            if end <= self.T:
-                self._m["prefix_hit"] += 1
-                _PREFIX.labels(event="hit").inc()
-                sp = None if req._span is None else _trace.start_span(
-                    "admit", subsystem="serving", parent=req._span,
-                    slot=slot, prefix="hit", prefix_tokens=prefix_len)
-                try:
-                    _, kc_p, vc_p, kc_pd, vc_pd = \
-                        self._prefixes[req.prefix_id]
-                    kc1 = self._copy_cache(kc_p)
-                    vc1 = self._copy_cache(vc_p)
-                    kc1d = vc1d = None
-                    if self._draft is not None:
-                        kc1d = self._copy_cache(kc_pd)
-                        vc1d = self._copy_cache(vc_pd)
-                    self._slot_req[slot] = req
-                    self._prefilling[slot] = [req, kc1, vc1, prefix_len, C,
-                                              kc1d, vc1d]
-                except BaseException:
-                    if sp is not None:
-                        sp.end(error=True)
-                    raise
+            # (the chunk program donates its cache args)
+            prefix_len, C = plan
+            self._m["prefix_hit"] += 1
+            _PREFIX.labels(event="hit").inc()
+            sp = None if req._span is None else _trace.start_span(
+                "admit", subsystem="serving", parent=req._span,
+                slot=slot, prefix="hit", prefix_tokens=prefix_len)
+            try:
+                _, kc_p, vc_p, kc_pd, vc_pd = self._prefixes[req.prefix_id]
+                kc1 = self._copy_cache(kc_p)
+                vc1 = self._copy_cache(vc_p)
+                kc1d = vc1d = None
+                if self._draft is not None:
+                    kc1d = self._copy_cache(kc_pd)
+                    vc1d = self._copy_cache(vc_pd)
+                self._slot_req[slot] = req
+                self._prefilling[slot] = [req, kc1, vc1, prefix_len, C,
+                                          kc1d, vc1d]
+            except BaseException:
                 if sp is not None:
-                    sp.end()
-                return
-            # else: fall through to whole-prompt prefill (recomputes the
-            # prefix — slower but correct near the capacity edge)
+                    sp.end(error=True)
+                raise
+            if sp is not None:
+                sp.end()
+            return
         if req.prefix_len:   # wanted prefix reuse, got a full recompute
             self._m["prefix_miss"] += 1
             _PREFIX.labels(event="miss").inc()
-        n_chunks_end = 0 if self._chunk is None else \
-            -(-n // self._chunk) * self._chunk
-        if self._chunk is not None and n_chunks_end <= self.T:
+        if plan is not None:
             # chunked admission: reserve the slot, consume the prompt one
             # chunk per step() so active decodes run in between
             self._slot_req[slot] = req
@@ -2143,12 +2161,9 @@ class ServingEngine:
             if self._draft is not None:
                 kc1d, vc1d = self._draft_row()
             self._prefilling[slot] = [req, *self._prefill_start(), 0,
-                                      self._chunk, kc1d, vc1d]
+                                      plan[1], kc1d, vc1d]
             return
-        # whole-prompt (bucketed) prefill — also the fallback when the
-        # chunk schedule's fixed-width final write would cross max_seq_len
-        # (dynamic_update_slice CLAMPS out-of-range starts, which would
-        # silently shift tokens onto valid prefix columns)
+        # whole-prompt (bucketed) prefill
         pb = self._bucket(n)
         sp = None if req._span is None else _trace.start_span(
             "prefill", subsystem="serving", parent=req._span, slot=slot,
@@ -2364,11 +2379,12 @@ class ServingEngine:
 
     def _advance_and_admit(self):
         """The round's admission window, shared by both loops: advance
-        every in-flight chunked prefill ONE chunk (so active decodes
-        never wait for a whole long prefill), then admit
-        queued/handoff requests into free slots. Per-request failures
-        isolate: the failing request finishes reason="error" and the
-        pass continues."""
+        the chunked prefill in flight ONE chunk (so active decodes never
+        wait for more than a chunk), then admit queued/handoff requests
+        into free slots; a queued request that would prefill in chunks
+        waits while another does. Per-request failures isolate: the
+        failing request finishes reason="error" and the pass
+        continues."""
         for slot in list(self._prefilling):
             req = self._prefilling[slot][0]
             try:
@@ -2398,6 +2414,12 @@ class ServingEngine:
                         self._note_error()
                         continue
                 else:
+                    if self._prefilling and \
+                            self._chunk_plan(self._queue[0]) is not None:
+                        # one slot prefills in chunks at a time: a round
+                        # carries one chunk and one side row is held. The
+                        # queue's head waits for that slot's last chunk
+                        return
                     req = self._queue.pop(0)
                     try:
                         self._admit_one(slot, req)

@@ -34,10 +34,11 @@ from .hf_bridge import (  # noqa: F401
 )
 
 
-# the solar_open2 family is imported when first asked for: serving GPT-2 pays
-# nothing for it at start-up (PERF.md: PR 27's imports cost 7.9 % of setup_s)
+# the solar_open2 and axk1 families are imported when first asked for: serving
+# GPT-2 pays nothing for them at start-up (PERF.md: PR 27's imports cost 7.9 % of setup_s)
 _LAZY = {"SolarOpen2Config": "solar_open2",
-         "SolarOpen2ForCausalLM": "solar_open2"}
+         "SolarOpen2ForCausalLM": "solar_open2",
+         "AXK1Config": "axk1", "AXK1ForCausalLM": "axk1"}
 
 
 def __getattr__(name):

@@ -37,15 +37,11 @@ Forward only: the expert loop's trip count is data (distributed/moe.py).
 """
 import math
 
-import numpy as np
-
-from .. import nn
-from ..core.tensor import ParamBase, Tensor
 from ..serving import decode_model as _decode_model
+from ._functional_lm import (STEP_COUNTS, FunctionalCausalLM, count_vector,
+                             dot as _dot, dot32 as _dot32,
+                             einsum32 as _einsum32, rms as _rms)
 
-#: the counts a decode step returns beside its tokens, summed over the layers
-STEP_COUNTS = ("moe_assignments", "moe_assignments_held", "moe_rows_computed",
-               "moe_experts_touched")
 _Q_BLOCK = 256      # queries a block of the whole-sequence softmax attention
 
 
@@ -195,83 +191,7 @@ def _default_init(cfg):
     return init
 
 
-class SolarOpen2ForCausalLM(nn.Layer):
-    """The decoder with its untied head. `initializer(name, shape, kind,
-    dtype) -> array` draws each parameter as it is created (default:
-    `_default_init` from the global seed), so a caller that brings its own
-    weights never holds two sets; `dtype` is the parameters' (default
-    float32). `forward(input_ids [b, s]) -> logits [b, s, vocab]`."""
-
-    def __init__(self, cfg, initializer=None, dtype=None):
-        super().__init__()
-        import jax.numpy as jnp
-
-        from ..core import dtype as dtype_mod
-
-        self.cfg = cfg
-        dt = dtype_mod.convert_dtype(dtype) or jnp.float32
-        if initializer is None:
-            initializer = _default_init(cfg)
-        for name, (shape, kind) in param_shapes(cfg).items():
-            data = initializer(name, tuple(shape), kind, dt)
-            if tuple(data.shape) != tuple(shape):
-                raise ValueError(f"initializer gave {name} the shape "
-                                 f"{tuple(data.shape)}, not {tuple(shape)}")
-            *path, leaf = name.split(".")
-            at = self
-            for part in path:
-                if part not in at._sub_layers:
-                    setattr(at, part, nn.Layer())
-                at = at._sub_layers[part]
-            setattr(at, leaf, ParamBase(data, trainable=False))
-        self._fns = None
-
-    def forward(self, input_ids):
-        """Whole sequences from an empty state: logits at every position."""
-        import jax.numpy as jnp
-
-        ids = input_ids._data if isinstance(input_ids, Tensor) \
-            else jnp.asarray(np.asarray(input_ids))
-        if self._fns is None:
-            self._fns = _decode_fns(self.cfg)
-        fwd, logits_of, cache_init = self._fns
-        p = {n: t._data for n, t in self.named_parameters()}
-        b, s = ids.shape
-        dt = p["embed.weight"].dtype
-        x, _, _ = fwd(p, ids, 0, *cache_init(b, s, dt))
-        return Tensor(logits_of(p, x), stop_gradient=True)
-
-
 # -- the pure functions --------------------------------------------------------
-
-def _rms(x, w, eps):
-    import jax
-    import jax.numpy as jnp
-
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return y * w.astype(jnp.float32)
-
-
-def _dot32(a, b):
-    from ..distributed.moe import dot_f32
-
-    return dot_f32(a, b)
-
-
-def _dot(a, b):
-    return _dot32(a, b).astype(a.dtype)
-
-
-def _einsum32(eq, a, b):
-    """An einsum accumulated and returned in float32."""
-    import jax.numpy as jnp
-
-    from ..distributed.moe import f32_operands
-
-    return jnp.einsum(eq, *f32_operands(a, b),
-                      preferred_element_type=jnp.float32)
-
 
 def _attend(q, keys, vals, limit, scale):
     """q [B, t, H, hd]; keys, vals [B, S, KVh, hd]; limit [B or 1, t]: query
@@ -467,8 +387,7 @@ def _decode_fns(cfg):
             h32 = _rms(x, p[pre + "norm2.weight"], cfg.rms_norm_eps)
             y, c = _moe(p, pre + "moe.", cfg, h32.astype(cdt), h32)
             x = x + y
-            total = total + jnp.stack(
-                [c[n[len("moe_"):]] for n in STEP_COUNTS]).astype(jnp.int32)
+            total = total + count_vector(c)
         out = (x, {"k": K, "v": V},
                {"recurrent": tuple(S), "conv": tuple(conv)})
         return out + (total,) if counts else out
@@ -481,6 +400,15 @@ def _decode_fns(cfg):
     return fwd, logits_of, cache_init
 
 
+class SolarOpen2ForCausalLM(FunctionalCausalLM):
+    """The decoder with its untied head (models/_functional_lm.py has the
+    constructor's and `forward`'s contract)."""
+
+    param_shapes = staticmethod(param_shapes)
+    default_init = staticmethod(_default_init)
+    decode_fns = staticmethod(_decode_fns)
+
+
 class SolarOpen2DecodeModel(_decode_model.DecodeModel):
     """The family's DecodeModel adapter. Its cache is a described tree of
     state kinds (`cache_spec`); its whole-sequence call takes `valid_len`
@@ -488,6 +416,16 @@ class SolarOpen2DecodeModel(_decode_model.DecodeModel):
 
     name = "solar_open2"
     step_counts = STEP_COUNTS
+    not_served = {
+        "paged_kv": "it keeps fixed-size state (recurrent, conv) beside "
+                    "its keys and values; a paged pool holds `kv` pages "
+                    "only",
+        "draft_model": "a speculative round would have to take back the "
+                       "state its rejected tokens changed",
+        "tp_mesh": "its experts and state are not sharded over 'mp'",
+        "lora": "no LoRA sites",
+        "cache_dtype": "its recurrent state is float32 by the "
+                       "configuration"}
 
     def check_config(self, cfg):
         if not isinstance(cfg, SolarOpen2Config):

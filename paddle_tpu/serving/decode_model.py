@@ -47,7 +47,9 @@ The protocol (docs/SERVING.md for the full contract):
     it), ``kind`` (``"kv"``: grows with the context, written at ``pos``,
     a slot's junk past its request's columns is never seen;
     ``"recurrent"`` and ``"conv"``: a fixed size whatever the context,
-    replaced every step), ``slot_axis`` and ``layers``. The engine's
+    replaced every step), ``slot_axis`` and ``layers``. A ``kv`` leaf need
+    not be keys or values of heads: models/axk1.py's is one latent row
+    ``[L, B, T, W]`` for all heads. The engine's
     admission, hand-off rows, row copies and byte counts go by it
     (:func:`state_leaves`, :func:`slot_axes`, :func:`bytes_by_kind`,
     :func:`check_row`). A spec without ``"leaves"`` is a K/V pair with
@@ -67,6 +69,14 @@ The protocol (docs/SERVING.md for the full contract):
     call's ``cache_init`` makes it (a shard's heads under tensor
     parallelism), ``dtype`` what the step computes in. The engine's
     ``kv_tiles_read`` / ``kv_tiles_held`` counts follow it.
+``not_served``
+    Optional attribute: ``{option: reason}`` of the engines beyond the
+    dense one this adapter's programs do NOT serve: ``"paged_kv"``,
+    ``"draft_model"``, ``"tp_mesh"``, ``"lora"``, ``"cache_dtype"``. The
+    engine refuses each by the adapter's name and reason where it is
+    asked for. What decides is this declaration, not the kinds of the
+    cache's leaves: a latent cache has `kv` leaves only and still no
+    pages of K/V heads for a paged pool.
 ``lora_init(cfg, n_slots, rank, dtype=None)`` / ``lora_pack(cfg,
   exported, rank)``
     Optional multi-LoRA batched decode (FLAGS_paged_kv engines): the
@@ -92,6 +102,8 @@ class DecodeModel:
     module docstring. ``name`` is the registry key."""
 
     name = None
+    #: {option: reason} of the engines this adapter does not serve
+    not_served = {}
 
     # -- required ----------------------------------------------------------
     def check_config(self, cfg):
@@ -156,7 +168,8 @@ class DecodeModel:
 # without the caller having imported its module first.
 _REGISTRY = {}
 _LAZY = {"gpt": "paddle_tpu.models.gpt",
-         "solar_open2": "paddle_tpu.models.solar_open2"}
+         "solar_open2": "paddle_tpu.models.solar_open2",
+         "axk1": "paddle_tpu.models.axk1"}
 
 
 def register_decode_model(adapter, clobber=False):

@@ -447,3 +447,47 @@ def test_decode_attention(chip, kvh, hd, t_max, dtype):
     assert text.count("tpu_custom_call") == 1
     assert f"[3,8,{kvh},{t_max},{hd}]" not in "".join(
         line for line in text.splitlines() if " copy(" in line)
+
+
+@pytest.mark.parametrize("width, kernel", [(640, True), (640, False),
+                                           (576, False)])
+def test_latent_step_keeps_no_copy_of_its_cache(chip, monkeypatch, width,
+                                                kernel):
+    """One layer of models/axk1.py's absorbed decode step at its cell's widths
+    (128 rows x 8,192 columns, 64 heads, latent 512 + 64). With the cache's
+    minor axis in whole lanes (640) the step writes its column in place and
+    reads the row through ops/latent_decode_attention.py (a `tpu_custom_call`,
+    no temporaries to speak of) or, the kernel refused, through einsums whose
+    temporaries are the float32 scores and little else. Handed the 576 values
+    as they are, the chip's compiler unpacks the whole leaf into a padded copy
+    inside the step (why `AXK1Config.cache_width` rounds up)."""
+    from paddle_tpu.distributed import moe
+    from paddle_tpu.models import axk1
+    from paddle_tpu.ops import latent_decode_attention as lda
+
+    monkeypatch.setattr(moe, "on_tpu", lambda: True)    # bf16 x bf16 -> f32
+    monkeypatch.setattr(lda, "on_tpu", lambda: kernel)
+    cfg = axk1.AXK1Config(num_hidden_layers=1, max_seq_len=8192)
+    B, T, H = 128, 8192, cfg.num_heads
+    r, dn, dr, dv = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim, cfg.v_head_dim)
+
+    def step(lat, new, q_nope, q_rope, w_kvb, at):
+        lat = lat.at[0, jnp.arange(B), at].set(new)
+        o = axk1._attend_absorbed(q_nope, q_rope, lat, 0, w_kvb, at, cfg)
+        return lat, o
+
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        chip((1, B, T, width)), chip((B, width)), chip((B, H, dn)),
+        chip((B, H, dr)), chip((r, H * (dn + dv))),
+        chip((B,), jnp.int32)).compile()
+    leaf = B * T * width * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    scores = B * H * T * 4
+    assert ("tpu_custom_call" in compiled.as_text()) == kernel
+    if kernel:
+        assert temp < scores // 4, temp
+    elif width % 128:
+        assert temp > leaf
+    else:
+        assert temp < 3 * scores < leaf, (temp, scores)
