@@ -233,6 +233,11 @@ def phase_serve(cfg_kw, args, events, on_chip):
             "serve: the decode steps read every cache tile they hold "
             f"({st['kv_tiles_read']} of {st['kv_tiles_held']}): the "
             "attention went down the masked einsums, not the Pallas kernel")
+    if live_only != bool(st["kv_tiles_written"]):
+        raise AssertionError(
+            "serve: the kernel that reads the live tiles stores the step's "
+            f"column into them, and here {st['kv_tiles_written']} tiles "
+            f"were written back with live_only {live_only}")
     first = first_difference(model, prompts[0], results[rids[0]].tokens, ref)
     if first is not None:
         # two near-equal logits of this untrained model may fall either way

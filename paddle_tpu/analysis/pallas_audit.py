@@ -4,8 +4,8 @@ at lint time, not on a burned 900-second TPU bench round.
 Every Pallas kernel family the repo ships (the TPP micro-kernel registry
 ``ops/tpp.py``, flash attention ``ops/flash_attention.py``, the NMS sweep
 ``ops/nms_pallas.py``, the decode step's in-place KV-cache store
-``ops/kv_store.py``, its read of the live cache tiles
-``ops/decode_attention.py`` and the latent family's
+``ops/kv_store.py``, its read of the live cache tiles, with and without
+that store folded in, ``ops/decode_attention.py`` and the latent family's
 ``ops/latent_decode_attention.py``) exposes an ``audit_manifest()``: a list of
 declarative entries describing what each kernel compiles to at its
 representative shapes — grid dims with their block edges, every

@@ -539,6 +539,11 @@ class ServingEngine:
             lambda: cache_init(self.B, self.T, cache_dt))[0]
         self._kv_tile = dm.kv_read_tile(cfg, k_side, cache_dt,
                                         tp_size) or self.T
+        # ... and how many of them, over all rows, such a step's attention
+        # writes back with the step's column in them
+        # (stats()["kv_tiles_written"]); 0 where the store is its own
+        self._kv_tiles_written = self.B * dm.kv_tiles_written(
+            cfg, k_side, cache_dt, tp_size)
 
         fixed_state, count_names = self._fixed_state, self._count_names
 
@@ -931,6 +936,7 @@ class ServingEngine:
                    "prefix_hit": 0, "prefix_miss": 0,
                    "occupancy_sum": 0, "occupancy_steps": 0,
                    "kv_tiles_read": 0, "kv_tiles_held": 0,
+                   "kv_tiles_written": 0,
                    "state_bytes_moved": {k: 0 for k in self._state_held},
                    "step_counts": {n: 0 for n in self._count_names},
                    "lookahead": {"rounds": 0, "rounds_overlapped": 0,
@@ -1240,6 +1246,7 @@ class ServingEngine:
             "batch_occupancy_avg": occ,
             "kv_tiles_read": m["kv_tiles_read"],
             "kv_tiles_held": m["kv_tiles_held"],
+            "kv_tiles_written": m["kv_tiles_written"],
             # bytes of state by kind (kv, recurrent, conv): what the engine
             # holds, and what its decode steps so far had to move of it
             "state_bytes": {"held": dict(self._state_held),
@@ -2266,16 +2273,21 @@ class ServingEngine:
 
     def _count_kv_tiles(self, disp, one_token=True):
         """Onto the `serve/decode_dispatch` phase and the running sums: the
-        cache tiles this round's step reads, and the tiles the cache holds.
-        The step walks every row, a free one (position 0) as well. A
-        speculative round's verify (several columns a row) reads all."""
+        cache tiles this round's step reads, those its attention writes
+        back, and the tiles the cache holds. The step walks every row, a
+        free one (position 0) as well. A speculative round's verify
+        (several columns a row) reads all and stores by itself."""
         held = read = self.B * -(-self.T // self._kv_tile)
         cols = np.minimum(self._pos, self.T - 1)
+        written = 0
         if one_token:
             read = int((cols // self._kv_tile + 1).sum())
-        disp.counts.update(kv_tiles_read=read, kv_tiles_held=held)
+            written = self._kv_tiles_written
+        disp.counts.update(kv_tiles_read=read, kv_tiles_held=held,
+                           kv_tiles_written=written)
         self._m["kv_tiles_read"] += read
         self._m["kv_tiles_held"] += held
+        self._m["kv_tiles_written"] += written
         moved = self._m["state_bytes_moved"]
         for kind, nbytes in self._state_held.items():
             # live columns read and one a row written; fixed-size state

@@ -464,11 +464,12 @@ def _row_update(cache_i, val, pos_vec):
 
 def _live_tile(kc, q, pos, win, key_valid):
     """The width, in cache columns, of the tiles this step's attention reads
-    each row in, up to the row's position and no further
-    (ops/decode_attention.py), or None where it contracts with all T columns
-    and masks. Chosen from what can be seen: one query a row, each row at
-    its own `pos`, nothing masked but the columns past it, a plain cache
-    side of a shape the kernel takes, a TPU."""
+    each row in, up to the row's position and no further, storing the step's
+    keys and values into the last of them (ops/decode_attention.py), or None
+    where a store of its own runs first and the attention contracts with
+    all T columns and masks. Chosen from what can be seen: one query a row,
+    each row at its own `pos`, nothing masked but the columns past it, a
+    plain cache side of a shape the kernel takes, a TPU."""
     import jax.numpy as jnp
 
     from ..ops import decode_attention
@@ -625,8 +626,11 @@ def _decode_fns(cfg, untied, untied_bias, cache_dtype=None, tp_axis=None,
                 1, 2)
             v = jnp.moveaxis(
                 flat[..., (Hh + KVh) * hd:].reshape(bb, t, KVh, hd), 1, 2)
-        kc = _store(kc, k, i, pos)
-        vc = _store(vc, v, i, pos)
+        # a decode step on the chip leaves the store to its attention
+        fused = _live_tile(kc, q, pos, win, key_valid)
+        if not fused:
+            kc = _store(kc, k, i, pos)
+            vc = _store(vc, v, i, pos)
         # causal over cache columns: query row r (column pos+r) sees cache
         # column c iff c <= pos + r. pos is a scalar (whole batch at one
         # frontier) or [B] (per-slot frontiers — continuous batching); one
@@ -640,10 +644,11 @@ def _decode_fns(cfg, untied, untied_bias, cache_dtype=None, tp_axis=None,
         if key_valid is not None:
             self_col = cols == rows                    # keep self: no NaN rows
             mask = mask & (key_valid[:, None, :] | self_col)
-        if _live_tile(kc, q, pos, win, key_valid):
-            # a decode step on the chip: tiles 0..pos[b] // 128 of row b,
-            # and no column beyond them
-            out = _decode_attention.decode_attention(kc, vc, q, i, pos)
+        if fused:
+            # tiles 0..pos[b] // 128 of row b and no column beyond them;
+            # the last of them takes the new column and is written back
+            out, kc, vc = _decode_attention.decode_attention_store(
+                kc, vc, q, k, v, i, pos)
         elif g == 1:
             att = jnp.einsum("bhtd,bhTd->bhtT", q,
                              _load(kc, i, q.dtype)) * scale
@@ -1445,6 +1450,12 @@ class GPTDecodeModel(_decode_model.DecodeModel):
                                  dtype)
         return _live_tile(side, q, np.zeros((rows,), np.int32),
                           getattr(cfg, "attention_window", None), None)
+
+    def kv_tiles_written(self, cfg, side, dtype, tp_size=1):
+        # `block` hands the store to the kernel wherever it takes the
+        # kernel: a tile of keys and one of values, every layer
+        return 2 * cfg.num_layers if self.kv_read_tile(
+            cfg, side, dtype, tp_size) else 0
 
     def tp_setup(self, tp_mesh, cfg, params):
         return _tp_setup(tp_mesh, cfg, params)

@@ -17,7 +17,12 @@ The new values come in as they leave the qkv product, [B, KVh * hd] along the
 lanes, and are turned to the block's orientation inside the kernel: handed over
 as [B, KVh, hd, 1] they cost a lane-padded transpose a call, 1.9 ms of a 15.8
 ms step (PERF.md, PR 28). The layer index rides in as a scalar and the call is
-a jit of its own, so the 72 stores of a 36-layer step share one traced kernel.
+a jit of its own, so the stores of a step share one traced kernel.
+
+Where a step's attention is ops/decode_attention.py, that kernel does this
+store on the tile it has fetched anyway and this one is not called (PR 37);
+it stays the store where the einsums read the cache: grouped queries, a
+quantized cache's values leaf.
 """
 import functools
 

@@ -69,6 +69,12 @@ The protocol (docs/SERVING.md for the full contract):
     call's ``cache_init`` makes it (a shard's heads under tensor
     parallelism), ``dtype`` what the step computes in. The engine's
     ``kv_tiles_read`` / ``kv_tiles_held`` counts follow it.
+``kv_tiles_written(cfg, side, dtype, tp_size=1)``
+    Optional, same arguments: how many of those tiles such a step's
+    attention writes back for each row, the step's new column stored into
+    them (all layers, keys and values); 0 (the default) where the step
+    stores by a call or a select of its own. The engine's
+    ``kv_tiles_written`` count is rows times it.
 ``not_served``
     Optional attribute: ``{option: reason}`` of the engines beyond the
     dense one this adapter's programs do NOT serve: ``"paged_kv"``,
@@ -143,6 +149,11 @@ class DecodeModel:
         """The width of the tiles a one-token decode step reads each row
         of ``side`` in, up to the row's position; None: all of it."""
         return None
+
+    def kv_tiles_written(self, cfg, side, dtype, tp_size=1):
+        """How many of those tiles such a step's attention writes back a
+        row, the step's column in them; 0: the store is not its work."""
+        return 0
 
     # -- optional (multi-LoRA batched decode, FLAGS_paged_kv engines) ------
     def lora_init(self, cfg, n_slots, rank, dtype=None):

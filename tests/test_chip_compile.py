@@ -200,9 +200,10 @@ class TestDecodeStep:
     dense cache on the chip"): the compiled step may hold neither, by either
     form of the store — the in-place kernel a TPU takes (ops/kv_store.py),
     and the select every other shape and platform takes. On a TPU the
-    attention of a plain cache reads it through a kernel too
-    (ops/decode_attention.py): no operation of XLA's own then takes a cache
-    layer as an operand."""
+    attention of a plain cache reads it through a kernel too, and that
+    kernel is the store as well (ops/decode_attention.py): a layer is one
+    call, and no operation of XLA's own takes a cache layer as an
+    operand."""
 
     LAYERS, ROWS, T = 2, 32, 1024
 
@@ -278,11 +279,12 @@ class TestDecodeStep:
                    for line in entry.splitlines())
         assert [m.group(0)[:160] for m in results
                 if m and layer.search(m.group(1))] == []
-        # the store of K and of V of every layer (an int8 cache's scales
-        # take the select) and, over a plain cache, the attention's read
-        calls = {"select": 0, "kernel": 3 if cache_dtype is None else 2}
+        # over a plain cache the attention's kernel, which stores K and V
+        # too; over an int8 cache the store of K's and of V's values (the
+        # scales take the select)
+        calls = {"select": 0, "kernel": 1 if cache_dtype is None else 2}
         assert text.count("tpu_custom_call") == calls[store] * self.LAYERS
-        if calls[store] == 3:
+        if calls[store] == 1:
             assert self._reads_of_a_layer(text, 20) == []
         else:       # the einsums: the check above finds what it looks for
             assert self._reads_of_a_layer(text, 20)
@@ -293,13 +295,14 @@ class TestDecodeStep:
 
         weights, cache = nbytes(eng._params), nbytes((eng._kc, eng._vc))
         accessed = compiled.cost_analysis()["bytes accessed"]
-        # XLA's count: the select reads 1.25 x, the kernel 0.7 x, the store
-        # this replaced 3.7 x. It charges an int8 cache's dequantizing read
-        # (_load, not the store) several times its bytes: 2.2-2.3 x there,
+        # XLA's count: the select reads 1.20 x, the one kernel 0.37 x (0.7 x
+        # while the store was a call of its own), the store the select
+        # replaced 3.7 x. It charges an int8 cache's dequantizing read
+        # (_load, not the store) several times its bytes: 1.9-2.3 x there,
         # 4.2 x before
-        room = 1.4 if cache_dtype is None else 2.5
+        room = 2.5 if cache_dtype else 1.4 if store == "select" else 0.5
         if kind == "sample":
-            room += 0.2          # two sorts of the [32, 50304] logits
+            room += 0.25         # two sorts of the [32, 50304] logits
         assert accessed <= room * (weights + 3 * cache), (
             accessed / (weights + 3 * cache))
         mem = compiled.memory_analysis()
@@ -358,8 +361,8 @@ class TestDecodeStep:
     def test_tensor_parallel_step_over_four_chips(self, topo, monkeypatch):
         """The engine's greedy step as tensor-parallel serving runs it
         (shard_map over `mp`, 5 of gpt2-large's 20 heads a chip): each
-        shard stores and reads its heads through the kernels, and nothing
-        else of the compiled step takes a shard's cache layer."""
+        shard stores and reads its heads through the one kernel, and
+        nothing else of the compiled step takes a shard's cache layer."""
         import paddle_tpu as paddle
         from paddle_tpu.models import GPTConfig, GPTForCausalLM, gpt
         from paddle_tpu.ops import decode_attention, kv_store
@@ -397,7 +400,7 @@ class TestDecodeStep:
             {n: on_chips(v.shape, BF16, specs[n]) for n, v in params.items()},
             cache, cache, rows, rows).compile()
         text = compiled.as_text()
-        assert text.count("tpu_custom_call") == 3 * self.LAYERS
+        assert text.count("tpu_custom_call") == self.LAYERS
         assert self._reads_of_a_layer(text, 5) == []
         mem = compiled.memory_analysis()
         shard = 2 * cache.size * 2 // 4              # K and V, a chip
@@ -426,12 +429,15 @@ def test_kv_store_columns(chip, kvh, hd, t_max, dtype):
         leaf, val, chip((8,), jnp.int32)) == 1
 
 
-@pytest.mark.parametrize("kvh,hd,t_max,dtype", [
+_decode_attention_shapes = pytest.mark.parametrize("kvh,hd,t_max,dtype", [
     (20, 64, 1024, BF16),             # gpt2-large
     (5, 64, 1024, BF16),              # its heads over four chips: 320 lanes
     (4, 32, 256, BF16),               # a narrow draft model
     (12, 64, 384, jnp.float32),
 ], ids=["gpt2_large", "tp_local_heads", "narrow", "f32"])
+
+
+@_decode_attention_shapes
 def test_decode_attention(chip, kvh, hd, t_max, dtype):
     """The decode step's read of the live tiles alone: one kernel, and no
     copy or relayout of the cache around it."""
@@ -447,6 +453,30 @@ def test_decode_attention(chip, kvh, hd, t_max, dtype):
     assert text.count("tpu_custom_call") == 1
     assert f"[3,8,{kvh},{t_max},{hd}]" not in "".join(
         line for line in text.splitlines() if " copy(" in line)
+
+
+@_decode_attention_shapes
+def test_decode_attention_store(chip, kvh, hd, t_max, dtype):
+    """The read that stores the step's column into the tile it holds, over
+    `test_decode_attention`'s shapes: one kernel, both leaves donated
+    through it in place, no copy of the cache and no temporary."""
+    from paddle_tpu.ops import decode_attention as da
+
+    leaf, q = chip((3, 8, kvh, t_max, hd), dtype), chip((8, kvh, 1, hd),
+                                                        dtype)
+    assert da.fits(leaf, q)
+    compiled = jax.jit(
+        lambda k, v, q, k_new, v_new, pos: da.decode_attention_store(
+            k, v, q, k_new, v_new, 1, pos, interpret=False),
+        donate_argnums=(0, 1),
+    ).lower(leaf, leaf, q, q, q, chip((8,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert f"[3,8,{kvh},{t_max},{hd}]" not in "".join(
+        line for line in text.splitlines() if " copy(" in line)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * leaf.size * leaf.dtype.itemsize
+    assert mem.temp_size_in_bytes == 0
 
 
 @pytest.mark.parametrize("width, kernel", [(640, True), (640, False),

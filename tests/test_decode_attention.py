@@ -87,15 +87,16 @@ def _tiny(**kw):
 @pytest.fixture
 def kernel_calls(monkeypatch):
     """Skips the choice's platform test, as on a chip (off one the kernel
-    interprets), and notes the kernel's calls."""
-    calls, real = [], da.decode_attention
+    interprets), and notes the calls of the entry `block` takes: the one
+    that stores the step's column too."""
+    calls, real = [], da.decode_attention_store
 
     def noted(kleaf, *a, **kw):
         calls.append(kleaf.shape)
         return real(kleaf, *a, **kw)
 
     monkeypatch.setattr(da, "live_only", da.fits)
-    monkeypatch.setattr(da, "decode_attention", noted)
+    monkeypatch.setattr(da, "decode_attention_store", noted)
     return calls
 
 
@@ -197,6 +198,29 @@ class TestEngine:
                   if n == "serve/decode_dispatch" and c][-len(seen):]
         assert sum(c["kv_tiles_read"] for c in counts) == st["kv_tiles_read"]
         assert sum(c["kv_tiles_held"] for c in counts) == st["kv_tiles_held"]
+
+    @pytest.mark.parametrize("where", ["on_the_chip", "off_the_chip"])
+    def test_tiles_written_back_are_counted(self, request, where):
+        """Where the step's attention stores the step's column, a tile of
+        keys and one of values a row a layer, every step; where the store
+        is a call or a select of its own, none."""
+        from paddle_tpu import trace
+        from paddle_tpu.inference.serving import ServingEngine
+
+        if where == "on_the_chip":
+            request.getfixturevalue("kernel_calls")
+        eng = ServingEngine(_tiny(), max_batch=3)
+        eng.submit(np.arange(7, dtype=np.int32), max_new_tokens=5)
+        eng.submit(np.arange(130, dtype=np.int32), max_new_tokens=3)
+        eng.run_until_complete()
+        st = eng.stats()
+        steps = st["lookahead"]["rounds"]       # decode steps dispatched
+        a_step = 2 * 3 * 2 if where == "on_the_chip" else 0   # x rows x layers
+        assert steps and st["kv_tiles_written"] == a_step * steps
+        rows, _ = trace.phases()
+        counts = [c for n, *_, c in rows
+                  if n == "serve/decode_dispatch" and c][-steps:]
+        assert [c["kv_tiles_written"] for c in counts] == [a_step] * steps
 
     def test_off_the_chip_every_tile_is_read(self):
         from paddle_tpu.inference.serving import ServingEngine

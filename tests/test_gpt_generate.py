@@ -1053,3 +1053,172 @@ class TestPerRowStore:
                 temperature=0.0, cache_dtype=cache_dtype)._data)[0, len(p):]
             np.testing.assert_array_equal(res[rid].tokens, want)
         assert calls is None or calls
+
+
+def _store_then_read(kleaf, vleaf, q, k_new, v_new, i, pos):
+    """`decode_attention_store` as the two kernels it took the place of."""
+    from paddle_tpu.ops import decode_attention as da
+    from paddle_tpu.ops import kv_store
+
+    kleaf = kv_store.store_columns(kleaf, k_new, i, pos)
+    vleaf = kv_store.store_columns(vleaf, v_new, i, pos)
+    return da.decode_attention(kleaf, vleaf, q, i, pos), kleaf, vleaf
+
+
+class TestStoreInTheRead:
+    """Where a decode step's attention is the live-tile kernel, the kernel
+    stores the step's keys and values itself
+    (ops/decode_attention.py decode_attention_store): the cache it returns
+    is `kv_store.store_columns`' bit for bit, its result `decode_attention`'s
+    after that store, and `block` calls no store of its own. Interpret mode."""
+
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    @pytest.mark.parametrize("hd", [32, 64])
+    @pytest.mark.parametrize("pos", [
+        [0, 0, 0, 0], [127, 127, 127, 127], [128, 128, 128, 128],
+        [383, 383, 383, 383], [4383, 384, 1 << 30, 383],
+        [0, 127, 128, 383], [300, 5, 200, 129]],
+        ids=["first", "tile_end", "tile_start", "last", "stale", "edges",
+             "rows_at_different_tiles"])
+    def test_the_store_then_the_read_bit_for_bit(self, pos, hd, dtype):
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops import decode_attention as da
+        from paddle_tpu.ops import kv_store
+
+        t_max, kvh = 384, 4
+        ks = jax.random.split(jax.random.PRNGKey(hd), 5)
+        dt = jnp.dtype(dtype)
+        kc, vc = (jax.random.normal(k, (3, 4, kvh, t_max, hd),
+                                    jnp.float32).astype(dt) for k in ks[:2])
+        q, k_new, v_new = (jax.random.normal(k, (4, kvh, 1, hd),
+                                             jnp.float32).astype(dt)
+                           for k in ks[2:])
+        pos = jnp.asarray(pos, jnp.int32)
+        assert da.fits(kc, q)
+        out, k_got, v_got = da.decode_attention_store(
+            kc, vc, q, k_new, v_new, 1, pos, interpret=True)
+        k_want = kv_store.store_columns(kc, k_new, 1, pos, interpret=True)
+        v_want = kv_store.store_columns(vc, v_new, 1, pos, interpret=True)
+        for got, want, before in ((k_got, k_want, kc), (v_got, v_want, vc)):
+            np.testing.assert_array_equal(_bits(got)[0], _bits(want)[0])
+            assert (_bits(got)[0] != _bits(before)[0]).any()
+        # ... which is the column a per-row dynamic_update_slice stores
+        np.testing.assert_array_equal(
+            _bits(k_got[1])[0],
+            _bits(_update_slice_a_row(kc[1], k_new, pos))[0])
+        want = da.decode_attention(k_want, v_want, q, 1, pos, interpret=True)
+        assert out.shape == q.shape and out.dtype == q.dtype
+        np.testing.assert_array_equal(_bits(out)[0], _bits(want)[0])
+
+    @staticmethod
+    def _as_on_a_chip(monkeypatch):
+        """The platform tests of the read and of the store skipped, as on a
+        TPU (off one the kernels interpret); returns the calls noted, by
+        name: the kernel that stores, the store's own, the select."""
+        from paddle_tpu.models import gpt
+        from paddle_tpu.ops import decode_attention as da
+        from paddle_tpu.ops import kv_store
+
+        calls = {"decode_attention_store": 0, "store_columns": 0,
+                 "_row_update": 0}
+
+        def noting(mod, name):
+            real = getattr(mod, name)
+
+            def noted(*a, **kw):
+                calls[name] += 1
+                return real(*a, **kw)
+
+            monkeypatch.setattr(mod, name, noted)
+
+        monkeypatch.setattr(da, "live_only", da.fits)
+        monkeypatch.setattr(kv_store, "in_place", kv_store.fits)
+        noting(da, "decode_attention_store")
+        noting(kv_store, "store_columns")
+        noting(gpt, "_row_update")
+        return calls
+
+    @pytest.mark.parametrize("case,stores", [
+        ("decode_step", {"decode_attention_store": 2}),
+        # values by the store's kernel, scales by the select
+        ("int8", {"store_columns": 4, "_row_update": 4}),
+        ("gqa", {"store_columns": 4}),
+        ("several_columns", {"_row_update": 4}),
+        ("window", {"store_columns": 4})])
+    def test_block_stores_once_by_the_kernel_or_as_before(
+            self, monkeypatch, case, stores):
+        """K and V of two layers: by the attention's kernel and no other
+        call where `_live_tile` holds, by `_store` everywhere else."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.models import gpt
+
+        paddle.seed(0)
+        cfg = {"gqa": {"num_kv_heads": 2},
+               "window": {"attention_window": 64}}.get(case, {})
+        model = GPTForCausalLM(GPTConfig(
+            vocab_size=128, hidden_size=128, num_layers=2, num_heads=4,
+            max_seq_len=256, dropout=0.0, **cfg))
+        model.eval()
+        _, _, params = gpt._decode_params(model, "the model")
+        params = {n: v.astype(jnp.bfloat16) for n, v in params.items()}
+        fwd, _, cache_init = gpt._decode_fns(
+            model.cfg, False, False,
+            cache_dtype="int8" if case == "int8" else None)
+        kc, vc = cache_init(4, 256, jnp.bfloat16)
+        toks = jnp.asarray(np.random.RandomState(3).randint(
+            0, 128, (4, 3 if case == "several_columns" else 1)), jnp.int32)
+        pos = jnp.asarray([0, 127, 128, 199], jnp.int32)
+
+        calls = self._as_on_a_chip(monkeypatch)
+        x, *got = fwd(params, toks, pos, kc, vc)
+        assert {n: c for n, c in calls.items() if c} == stores
+        # the same cache, by whichever store, and something stored
+        monkeypatch.undo()
+        if case == "decode_step":       # the same read, after its own store
+            from paddle_tpu.ops import decode_attention as da
+
+            self._as_on_a_chip(monkeypatch)
+            monkeypatch.setattr(da, "decode_attention_store",
+                                _store_then_read)
+        x_ref, *want = fwd(params, toks, pos, kc, vc)
+        np.testing.assert_array_equal(_bits(x)[0], _bits(x_ref)[0])
+        for g, w, before in zip(_bits(got), _bits(want), _bits((kc, vc))):
+            np.testing.assert_array_equal(g, w)
+            assert (g != before).any()
+
+    def test_engine_emits_what_the_store_then_the_read_emit(self,
+                                                            monkeypatch):
+        """An engine whose steps store through the attention's kernel
+        emits the tokens of one whose steps call the store, then the
+        read."""
+        from paddle_tpu.inference.serving import ServingEngine
+        from paddle_tpu.ops import decode_attention as da
+
+        def tokens(fused):
+            calls = self._as_on_a_chip(monkeypatch)
+            if not fused:
+                monkeypatch.setattr(da, "decode_attention_store",
+                                    _store_then_read)
+            paddle.seed(0)
+            model = GPTForCausalLM(GPTConfig(
+                vocab_size=128, hidden_size=128, num_layers=2, num_heads=4,
+                max_seq_len=256, dropout=0.0))
+            model.eval()
+            eng = ServingEngine(model, max_batch=3, dtype="bfloat16")
+            rng = np.random.RandomState(11)
+            prompts = [rng.randint(0, 128, (n,)).astype(np.int32)
+                       for n in (5, 120, 9, 150, 127)]
+            rids = [eng.submit(p, max_new_tokens=12) for p in prompts]
+            res = eng.run_until_complete()
+            monkeypatch.undo()
+            return [res[r].tokens for r in rids], calls
+
+        got, calls = tokens(fused=True)
+        assert calls["decode_attention_store"] and not calls["store_columns"]
+        want, calls = tokens(fused=False)
+        assert calls["store_columns"] and not calls["decode_attention_store"]
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
