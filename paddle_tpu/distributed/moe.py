@@ -223,34 +223,37 @@ def moe_dropless(x, experts, weights, w_gate, w_up, w_down, held=None, num_exper
     max_tiles = -(-N // tm) + count
     P_rows = max_tiles * tm
 
-    flat_e = experts.reshape(N) - first
-    is_held = (flat_e >= 0) & (flat_e < count)
-    group = jnp.where(is_held, flat_e, count)             # the rest sorts last
-    sizes = jnp.sum(group[:, None] == jnp.arange(count, dtype=jnp.int32)[None, :],
-                    axis=0, dtype=jnp.int32)
-    tiles = (sizes + tm - 1) // tm                        # tiles an expert's run takes
-    tile_end = jnp.cumsum(tiles)
-    n_tiles = tile_end[-1]
-    tile_start = tile_end - tiles
-    run_start = jnp.cumsum(sizes) - sizes
-    # gathers only (a sort and its inverse), no scatter: the assignments in expert order,
-    # then for every padded row the assignment it carries, if any
-    order = jnp.argsort(group, stable=True).astype(jnp.int32)
-    tile_expert = jnp.minimum(jnp.searchsorted(
-        tile_end, jnp.arange(max_tiles, dtype=jnp.int32), side="right"),
-        count - 1).astype(jnp.int32)
-    p_row = jnp.arange(P_rows, dtype=jnp.int32)
-    tau = p_row // tm
-    e_p = tile_expert[tau]
-    r_p = (tau - tile_start[e_p]) * tm + p_row % tm
-    carries = (tau < n_tiles) & (r_p < sizes[e_p])
-    a_p = order[jnp.clip(run_start[e_p] + r_p, 0, N - 1)]
-    src = jnp.where(carries, a_p // k, T)
-    x_pad = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[src]   # [P_rows, d]
-    # and for every assignment the padded row that carries it
-    safe = jnp.minimum(group, count - 1)
-    rank = jnp.argsort(order).astype(jnp.int32) - run_start[safe]
-    row = jnp.clip(tile_start[safe] * tm + rank, 0, P_rows - 1)
+    # the sort and the gathers that lay the assignments out in expert order are the
+    # router's; the loop over the tiles is `moe/experts`, the weighted sum `moe/combine`
+    with jax.named_scope("moe/router"):
+        flat_e = experts.reshape(N) - first
+        is_held = (flat_e >= 0) & (flat_e < count)
+        group = jnp.where(is_held, flat_e, count)             # the rest sorts last
+        sizes = jnp.sum(group[:, None] == jnp.arange(count, dtype=jnp.int32)[None, :],
+                        axis=0, dtype=jnp.int32)
+        tiles = (sizes + tm - 1) // tm                        # tiles an expert's run takes
+        tile_end = jnp.cumsum(tiles)
+        n_tiles = tile_end[-1]
+        tile_start = tile_end - tiles
+        run_start = jnp.cumsum(sizes) - sizes
+        # gathers only (a sort and its inverse), no scatter: the assignments in expert order,
+        # then for every padded row the assignment it carries, if any
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        tile_expert = jnp.minimum(jnp.searchsorted(
+            tile_end, jnp.arange(max_tiles, dtype=jnp.int32), side="right"),
+            count - 1).astype(jnp.int32)
+        p_row = jnp.arange(P_rows, dtype=jnp.int32)
+        tau = p_row // tm
+        e_p = tile_expert[tau]
+        r_p = (tau - tile_start[e_p]) * tm + p_row % tm
+        carries = (tau < n_tiles) & (r_p < sizes[e_p])
+        a_p = order[jnp.clip(run_start[e_p] + r_p, 0, N - 1)]
+        src = jnp.where(carries, a_p // k, T)
+        x_pad = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[src]   # [P_rows, d]
+        # and for every assignment the padded row that carries it
+        safe = jnp.minimum(group, count - 1)
+        rank = jnp.argsort(order).astype(jnp.int32) - run_start[safe]
+        row = jnp.clip(tile_start[safe] * tm + rank, 0, P_rows - 1)
 
     def body(t, y_pad):
         e = tile_expert[t]
@@ -262,10 +265,12 @@ def moe_dropless(x, experts, weights, w_gate, w_up, w_down, held=None, num_exper
         yt = dot_f32(a.astype(x.dtype), wd)
         return jax.lax.dynamic_update_slice_in_dim(y_pad, yt.astype(x.dtype), t * tm, 0)
 
-    y_pad = jax.lax.fori_loop(0, n_tiles, body, jnp.zeros((P_rows, d), x.dtype))
-    picked = y_pad[row].astype(jnp.float32)               # [N, d]
-    w_flat = jnp.where(is_held, weights.reshape(N), 0.0)
-    y = jnp.sum((picked * w_flat[:, None]).reshape(T, k, d), axis=1)
+    with jax.named_scope("moe/experts"):
+        y_pad = jax.lax.fori_loop(0, n_tiles, body, jnp.zeros((P_rows, d), x.dtype))
+    with jax.named_scope("moe/combine"):
+        picked = y_pad[row].astype(jnp.float32)               # [N, d]
+        w_flat = jnp.where(is_held, weights.reshape(N), 0.0)
+        y = jnp.sum((picked * w_flat[:, None]).reshape(T, k, d), axis=1)
     counts = {"assignments_held": jnp.sum(sizes), "rows_computed": n_tiles * tm,
               "experts_touched": jnp.sum(sizes > 0, dtype=jnp.int32)}
     return y, counts
@@ -287,12 +292,14 @@ def moe_dropless_layer(x, router_w, select_bias, w_gate, w_up, w_down, k, shared
     between scores, and should not decide by a rounding). Returns (y [T, d] float32,
     counts: `assignments` (static), `assignments_held`, `rows_computed`,
     `experts_touched`)."""
-    experts, weights = sigmoid_topk_routing(x if router_x is None else router_x, router_w,
-                                            select_bias, k, normalize, scale)
+    with jax.named_scope("moe/router"):
+        experts, weights = sigmoid_topk_routing(x if router_x is None else router_x,
+                                                router_w, select_bias, k, normalize, scale)
     y, counts = moe_dropless(x, experts, weights, w_gate, w_up, w_down, held=held,
                              num_experts=router_w.shape[1], tile=tile,
                              activation=activation)
     if shared is not None:
-        y = y + gated_mlp(x, *shared, activation=activation)
+        with jax.named_scope("moe/shared"):
+            y = y + gated_mlp(x, *shared, activation=activation)
     counts = dict(counts, assignments=jnp.int32(x.shape[0] * k))
     return y, counts

@@ -989,7 +989,12 @@ class SpmdTrainer:
             else:
                 (loss, (new_buffers, outputs)), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(params, batch, rng)
-            new_params, new_state = self.optimizer.functional_apply(params, grads, opt_state, lr=lr)
+            # the gradients' reduction over dp is XLA's here, from the
+            # shardings: it has no scope of its own (`grad_sync` names the
+            # explicit collectives of the three other builders)
+            with jax.named_scope("optimizer"):
+                new_params, new_state = self.optimizer.functional_apply(
+                    params, grads, opt_state, lr=lr)
             nstats = None
             if narmed:
                 # FLAGS_numerics: the fused per-layer health aggregation
@@ -1052,7 +1057,8 @@ class SpmdTrainer:
         # owns them (owned_device_put) and rebinds them from the step
         # output every call — not donating doubled their HBM footprint
         # (the donation-miss finding ISSUE 13's sharding targets surfaced)
-        return jax.jit(step, in_shardings=in_shardings, out_shardings=out_shardings,
+        return jax.jit(_aot.named(step, "train.step"),
+                       in_shardings=in_shardings, out_shardings=out_shardings,
                        donate_argnums=(0, 1, 2))
 
     def _shard_map(self, f, in_specs, out_specs, check_vma=True):
@@ -1091,13 +1097,16 @@ class SpmdTrainer:
 
                 (loss, new_buf), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(p, batch_local)
-                new_p, new_st = opt.functional_apply(p, grads, st, lr=lr)
-                step_no = new_st["__step__"]
-                do_avg = jnp.logical_and(step_no >= begin, step_no % k == 0)
-                avg = {n: jax.lax.pmean(v, ax) for n, v in new_p.items()}
-                new_p = {n: jnp.where(do_avg, avg[n], new_p[n]) for n in new_p}
-                loss = jax.lax.pmean(loss, ax)
-                new_buf = {n: jax.lax.pmean(v, ax) for n, v in new_buf.items()}
+                with jax.named_scope("optimizer"):
+                    new_p, new_st = opt.functional_apply(p, grads, st, lr=lr)
+                # what LocalSGD reduces is the replicas, every k-th step
+                with jax.named_scope("grad_sync"):
+                    step_no = new_st["__step__"]
+                    do_avg = jnp.logical_and(step_no >= begin, step_no % k == 0)
+                    avg = {n: jax.lax.pmean(v, ax) for n, v in new_p.items()}
+                    new_p = {n: jnp.where(do_avg, avg[n], new_p[n]) for n in new_p}
+                    loss = jax.lax.pmean(loss, ax)
+                    new_buf = {n: jax.lax.pmean(v, ax) for n, v in new_buf.items()}
                 out_p = {n: v[None] for n, v in new_p.items()}
                 out_st = {n: (v if n == "__step__" else {m: a[None] for m, a in v.items()})
                           for n, v in new_st.items()}
@@ -1123,7 +1132,7 @@ class SpmdTrainer:
         in_shardings = (self.p_shardings, dict(self.s_shardings),
                         self.b_shardings, repl, repl) + tuple(batch_shard for _ in batch_arrays)
         out_shardings = (repl, self.p_shardings, dict(self.s_shardings), self.b_shardings)
-        return jax.jit(step, in_shardings=in_shardings,
+        return jax.jit(_aot.named(step, "train.step_localsgd"), in_shardings=in_shardings,
                        out_shardings=out_shardings,
                        donate_argnums=(0, 1, 2))  # buffers too (ISSUE 13)
 
@@ -1159,21 +1168,25 @@ class SpmdTrainer:
                     loss_fn, has_aux=True)(params_v, batch_local)
                 new_p, new_st = {}, {"__step__": st["__step__"] + 1}
                 for n, p in params.items():
-                    g = grads[n].astype(p.dtype)
-                    u = m * st[n]["dgc_u"] + g
-                    v = st[n]["dgc_v"] + u
-                    kk = max(1, int(v.size * (1.0 - sparsity)))
-                    thresh = jax.lax.top_k(jnp.abs(v).reshape(-1), kk)[0][-1]
-                    mask = (jnp.abs(v) >= thresh).astype(v.dtype)
-                    sparse = v * mask
+                    with jax.named_scope("optimizer"):
+                        g = grads[n].astype(p.dtype)
+                        u = m * st[n]["dgc_u"] + g
+                        v = st[n]["dgc_v"] + u
+                        kk = max(1, int(v.size * (1.0 - sparsity)))
+                        thresh = jax.lax.top_k(jnp.abs(v).reshape(-1), kk)[0][-1]
+                        mask = (jnp.abs(v) >= thresh).astype(v.dtype)
+                        sparse = v * mask
                     # THE DGC allreduce: only the compressed tensor crosses ranks
-                    cross = jax.lax.pmean(sparse, ax)
-                    new_p[n] = p - lr.astype(p.dtype) * cross
-                    new_st[n] = {"velocity": st[n]["velocity"],
-                                 "dgc_u": (u * (1 - mask))[None],
-                                 "dgc_v": (v * (1 - mask))[None]}
-                loss = jax.lax.pmean(loss, ax)
-                new_buf = {n: jax.lax.pmean(v, ax) for n, v in new_buf.items()}
+                    with jax.named_scope("grad_sync"):
+                        cross = jax.lax.pmean(sparse, ax)
+                    with jax.named_scope("optimizer"):
+                        new_p[n] = p - lr.astype(p.dtype) * cross
+                        new_st[n] = {"velocity": st[n]["velocity"],
+                                     "dgc_u": (u * (1 - mask))[None],
+                                     "dgc_v": (v * (1 - mask))[None]}
+                with jax.named_scope("grad_sync"):
+                    loss = jax.lax.pmean(loss, ax)
+                    new_buf = {n: jax.lax.pmean(v, ax) for n, v in new_buf.items()}
                 return loss, new_p, new_st, new_buf
 
             state_spec = {n: (P() if n == "__step__" else
@@ -1193,7 +1206,7 @@ class SpmdTrainer:
         in_shardings = (self.p_shardings, dict(self.s_shardings),
                         self.b_shardings, repl, repl) + tuple(batch_shard for _ in batch_arrays)
         out_shardings = (repl, self.p_shardings, dict(self.s_shardings), self.b_shardings)
-        return jax.jit(step, in_shardings=in_shardings,
+        return jax.jit(_aot.named(step, "train.step_dgc"), in_shardings=in_shardings,
                        out_shardings=out_shardings,
                        donate_argnums=(0, 1, 2))  # buffers too (ISSUE 13)
 
@@ -1307,145 +1320,147 @@ class SpmdTrainer:
                         ).astype(jnp.float32)
                         for n in stat_layers]), ax)
 
-                red = {}          # name -> full-shape MEAN grad (f32)
-                g_shards = {}     # name -> [ps] MEAN grad shard (f32)
-                res_out = {}
-                qerr_sq = jnp.zeros((), jnp.float32)
-                if legs:
-                    # overlapped per-layer legs: each eligible grad is
-                    # its own EF-corrected int8 exchange with a per-leg
-                    # rounding key — independent ops the scheduler can
-                    # pipeline against backward compute
-                    for i, (name, L) in enumerate(legs):
-                        shape, size, _ = shapes[name]
-                        g32 = grads[name].astype(jnp.float32).ravel()
-                        inp = (g32 + res_in[name][0]
-                               .astype(jnp.float32).ravel())
-                        flat = jnp.pad(inp, (0, L - size))
-                        _coll.record_compressed(
-                            "quantized_all_reduce", size * 4,
-                            L * bits // 8 + (L // block) * 4)
-                        reduced, local_rt = \
-                            _compress.quantized_all_reduce_ef(
-                                flat, ax, jax.random.fold_in(qkey, i),
-                                bits=bits, block=block)
-                        red[name] = (reduced[:size] / ndp).reshape(shape)
-                        r_new = (inp - local_rt[:size]).reshape(shape)
-                        res_out[name] = r_new
-                        qerr_sq = qerr_sq + jnp.sum(r_new * r_new)
-                if plan and bundle:
-                    parts, logical = [], 0
-                    for name, off, L in plan:
-                        g32 = grads[name].astype(jnp.float32).ravel()
-                        inp = (g32 + res_in[name][0]
-                               .astype(jnp.float32).ravel())
-                        parts.append(jnp.pad(inp, (0, L - g32.shape[0])))
-                        logical += shapes[name][1] * 4
-                    tail = bundle - sum(L for _, _, L in plan)
-                    if tail:
-                        parts.append(jnp.zeros((tail,), jnp.float32))
-                    flat = (jnp.concatenate(parts) if len(parts) > 1
-                            else parts[0])
-                    _coll.record_compressed(
-                        "quantized_all_reduce", logical,
-                        bundle * bits // 8 + (bundle // block) * 4)
-                    reduced, local_rt = _compress.quantized_all_reduce_ef(
-                        flat, ax, qkey, bits=bits, block=block)
-                    for name, off, L in plan:
-                        shape, size, _ = shapes[name]
-                        red[name] = (reduced[off:off + size]
-                                     / ndp).reshape(shape)
-                        r_new = (flat[off:off + size]
-                                 - local_rt[off:off + size]).reshape(shape)
-                        res_out[name] = r_new
-                        qerr_sq = qerr_sq + jnp.sum(r_new * r_new)
-                if shard_upd:
-                    for i, name in enumerate(pnames):
-                        shape, size, _ = shapes[name]
-                        ps = self._shard_ps[name]
-                        g32 = grads[name].astype(jnp.float32).ravel()
-                        if name in eligible:
+                with jax.named_scope("grad_sync"):
+                    red = {}          # name -> full-shape MEAN grad (f32)
+                    g_shards = {}     # name -> [ps] MEAN grad shard (f32)
+                    res_out = {}
+                    qerr_sq = jnp.zeros((), jnp.float32)
+                    if legs:
+                        # overlapped per-layer legs: each eligible grad is
+                        # its own EF-corrected int8 exchange with a per-leg
+                        # rounding key — independent ops the scheduler can
+                        # pipeline against backward compute
+                        for i, (name, L) in enumerate(legs):
+                            shape, size, _ = shapes[name]
+                            g32 = grads[name].astype(jnp.float32).ravel()
                             inp = (g32 + res_in[name][0]
                                    .astype(jnp.float32).ravel())
-                            flat = jnp.pad(inp, (0, ps * ndp - size))
+                            flat = jnp.pad(inp, (0, L - size))
                             _coll.record_compressed(
-                                "quantized_reduce_scatter", size * 4,
-                                ps * ndp * bits // 8
-                                + (ps * ndp // block) * 4)
-                            shard_sum, local_rt = _compress._exchange_reduce(
-                                flat, ax, jax.random.fold_in(qkey, i),
-                                bits, block)
+                                "quantized_all_reduce", size * 4,
+                                L * bits // 8 + (L // block) * 4)
+                            reduced, local_rt = \
+                                _compress.quantized_all_reduce_ef(
+                                    flat, ax, jax.random.fold_in(qkey, i),
+                                    bits=bits, block=block)
+                            red[name] = (reduced[:size] / ndp).reshape(shape)
                             r_new = (inp - local_rt[:size]).reshape(shape)
                             res_out[name] = r_new
                             qerr_sq = qerr_sq + jnp.sum(r_new * r_new)
-                        else:
-                            flat = jnp.pad(g32, (0, ps * ndp - size))
-                            _monitor.record_collective(
-                                "reduce-scatter",
-                                _monitor.tensor_nbytes(flat))
-                            shard_sum = jax.lax.psum_scatter(
-                                flat, ax, tiled=True)
-                        g_shards[name] = shard_sum / ndp
-                else:
-                    for name in pnames:
-                        if name not in red:
-                            g = grads[name]
-                            _monitor.record_collective(
-                                "all-reduce", _monitor.tensor_nbytes(g))
-                            red[name] = jax.lax.pmean(g, ax)
+                    if plan and bundle:
+                        parts, logical = [], 0
+                        for name, off, L in plan:
+                            g32 = grads[name].astype(jnp.float32).ravel()
+                            inp = (g32 + res_in[name][0]
+                                   .astype(jnp.float32).ravel())
+                            parts.append(jnp.pad(inp, (0, L - g32.shape[0])))
+                            logical += shapes[name][1] * 4
+                        tail = bundle - sum(L for _, _, L in plan)
+                        if tail:
+                            parts.append(jnp.zeros((tail,), jnp.float32))
+                        flat = (jnp.concatenate(parts) if len(parts) > 1
+                                else parts[0])
+                        _coll.record_compressed(
+                            "quantized_all_reduce", logical,
+                            bundle * bits // 8 + (bundle // block) * 4)
+                        reduced, local_rt = _compress.quantized_all_reduce_ef(
+                            flat, ax, qkey, bits=bits, block=block)
+                        for name, off, L in plan:
+                            shape, size, _ = shapes[name]
+                            red[name] = (reduced[off:off + size]
+                                         / ndp).reshape(shape)
+                            r_new = (flat[off:off + size]
+                                     - local_rt[off:off + size]).reshape(shape)
+                            res_out[name] = r_new
+                            qerr_sq = qerr_sq + jnp.sum(r_new * r_new)
+                    if shard_upd:
+                        for i, name in enumerate(pnames):
+                            shape, size, _ = shapes[name]
+                            ps = self._shard_ps[name]
+                            g32 = grads[name].astype(jnp.float32).ravel()
+                            if name in eligible:
+                                inp = (g32 + res_in[name][0]
+                                       .astype(jnp.float32).ravel())
+                                flat = jnp.pad(inp, (0, ps * ndp - size))
+                                _coll.record_compressed(
+                                    "quantized_reduce_scatter", size * 4,
+                                    ps * ndp * bits // 8
+                                    + (ps * ndp // block) * 4)
+                                shard_sum, local_rt = _compress._exchange_reduce(
+                                    flat, ax, jax.random.fold_in(qkey, i),
+                                    bits, block)
+                                r_new = (inp - local_rt[:size]).reshape(shape)
+                                res_out[name] = r_new
+                                qerr_sq = qerr_sq + jnp.sum(r_new * r_new)
+                            else:
+                                flat = jnp.pad(g32, (0, ps * ndp - size))
+                                _monitor.record_collective(
+                                    "reduce-scatter",
+                                    _monitor.tensor_nbytes(flat))
+                                shard_sum = jax.lax.psum_scatter(
+                                    flat, ax, tiled=True)
+                            g_shards[name] = shard_sum / ndp
+                    else:
+                        for name in pnames:
+                            if name not in red:
+                                g = grads[name]
+                                _monitor.record_collective(
+                                    "all-reduce", _monitor.tensor_nbytes(g))
+                                red[name] = jax.lax.pmean(g, ax)
 
                 # ---- optimizer update ---------------------------------
-                if shard_upd:
-                    wd = jnp.asarray(opt._wd, jnp.float32)
-                    stats_red = None
-                    if narmed:
-                        # the telescope reads full-shape reduced grads
-                        # (pre-clip, like the plain path); gathering them
-                        # is diagnostic-only traffic
-                        stats_red = {}
+                with jax.named_scope("optimizer"):
+                    if shard_upd:
+                        wd = jnp.asarray(opt._wd, jnp.float32)
+                        stats_red = None
+                        if narmed:
+                            # the telescope reads full-shape reduced grads
+                            # (pre-clip, like the plain path); gathering them
+                            # is diagnostic-only traffic
+                            stats_red = {}
+                            for name in pnames:
+                                shape, size, _ = shapes[name]
+                                full = jax.lax.all_gather(
+                                    g_shards[name], ax, tiled=True)
+                                stats_red[name] = full[:size].reshape(shape)
+                        if has_clip:
+                            local_sq = sum(jnp.sum(v * v)
+                                           for v in g_shards.values())
+                            gnorm = jnp.sqrt(jax.lax.psum(local_sq, ax))
+                            clip_norm = opt._grad_clip.clip_norm
+                            scale = clip_norm / jnp.maximum(gnorm, clip_norm)
+                            g_shards = {k: v * scale
+                                        for k, v in g_shards.items()}
+                        idx = jax.lax.axis_index(ax)
+                        new_params, new_st = {}, {}
                         for name in pnames:
-                            shape, size, _ = shapes[name]
-                            full = jax.lax.all_gather(
-                                g_shards[name], ax, tiled=True)
-                            stats_red[name] = full[:size].reshape(shape)
-                    if has_clip:
-                        local_sq = sum(jnp.sum(v * v)
-                                       for v in g_shards.values())
-                        gnorm = jnp.sqrt(jax.lax.psum(local_sq, ax))
-                        clip_norm = opt._grad_clip.clip_norm
-                        scale = clip_norm / jnp.maximum(gnorm, clip_norm)
-                        g_shards = {k: v * scale
-                                    for k, v in g_shards.items()}
-                    idx = jax.lax.axis_index(ax)
-                    new_params, new_st = {}, {}
-                    for name in pnames:
-                        shape, size, dtype = shapes[name]
-                        ps = self._shard_ps[name]
-                        p = params[name]
-                        p_flat = jnp.pad(jnp.ravel(p),
-                                         (0, ps * ndp - size))
-                        p_shard = jax.lax.dynamic_slice_in_dim(
-                            p_flat, idx * ps, ps)
-                        sharded = self._shard_state_keys.get(name, set())
-                        st_shard = {k: (v[0] if k in sharded else v)
-                                    for k, v in st_in[name].items()}
-                        new_p_shard, new_st_shard = opt._rule_with_decay(
-                            p_shard, g_shards[name].astype(p.dtype),
-                            st_shard, lr, wd)
-                        _monitor.record_collective(
-                            "all-gather",
-                            _monitor.tensor_nbytes(new_p_shard) * ndp)
-                        full = jax.lax.all_gather(new_p_shard, ax,
-                                                  tiled=True)
-                        new_params[name] = full[:size].reshape(shape)
-                        new_st[name] = {
-                            k: (v[None] if k in sharded else v)
-                            for k, v in new_st_shard.items()}
-                    new_st["__step__"] = st_in["__step__"] + 1
-                else:
-                    stats_red = red
-                    new_params, new_st = opt.functional_apply(
-                        params, red, st_in, lr=lr)
+                            shape, size, dtype = shapes[name]
+                            ps = self._shard_ps[name]
+                            p = params[name]
+                            p_flat = jnp.pad(jnp.ravel(p),
+                                             (0, ps * ndp - size))
+                            p_shard = jax.lax.dynamic_slice_in_dim(
+                                p_flat, idx * ps, ps)
+                            sharded = self._shard_state_keys.get(name, set())
+                            st_shard = {k: (v[0] if k in sharded else v)
+                                        for k, v in st_in[name].items()}
+                            new_p_shard, new_st_shard = opt._rule_with_decay(
+                                p_shard, g_shards[name].astype(p.dtype),
+                                st_shard, lr, wd)
+                            _monitor.record_collective(
+                                "all-gather",
+                                _monitor.tensor_nbytes(new_p_shard) * ndp)
+                            full = jax.lax.all_gather(new_p_shard, ax,
+                                                      tiled=True)
+                            new_params[name] = full[:size].reshape(shape)
+                            new_st[name] = {
+                                k: (v[None] if k in sharded else v)
+                                for k, v in new_st_shard.items()}
+                        new_st["__step__"] = st_in["__step__"] + 1
+                    else:
+                        stats_red = red
+                        new_params, new_st = opt.functional_apply(
+                            params, red, st_in, lr=lr)
 
                 loss_red = jax.lax.pmean(loss, ax)
                 nstats = None
@@ -1536,7 +1551,7 @@ class SpmdTrainer:
             out_shardings.append(repl)
         if quant:
             out_shardings.append(repl)
-        return jax.jit(step, in_shardings=in_shardings,
+        return jax.jit(_aot.named(step, "train.step_dp_compressed"), in_shardings=in_shardings,
                        out_shardings=tuple(out_shardings),
                        donate_argnums=(0, 1, 2))   # buffers too (ISSUE 13)
 

@@ -27,6 +27,8 @@ carries a ``source`` label — ``memory`` (in-process hit) or ``fresh``
 (an XLA compile was asked for) — beside ``compile_total`` and
 ``compile_ms``.
 """
+import functools
+
 import numpy as np
 import jax
 
@@ -40,7 +42,7 @@ from ..monitor import blackbox_lazy as _blackbox  # import-free recorder facade 
 from ..profiler import RecordEvent as _RecordEvent
 
 __all__ = ["args_signature", "mesh_fingerprint", "compile_cached",
-           "CachedJit", "cached_jit", "executable_of"]
+           "CachedJit", "cached_jit", "executable_of", "named"]
 
 # the compile_cache_total/compile_total families are DECLARED by their
 # call sites (static/, distributed/spmd.py) with matching labels; these
@@ -209,6 +211,28 @@ def compile_cached(jitted, example_args, *, force=False):
     return _GuardedCompiled(compiled, jitted), "fresh"
 
 
+def _name(fn, name):
+    """`name` is what jax calls the program it traces from `fn` (the
+    module `jit_<name>` of the lowered text, the compiled executable and
+    the profiler's "XLA Modules" line): it reads `__name__` when it
+    traces, not when `jax.jit` wraps."""
+    try:
+        fn.__name__ = fn.__qualname__ = name
+    except (AttributeError, TypeError):
+        pass    # a callable that takes no name keeps its own
+
+
+def named(fn, name):
+    """`fn` behind a function of that name (the caller's own is left as
+    it is: it may be jitted elsewhere under another)."""
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    _name(program, name)
+    return program
+
+
 class CachedJit:
     """A ``jax.jit`` lookalike that can be compiled ahead of time: per
     call-signature, lower once, compile, keep the executable in an
@@ -221,6 +245,10 @@ class CachedJit:
     latency-critical caller that truly has one static signature can hold
     the plain jit.
 
+    The program is named ``<site>.<label>``, the pair the cost registry
+    and ``record_compile`` key on, so that a profile's "XLA Modules" line
+    says the same word (docs/OBSERVABILITY.md "Device scopes").
+
     ``warm(*specs)`` AOT-compiles one signature from
     ``jax.ShapeDtypeStruct`` specs (plus plain python scalars for
     weakly-typed args) without real data and without executing anything —
@@ -229,11 +257,14 @@ class CachedJit:
 
     def __init__(self, fn=None, *, site, jit=None, label=None,
                  donate_argnums=(), sig_label=None, record_event=None):
-        if jit is None:
-            jit = jax.jit(fn, donate_argnums=donate_argnums)
-        self._jit = jit
         self._site = site
         self._label = label or getattr(fn, "__name__", "jit")
+        if jit is None:
+            jit = jax.jit(named(fn, f"{site}.{self._label}"),
+                          donate_argnums=donate_argnums)
+        else:
+            _name(getattr(jit, "__wrapped__", None), f"{site}.{self._label}")
+        self._jit = jit
         self._sig_label = sig_label  # callable(args) -> str, or None
         self._record_event = record_event or f"{site}/compile"
         self._store = {}

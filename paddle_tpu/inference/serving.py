@@ -516,8 +516,9 @@ class ServingEngine:
             side_tpl = jax.eval_shape(
                 lambda: dense_cache_init(1, self.T, cache_dt))
             side_alloc = jax.jit(
-                lambda: jax.tree_util.tree_map(
-                    lambda s: jnp.zeros(s.shape, s.dtype), side_tpl),
+                jax.named_scope("cache/admit")(
+                    lambda: jax.tree_util.tree_map(
+                        lambda s: jnp.zeros(s.shape, s.dtype), side_tpl)),
                 out_shardings=jax.tree_util.tree_map(
                     lambda s: shard, side_tpl))
 
@@ -569,7 +570,8 @@ class ServingEngine:
             last_logits [vocab]). Junk beyond true_len is causally
             invisible and later overwritten by the decode loop; fixed-size
             state comes back as it stood AT true_len (`valid`)."""
-            kc1, vc1 = cache_init(1, self.T, cache_dt)
+            with jax.named_scope("cache/admit"):
+                kc1, vc1 = cache_init(1, self.T, cache_dt)
             x, kc1, vc1 = fwd(p, ids_padded, 0, kc1, vc1,
                               **valid(true_len))
             x_last = jax.lax.dynamic_slice_in_dim(
@@ -608,7 +610,8 @@ class ServingEngine:
                             r if i == axis else 0
                             for i in range(b_leaf.ndim)))
 
-                return jax.tree_util.tree_map(put, big, row, slot_axes)
+                with jax.named_scope("cache/admit"):
+                    return jax.tree_util.tree_map(put, big, row, slot_axes)
 
             return admit
 
@@ -619,6 +622,7 @@ class ServingEngine:
 
         vocab = cfg.vocab_size
 
+        @jax.named_scope("pick")
         def _pick(logits, temps, kvec, pvec, seeds, pos_vec):
             """Per-row pick: temperature 0 = exact greedy (the argmax path
             is untouched); temperature > 0 samples from the (optionally
@@ -662,7 +666,9 @@ class ServingEngine:
             x, kc, vc, extra = decode(p, last_toks[:, None], pos_vec, kc,
                                       vc)
             logits = logits_of(p, x[:, 0]).astype(jnp.float32)
-            return (jnp.argmax(logits, -1).astype(jnp.int32), kc, vc) + extra
+            with jax.named_scope("pick"):
+                toks = jnp.argmax(logits, -1).astype(jnp.int32)
+            return (toks, kc, vc) + extra
 
         def step_sample(p, kc, vc, last_toks, pos_vec, temps, kvec,
                         pvec, seeds):
@@ -689,7 +695,8 @@ class ServingEngine:
                 applied (aid [1]; slot 0 = base = exact-zero add): the
                 prefilled row and first-token logits match a dedicated
                 engine serving that adapter byte-for-byte."""
-                kc1, vc1 = cache_init(1, self.T, cache_dt)
+                with jax.named_scope("cache/admit"):
+                    kc1, vc1 = cache_init(1, self.T, cache_dt)
                 x, kc1, vc1 = _fwd_pg(p, ids_padded, 0, kc1, vc1, lora, aid)
                 x_last = jax.lax.dynamic_slice_in_dim(
                     x, true_len - 1, 1, axis=1)[:, 0]
@@ -707,18 +714,22 @@ class ServingEngine:
                 kc, vc = _paging_mod.gather_dense(kp, vp, tables)
                 x, kc, vc = _fwd_pg(p, last_toks[:, None], pos_vec, kc, vc,
                                     lora, aids)
-                kp, vp = _paging_mod.scatter_cols(kp, vp, kc, vc, tables,
-                                                  pos_vec)
+                with jax.named_scope("cache/store"):
+                    kp, vp = _paging_mod.scatter_cols(kp, vp, kc, vc, tables,
+                                                      pos_vec)
                 logits = logits_of(p, x[:, 0]).astype(jnp.float32)
-                return jnp.argmax(logits, -1).astype(jnp.int32), kp, vp
+                with jax.named_scope("pick"):
+                    toks = jnp.argmax(logits, -1).astype(jnp.int32)
+                return toks, kp, vp
 
             def step_sample_paged(p, kp, vp, tables, last_toks, pos_vec,
                                   temps, kvec, pvec, seeds, lora, aids):
                 kc, vc = _paging_mod.gather_dense(kp, vp, tables)
                 x, kc, vc = _fwd_pg(p, last_toks[:, None], pos_vec, kc, vc,
                                     lora, aids)
-                kp, vp = _paging_mod.scatter_cols(kp, vp, kc, vc, tables,
-                                                  pos_vec)
+                with jax.named_scope("cache/store"):
+                    kp, vp = _paging_mod.scatter_cols(kp, vp, kc, vc, tables,
+                                                      pos_vec)
                 logits = logits_of(p, x[:, 0]).astype(jnp.float32)
                 return (_pick(logits, temps, kvec, pvec, seeds, pos_vec),
                         kp, vp)
@@ -792,7 +803,8 @@ class ServingEngine:
             reads; the token alone comes back too, for the host to read
             when it reads the round's others."""
             tok = pick1(lg, t, k, tp, s, p_)
-            return tok, toks.at[slot].set(tok)
+            with jax.named_scope("pick"):
+                return tok, toks.at[slot].set(tok)
 
         # one decode step of lookahead (_step_inner_lookahead): dense
         # engines without a draft keep a step in flight and read its
@@ -825,6 +837,9 @@ class ServingEngine:
         # DONATES its cache args, so admissions consume a fresh COPY
         self._prefixes = {}
         self._next_pid = 0
+        # (its device work is the copy XLA makes of an undonated argument:
+        # no operation of the program's own to carry a scope; the module's
+        # name, serving.copy_cache, says what it is)
         self._copy_cache = _cj(
             lambda c: jax.tree_util.tree_map(jnp.array, c), "copy_cache")
 

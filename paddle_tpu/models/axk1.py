@@ -60,6 +60,7 @@ half of the pair is empty.
 
 Forward only: the expert loop's trip count is data (distributed/moe.py).
 """
+import jax
 import numpy as np
 
 from ..ops import rope as _rope
@@ -188,7 +189,6 @@ def param_shapes(cfg):
 def _default_init(cfg):
     """normal(0, init_std) matrices and unit gains; keys from the
     framework's generator."""
-    import jax
     import jax.numpy as jnp
 
     from ..core import generator as _generator
@@ -213,7 +213,6 @@ def _attend_naive(q_nope, q_rope, lat, w_kvb, limit, live, cfg):
     block up-projects its columns to per-head keys and values and is folded
     into a running float32 softmax.
     Returns [B, t, H, dv] in q_nope's dtype."""
-    import jax
     import jax.numpy as jnp
 
     B, t, H, dn = q_nope.shape
@@ -261,7 +260,6 @@ def _attend_absorbed(q_nope, q_rope, lat, l, w_kvb, at, cfg):
     the chip through ops/latent_decode_attention.py (the live tiles, each
     read once), elsewhere through masked einsums over all T columns. Returns
     [B, H, dv]."""
-    import jax
     import jax.numpy as jnp
 
     from ..ops import latent_decode_attention as _lda
@@ -294,7 +292,6 @@ def _attn(p, pre, cfg, inv_freq, rot_scale, h, lat, l, pos):
     layer's index l; pos: a [B] vector (one token a row, each at its own
     column: the absorbed form), or a scalar (the whole batch writes columns
     pos..pos + t: the naive form). Returns (y float32, lat)."""
-    import jax
     import jax.numpy as jnp
 
     B, t, _ = h.shape
@@ -307,32 +304,41 @@ def _attn(p, pre, cfg, inv_freq, rot_scale, h, lat, l, pos):
         where = at[:, None]                                 # [B, 1]
     else:
         where = (pos + jnp.arange(t, dtype=jnp.int32))[None, :]     # [1, t]
-    c_q = _rms(_dot32(h, p[pre + "q_a.weight"]), p[pre + "q_norm.weight"],
-               cfg.rms_norm_eps).astype(h.dtype)
-    q = _dot(c_q, p[pre + "q_b.weight"]).reshape(B, t, H, dn + dr)
-    q_nope = q[..., :dn]
-    q_rope = _rope.rotate(q[..., dn:], where[:, :, None], inv_freq,
-                          rot_scale).astype(h.dtype)
-    kv = _dot32(h, p[pre + "kv_a.weight"])                  # [B, t, r + dr]
-    new = jnp.concatenate(
-        [_rms(kv[..., :r], p[pre + "kv_norm.weight"], cfg.rms_norm_eps),
-         _rope.rotate(kv[..., r:], where, inv_freq, rot_scale),
-         jnp.zeros((B, t, lat.shape[-1] - r - dr), jnp.float32)],
-        axis=-1).astype(lat.dtype)
+    with jax.named_scope("proj"):
+        c_q = _rms(_dot32(h, p[pre + "q_a.weight"]),
+                   p[pre + "q_norm.weight"], cfg.rms_norm_eps).astype(h.dtype)
+        q = _dot(c_q, p[pre + "q_b.weight"]).reshape(B, t, H, dn + dr)
+        q_nope = q[..., :dn]
+        q_rope = _rope.rotate(q[..., dn:], where[:, :, None], inv_freq,
+                              rot_scale).astype(h.dtype)
+        kv = _dot32(h, p[pre + "kv_a.weight"])              # [B, t, r + dr]
+        new = jnp.concatenate(
+            [_rms(kv[..., :r], p[pre + "kv_norm.weight"], cfg.rms_norm_eps),
+             _rope.rotate(kv[..., r:], where, inv_freq, rot_scale),
+             jnp.zeros((B, t, lat.shape[-1] - r - dr), jnp.float32)],
+            axis=-1).astype(lat.dtype)
     w_kvb = p[pre + "kv_b.weight"]
-    if per_row:
-        lat = lat.at[l, jnp.arange(B), at].set(new[:, 0])
-        o = _attend_absorbed(q_nope[:, 0], q_rope[:, 0], lat, l, w_kvb, at,
-                             cfg)[:, None]
-    else:
-        lat = jax.lax.dynamic_update_slice(lat, new[None], (l, 0, pos, 0))
-        if isinstance(pos, int) and pos == 0:
-            # a sequence from its start attends to itself alone
-            seen, live = new, None
+    with jax.named_scope("cache/store"):
+        if per_row:
+            lat = lat.at[l, jnp.arange(B), at].set(new[:, 0])
         else:
-            seen, live = lat[l], pos + t
-        o = _attend_naive(q_nope, q_rope, seen, w_kvb, where, live, cfg)
-    return _dot32(o.reshape(B, t, H * dv), p[pre + "o.weight"]), lat
+            lat = jax.lax.dynamic_update_slice(lat, new[None],
+                                               (l, 0, pos, 0))
+    # the up-projections the two forms fold into or around their scores
+    # are part of the form: the naive one's inside its loop over blocks
+    with jax.named_scope("core"):
+        if per_row:
+            o = _attend_absorbed(q_nope[:, 0], q_rope[:, 0], lat, l, w_kvb,
+                                 at, cfg)[:, None]
+        else:
+            if isinstance(pos, int) and pos == 0:
+                # a sequence from its start attends to itself alone
+                seen, live = new, None
+            else:
+                seen, live = lat[l], pos + t
+            o = _attend_naive(q_nope, q_rope, seen, w_kvb, where, live, cfg)
+    with jax.named_scope("proj"):
+        return _dot32(o.reshape(B, t, H * dv), p[pre + "o.weight"]), lat
 
 
 def _ffn(p, pre, cfg, l, h, h32):
@@ -343,8 +349,9 @@ def _ffn(p, pre, cfg, l, h, h32):
     B, t, d = h.shape
     flat = h.reshape(B * t, d)
     if l < cfg.first_k_dense:
-        y = _moe_ops.gated_mlp(flat, *(p[pre + f"mlp.{n}.weight"]
-                                       for n in ("gate", "up", "down")))
+        with jax.named_scope("mlp"):
+            y = _moe_ops.gated_mlp(flat, *(p[pre + f"mlp.{n}.weight"]
+                                           for n in ("gate", "up", "down")))
         return y.reshape(B, t, d), None
     m = pre + "moe."
     shared = None
@@ -380,16 +387,21 @@ def _decode_fns(cfg):
         is taken and not needed: a latent past a sequence's end is junk
         nobody sees."""
         cdt = p["embed.weight"].dtype
-        x = p["embed.weight"][toks].astype(jnp.float32)
+        with jax.named_scope("embed"):
+            x = p["embed.weight"][toks].astype(jnp.float32)
         lat = kv["latent"]
         total = jnp.zeros((len(STEP_COUNTS),), jnp.int32)
         for l in range(cfg.num_layers):
             pre = f"layers.{l}."
-            h = _rms(x, p[pre + "norm1.weight"], cfg.rms_norm_eps).astype(cdt)
-            y, lat = _attn(p, pre + "attn.", cfg, inv_freq, rot_scale, h,
-                           lat, l, pos)
-            x = x + y
-            h32 = _rms(x, p[pre + "norm2.weight"], cfg.rms_norm_eps)
+            # a layer's norm lies under the layer's word
+            with jax.named_scope("attn"):
+                h = _rms(x, p[pre + "norm1.weight"],
+                         cfg.rms_norm_eps).astype(cdt)
+                y, lat = _attn(p, pre + "attn.", cfg, inv_freq, rot_scale, h,
+                               lat, l, pos)
+                x = x + y
+            with jax.named_scope("mlp" if l < cfg.first_k_dense else "moe"):
+                h32 = _rms(x, p[pre + "norm2.weight"], cfg.rms_norm_eps)
             y, c = _ffn(p, pre, cfg, l, h32.astype(cdt), h32)
             x = x + y
             if c is not None:
@@ -399,8 +411,9 @@ def _decode_fns(cfg):
 
     def logits_of(p, x):
         w = p["lm_head.weight"]
-        return _dot32(_rms(x, p["norm.weight"],
-                           cfg.rms_norm_eps).astype(w.dtype), w)
+        with jax.named_scope("head"):
+            return _dot32(_rms(x, p["norm.weight"],
+                               cfg.rms_norm_eps).astype(w.dtype), w)
 
     return fwd, logits_of, cache_init
 
@@ -466,8 +479,6 @@ class AXK1DecodeModel(_decode_model.DecodeModel):
     def kv_read_tile(self, cfg, side, dtype, tp_size=1):
         """The tile ops/latent_decode_attention.py walks a row in where the
         absorbed step takes it (a TPU); None where the einsums read all."""
-        import jax
-
         from ..ops import latent_decode_attention as _lda
 
         lat = side["latent"]

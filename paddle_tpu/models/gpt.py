@@ -12,6 +12,7 @@ the reference tree — this is the framework's own model zoo.
 import math
 import re
 
+import jax
 import numpy as np
 
 from .. import nn
@@ -180,23 +181,30 @@ class GPTAttention(nn.Layer):
     def forward(self, x):
         b, s, h = x.shape
         H, K, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        qkv = self.qkv(x)
         from ..tensor.manipulation import split as tsplit
 
-        # boundary split [q | k | v]: identical to the historical
-        # (3, H, hd) unpacking when K == H
-        q, k, v = tsplit(qkv, [H * hd, K * hd, K * hd], axis=-1)
-        q = q.reshape([b, s, H, hd])
-        k = k.reshape([b, s, K, hd])
-        v = v.reshape([b, s, K, hd])
-        if K != H:
-            # expand shared K/V heads across their query groups for the
-            # dense/flash attention math (the cache-side decode keeps the
-            # compact K heads — that is where GQA's memory win lives)
-            from ..tensor.manipulation import repeat_interleave
+        # the output projection is the sublayer `proj`: the same scope
+        with jax.named_scope("proj"):
+            qkv = self.qkv(x)
+            # boundary split [q | k | v]: identical to the historical
+            # (3, H, hd) unpacking when K == H
+            q, k, v = tsplit(qkv, [H * hd, K * hd, K * hd], axis=-1)
+            q = q.reshape([b, s, H, hd])
+            k = k.reshape([b, s, K, hd])
+            v = v.reshape([b, s, K, hd])
+            if K != H:
+                # expand shared K/V heads across their query groups for the
+                # dense/flash attention math (the cache-side decode keeps
+                # the compact K heads — that is where GQA's memory win lives)
+                from ..tensor.manipulation import repeat_interleave
 
-            k = repeat_interleave(k, H // K, axis=2)
-            v = repeat_interleave(v, H // K, axis=2)
+                k = repeat_interleave(k, H // K, axis=2)
+                v = repeat_interleave(v, H // K, axis=2)
+        with jax.named_scope("core"):
+            out = self._core(q, k, v, s)
+        return self.proj(out.reshape([b, s, h]))
+
+    def _core(self, q, k, v, s):
         if self.sp_mesh is not None and "sp" in self.sp_mesh.axis_names:
             from ..core.dispatch import apply
             from ..distributed.long_context import sequence_parallel_attention
@@ -217,19 +225,17 @@ class GPTAttention(nn.Layer):
                     f"ulysses_flash needs seq ({s}) in 128-token flash "
                     f"blocks: pad to a multiple of 128 or use "
                     f"sp_impl='ulysses'")
-            out = apply(
+            return apply(
                 lambda qv, kv, vv: sequence_parallel_attention(
                     qv, kv, vv, self.sp_mesh, impl=self.sp_impl, causal=True),
                 q, k, v)
-        else:
-            out = F.scaled_dot_product_attention(
-                q, k, v, is_causal=True,
-                dropout_p=self.dropout if self.training else 0.0,
-                training=self.training,
-                use_flash=self.use_flash,
-                window=self.window,
-            )
-        return self.proj(out.reshape([b, s, h]))
+        return F.scaled_dot_product_attention(
+            q, k, v, is_causal=True,
+            dropout_p=self.dropout if self.training else 0.0,
+            training=self.training,
+            use_flash=self.use_flash,
+            window=self.window,
+        )
 
 
 class GPTMLP(nn.Layer):
@@ -266,10 +272,16 @@ class GPTBlock(nn.Layer):
         self.drop = nn.Dropout(cfg.dropout)
 
     def forward(self, x):
-        x = x + self.drop(self.attn(self.ln1(x)))
+        # a sublayer's norm lies under the sublayer's word, as in the
+        # decode programs (docs/OBSERVABILITY.md "Device scopes")
+        with jax.named_scope("attn"):
+            h = self.ln1(x)
+        x = x + self.drop(self.attn(h))
         y = self._tpp_mlp(x)
         if y is None:
-            y = self.mlp(self.ln2(x))
+            with jax.named_scope("mlp"):
+                h = self.ln2(x)
+            y = self.mlp(h)
         x = x + self.drop(y)
         return x
 
@@ -319,13 +331,15 @@ class GPTModel(nn.Layer):
 
         from ..tensor.creation import arange
 
-        pos = arange(s, dtype="int32")  # int32: x64 is off on TPU/CPU — an "int64" request
-        # is truncated with a per-call UserWarning (caught by the analysis trace-warnings gate)
-        x = self.wte(input_ids) + self.wpe(pos)
-        x = self.drop(x)
+        with jax.named_scope("embed"):
+            pos = arange(s, dtype="int32")  # int32: x64 is off on TPU/CPU — an "int64" request
+            # is truncated with a per-call UserWarning (caught by the analysis trace-warnings gate)
+            x = self.wte(input_ids) + self.wpe(pos)
+            x = self.drop(x)
         for blk in self.blocks:
             x = blk(x)
-        return self.ln_f(x)
+        with jax.named_scope("head"):
+            return self.ln_f(x)
 
 
 class GPTForCausalLM(nn.Layer):
@@ -341,11 +355,13 @@ class GPTForCausalLM(nn.Layer):
         if getattr(self, "lm_head", None) is not None:
             # untied head installed by pipeline_split: after pipelined training
             # the trained head lives here, not in wte
-            return self.lm_head(h)
+            with jax.named_scope("head"):
+                return self.lm_head(h)
         # tied head: logits = h @ wte^T
         from ..tensor.math import matmul
 
-        return matmul(h, self.gpt.wte.weight, transpose_y=True)
+        with jax.named_scope("head"):
+            return matmul(h, self.gpt.wte.weight, transpose_y=True)
 
     def loss(self, input_ids, labels):
         logits = self.forward(input_ids)
@@ -430,7 +446,8 @@ class GPTForCausalLM(nn.Layer):
 class GPTPretrainLoss(nn.Layer):
     def forward(self, logits, labels):
         b, s, v = logits.shape
-        return F.cross_entropy(logits.reshape([b * s, v]), labels.reshape([b * s]))
+        with jax.named_scope("loss"):
+            return F.cross_entropy(logits.reshape([b * s, v]), labels.reshape([b * s]))
 
 
 # ---------------------------------------------------------------------------
@@ -603,110 +620,125 @@ def _decode_fns(cfg, untied, untied_bias, cache_dtype=None, tp_axis=None,
             # exact-zero add in xin's dtype, never a dtype promotion
             return (d * lora["_scale"][:, None, None]).astype(xin.dtype)
 
-        h_in = ln(x, p[pre + "ln1.weight"], p[pre + "ln1.bias"])
-        if tp_axis is not None:
-            # column-parallel qkv over LOCAL heads: weight [h, 3, H_loc, hd]
-            qkv = jnp.einsum("bti,iknd->btknd",
-                             h_in, p[pre + "attn.qkv.weight"]) \
-                + p[pre + "attn.qkv.bias"]
-            q = jnp.moveaxis(qkv[:, :, 0], 1, 2)      # [B, H_loc, t, hd]
-            k = jnp.moveaxis(qkv[:, :, 1], 1, 2)
-            v = jnp.moveaxis(qkv[:, :, 2], 1, 2)
-        else:
-            # boundary split [q | k | v] — identical to the historical
-            # (3, H, hd) unpacking for MHA, compact kv heads for GQA
-            flat = h_in @ p[pre + "attn.qkv.weight"] \
-                + p[pre + "attn.qkv.bias"]
-            if lora is not None and "qkv" in lora:
-                flat = flat + _ldelta(h_in, "qkv")
-            q = jnp.moveaxis(
-                flat[..., :Hh * hd].reshape(bb, t, Hh, hd), 1, 2)
-            k = jnp.moveaxis(
-                flat[..., Hh * hd:(Hh + KVh) * hd].reshape(bb, t, KVh, hd),
-                1, 2)
-            v = jnp.moveaxis(
-                flat[..., (Hh + KVh) * hd:].reshape(bb, t, KVh, hd), 1, 2)
-        # a decode step on the chip leaves the store to its attention
-        fused = _live_tile(kc, q, pos, win, key_valid)
-        if not fused:
-            kc = _store(kc, k, i, pos)
-            vc = _store(vc, v, i, pos)
-        # causal over cache columns: query row r (column pos+r) sees cache
-        # column c iff c <= pos + r. pos is a scalar (whole batch at one
-        # frontier) or [B] (per-slot frontiers — continuous batching); one
-        # mask construction serves both via a leading 1-or-B dim.
-        pos_b = jnp.atleast_1d(pos)
-        rows = pos_b[:, None, None] + jnp.arange(t)[None, :, None]
-        cols = jnp.arange(T)[None, None, :]
-        mask = cols <= rows                            # [1-or-B, t, T]
-        if win is not None:  # sliding window: same band as training
-            mask &= (rows - cols) < win
-        if key_valid is not None:
-            self_col = cols == rows                    # keep self: no NaN rows
-            mask = mask & (key_valid[:, None, :] | self_col)
-        if fused:
-            # tiles 0..pos[b] // 128 of row b and no column beyond them;
-            # the last of them takes the new column and is written back
-            out, kc, vc = _decode_attention.decode_attention_store(
-                kc, vc, q, k, v, i, pos)
-        elif g == 1:
-            att = jnp.einsum("bhtd,bhTd->bhtT", q,
-                             _load(kc, i, q.dtype)) * scale
-            att = jnp.where(mask[:, None], att, -jnp.inf)
-            att = jax.nn.softmax(att, axis=-1)
-            out = jnp.einsum("bhtT,bhTd->bhtd", att,
-                             _load(vc, i, att.dtype))
-        else:
-            # grouped queries share their kv head: [B, KVh, g, t, *]
-            qg = q.reshape(bb, KVh, g, t, hd)
-            att = jnp.einsum("bkgtd,bkTd->bkgtT", qg,
-                             _load(kc, i, q.dtype)) * scale
-            att = jnp.where(mask[:, None, None], att, -jnp.inf)
-            att = jax.nn.softmax(att, axis=-1)
-            out = jnp.einsum("bkgtT,bkTd->bkgtd", att,
-                             _load(vc, i, att.dtype)).reshape(
-                                 bb, Hh, t, hd)
-        out = jnp.moveaxis(out, 1, 2).reshape(bb, t, H_loc * hd)
-        proj = out @ p[pre + "attn.proj.weight"]  # row-parallel under tp
-        if lora is not None and "proj" in lora:
-            proj = proj + _ldelta(out, "proj")
-        if tp_axis is not None:
-            proj = jax.lax.psum(proj, tp_axis)
-        x = x + proj + p[pre + "attn.proj.bias"]
-        h2 = ln(x, p[pre + "ln2.weight"], p[pre + "ln2.bias"])
-        a1 = h2 @ p[pre + "mlp.fc1.weight"] + p[pre + "mlp.fc1.bias"]
-        if lora is not None and "fc1" in lora:
-            a1 = a1 + _ldelta(h2, "fc1")
-        h2 = jax.nn.gelu(a1, approximate=getattr(cfg, "gelu_approx", False))
-        mlp = h2 @ p[pre + "mlp.fc2.weight"]      # row-parallel under tp
-        if lora is not None and "fc2" in lora:
-            mlp = mlp + _ldelta(h2, "fc2")
-        if tp_axis is not None:
-            mlp = jax.lax.psum(mlp, tp_axis)
-        x = x + mlp + p[pre + "mlp.fc2.bias"]
+        with jax.named_scope("attn"):
+            with jax.named_scope("proj"):
+                h_in = ln(x, p[pre + "ln1.weight"], p[pre + "ln1.bias"])
+                if tp_axis is not None:
+                    # column-parallel qkv over LOCAL heads: weight
+                    # [h, 3, H_loc, hd]
+                    qkv = jnp.einsum("bti,iknd->btknd",
+                                     h_in, p[pre + "attn.qkv.weight"]) \
+                        + p[pre + "attn.qkv.bias"]
+                    q = jnp.moveaxis(qkv[:, :, 0], 1, 2)  # [B, H_loc, t, hd]
+                    k = jnp.moveaxis(qkv[:, :, 1], 1, 2)
+                    v = jnp.moveaxis(qkv[:, :, 2], 1, 2)
+                else:
+                    # boundary split [q | k | v] — identical to the
+                    # historical (3, H, hd) unpacking for MHA, compact kv
+                    # heads for GQA
+                    flat = h_in @ p[pre + "attn.qkv.weight"] \
+                        + p[pre + "attn.qkv.bias"]
+                    if lora is not None and "qkv" in lora:
+                        flat = flat + _ldelta(h_in, "qkv")
+                    q = jnp.moveaxis(
+                        flat[..., :Hh * hd].reshape(bb, t, Hh, hd), 1, 2)
+                    k = jnp.moveaxis(
+                        flat[..., Hh * hd:(Hh + KVh) * hd].reshape(
+                            bb, t, KVh, hd), 1, 2)
+                    v = jnp.moveaxis(
+                        flat[..., (Hh + KVh) * hd:].reshape(bb, t, KVh, hd),
+                        1, 2)
+            # a decode step on the chip leaves the store to its attention
+            fused = _live_tile(kc, q, pos, win, key_valid)
+            if not fused:
+                with jax.named_scope("cache/store"):
+                    kc = _store(kc, k, i, pos)
+                    vc = _store(vc, v, i, pos)
+            with jax.named_scope("core"):
+                # causal over cache columns: query row r (column pos+r) sees
+                # cache column c iff c <= pos + r. pos is a scalar (whole batch
+                # at one frontier) or [B] (per-slot frontiers — continuous
+                # batching); one mask construction serves both via a leading
+                # 1-or-B dim.
+                pos_b = jnp.atleast_1d(pos)
+                rows = pos_b[:, None, None] + jnp.arange(t)[None, :, None]
+                cols = jnp.arange(T)[None, None, :]
+                mask = cols <= rows                            # [1-or-B, t, T]
+                if win is not None:  # sliding window: same band as training
+                    mask &= (rows - cols) < win
+                if key_valid is not None:
+                    self_col = cols == rows        # keep self: no NaN rows
+                    mask = mask & (key_valid[:, None, :] | self_col)
+                if fused:
+                    # tiles 0..pos[b] // 128 of row b and no column beyond
+                    # them; the last of them takes the new column and is
+                    # written back
+                    out, kc, vc = _decode_attention.decode_attention_store(
+                        kc, vc, q, k, v, i, pos)
+                elif g == 1:
+                    att = jnp.einsum("bhtd,bhTd->bhtT", q,
+                                     _load(kc, i, q.dtype)) * scale
+                    att = jnp.where(mask[:, None], att, -jnp.inf)
+                    att = jax.nn.softmax(att, axis=-1)
+                    out = jnp.einsum("bhtT,bhTd->bhtd", att,
+                                     _load(vc, i, att.dtype))
+                else:
+                    # grouped queries share their kv head: [B, KVh, g, t, *]
+                    qg = q.reshape(bb, KVh, g, t, hd)
+                    att = jnp.einsum("bkgtd,bkTd->bkgtT", qg,
+                                     _load(kc, i, q.dtype)) * scale
+                    att = jnp.where(mask[:, None, None], att, -jnp.inf)
+                    att = jax.nn.softmax(att, axis=-1)
+                    out = jnp.einsum("bkgtT,bkTd->bkgtd", att,
+                                     _load(vc, i, att.dtype)).reshape(
+                                         bb, Hh, t, hd)
+            with jax.named_scope("proj"):
+                out = jnp.moveaxis(out, 1, 2).reshape(bb, t, H_loc * hd)
+                # row-parallel under tp
+                proj = out @ p[pre + "attn.proj.weight"]
+                if lora is not None and "proj" in lora:
+                    proj = proj + _ldelta(out, "proj")
+                if tp_axis is not None:
+                    proj = jax.lax.psum(proj, tp_axis)
+                x = x + proj + p[pre + "attn.proj.bias"]
+        with jax.named_scope("mlp"):
+            h2 = ln(x, p[pre + "ln2.weight"], p[pre + "ln2.bias"])
+            a1 = h2 @ p[pre + "mlp.fc1.weight"] + p[pre + "mlp.fc1.bias"]
+            if lora is not None and "fc1" in lora:
+                a1 = a1 + _ldelta(h2, "fc1")
+            h2 = jax.nn.gelu(a1,
+                             approximate=getattr(cfg, "gelu_approx", False))
+            mlp = h2 @ p[pre + "mlp.fc2.weight"]      # row-parallel under tp
+            if lora is not None and "fc2" in lora:
+                mlp = mlp + _ldelta(h2, "fc2")
+            if tp_axis is not None:
+                mlp = jax.lax.psum(mlp, tp_axis)
+            x = x + mlp + p[pre + "mlp.fc2.bias"]
         return x, kc, vc
 
     def logits_of(p, x_last):
-        h = ln(x_last, p["gpt.ln_f.weight"], p["gpt.ln_f.bias"])
-        if untied:
-            out = h @ p["lm_head.weight"]
-            return out + p["lm_head.bias"] if untied_bias else out
-        return h @ p["gpt.wte.weight"].T
+        with jax.named_scope("head"):
+            h = ln(x_last, p["gpt.ln_f.weight"], p["gpt.ln_f.bias"])
+            if untied:
+                out = h @ p["lm_head.weight"]
+                return out + p["lm_head.bias"] if untied_bias else out
+            return h @ p["gpt.wte.weight"].T
 
     def fwd(p, tok_ids, pos, kc, vc, key_valid=None, pos_ids=None,
             lora=None, adapter_ids=None):
         t = tok_ids.shape[1]
-        if pos_ids is None:
-            if jnp.ndim(pos) == 1:   # per-row pos needs per-row pe too
-                pos_ids = pos[:, None] + jnp.arange(t)[None, :]
-                wpe = jnp.take(p["gpt.wpe.weight"], pos_ids, axis=0)
+        with jax.named_scope("embed"):
+            if pos_ids is None:
+                if jnp.ndim(pos) == 1:   # per-row pos needs per-row pe too
+                    pos_ids = pos[:, None] + jnp.arange(t)[None, :]
+                    wpe = jnp.take(p["gpt.wpe.weight"], pos_ids, axis=0)
+                else:
+                    wpe = jax.lax.dynamic_slice_in_dim(p["gpt.wpe.weight"],
+                                                       pos, t)
             else:
-                wpe = jax.lax.dynamic_slice_in_dim(p["gpt.wpe.weight"],
-                                                   pos, t)
-        else:
-            # ragged rows: per-row position ids (left-padding support)
-            wpe = jnp.take(p["gpt.wpe.weight"], pos_ids, axis=0)
-        x = jnp.take(p["gpt.wte.weight"], tok_ids, axis=0) + wpe
+                # ragged rows: per-row position ids (left-padding support)
+                wpe = jnp.take(p["gpt.wpe.weight"], pos_ids, axis=0)
+            x = jnp.take(p["gpt.wte.weight"], tok_ids, axis=0) + wpe
         lg = None
         if lora is not None:
             if tp_axis is not None:
@@ -1341,9 +1373,10 @@ class GPTEmbed(nn.Layer):
         from ..tensor.creation import arange
 
         s = input_ids.shape[-1]
-        pos = arange(s, dtype="int32")  # int32: x64 is off on TPU/CPU — an "int64" request
-        # is truncated with a per-call UserWarning (caught by the analysis trace-warnings gate)
-        return self.drop(self.wte(input_ids) + self.wpe(pos))
+        with jax.named_scope("embed"):
+            pos = arange(s, dtype="int32")  # int32: x64 is off on TPU/CPU — an "int64" request
+            # is truncated with a per-call UserWarning (caught by the analysis trace-warnings gate)
+            return self.drop(self.wte(input_ids) + self.wpe(pos))
 
 
 class GPTStage(nn.Layer):
@@ -1377,10 +1410,12 @@ class GPTHeadLoss(nn.Layer):
         self.head.weight._data = wte_weight._data.T.copy()
 
     def forward(self, h, labels):
-        h = self.ln_f(h)
+        with jax.named_scope("head"):
+            h = self.ln_f(h)
         logits = self.head(h)
         b, s, v = logits.shape
-        return F.cross_entropy(logits.reshape([b * s, v]), labels.reshape([b * s]))
+        with jax.named_scope("loss"):
+            return F.cross_entropy(logits.reshape([b * s, v]), labels.reshape([b * s]))
 
 
 def _gpt_pipeline_split(model, pp_degree):

@@ -37,6 +37,8 @@ Forward only: the expert loop's trip count is data (distributed/moe.py).
 """
 import math
 
+import jax
+
 from ..serving import decode_model as _decode_model
 from ._functional_lm import (STEP_COUNTS, FunctionalCausalLM, count_vector,
                              dot as _dot, dot32 as _dot32,
@@ -164,7 +166,6 @@ def _default_init(cfg):
     taps uniform in +-1/sqrt(conv_size), A = exp(A_log) uniform in 1..16 and
     a dt_bias whose softplus is log-uniform in 0.001..0.1 (the ranges the
     `fla` layers start from); keys from the framework's generator."""
-    import jax
     import jax.numpy as jnp
 
     from ..core import generator as _generator
@@ -198,7 +199,6 @@ def _attend(q, keys, vals, limit, scale):
     i of row b sees columns 0..limit[b, i]. Softmax in float32, queries in
     blocks of _Q_BLOCK so that no [t, S] score tensor of all heads is held
     at once. Returns [B, t, H, hd] in q's dtype."""
-    import jax
     import jax.numpy as jnp
 
     B, t, H, hd = q.shape
@@ -233,34 +233,40 @@ def _gqa(p, pre, cfg, h, K, V, gi, pos):
     layer's index gi; pos: a [B] vector (one token a row, each at its own
     column), or a scalar (the whole batch writes columns pos..pos + t).
     Returns (y, K, V)."""
-    import jax
     import jax.numpy as jnp
 
     B, t, _ = h.shape
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     T = K.shape[2]
-    q = _dot(h, p[pre + "q.weight"]).reshape(B, t, H, hd)
-    k = _dot(h, p[pre + "k.weight"]).reshape(B, t, KV, hd).astype(K.dtype)
-    v = _dot(h, p[pre + "v.weight"]).reshape(B, t, KV, hd).astype(V.dtype)
-    if jnp.ndim(pos) == 1:
-        at = jnp.clip(pos, 0, T - 1)
-        rows = jnp.arange(B)
-        K = K.at[gi, rows, at].set(k[:, 0])
-        V = V.at[gi, rows, at].set(v[:, 0])
-        keys, vals, limit = K[gi], V[gi], at[:, None]
-    else:
-        K = jax.lax.dynamic_update_slice(K, k[None], (gi, 0, pos, 0, 0))
-        V = jax.lax.dynamic_update_slice(V, v[None], (gi, 0, pos, 0, 0))
-        steps = jnp.arange(t, dtype=jnp.int32)[None, :]
-        if isinstance(pos, int) and pos == 0:
-            # a sequence from its start attends to itself alone
-            keys, vals, limit = k, v, steps
+    with jax.named_scope("proj"):
+        q = _dot(h, p[pre + "q.weight"]).reshape(B, t, H, hd)
+        k = _dot(h, p[pre + "k.weight"]).reshape(B, t, KV, hd).astype(K.dtype)
+        v = _dot(h, p[pre + "v.weight"]).reshape(B, t, KV, hd).astype(V.dtype)
+    with jax.named_scope("cache/store"):
+        if jnp.ndim(pos) == 1:
+            at = jnp.clip(pos, 0, T - 1)
+            rows = jnp.arange(B)
+            K = K.at[gi, rows, at].set(k[:, 0])
+            V = V.at[gi, rows, at].set(v[:, 0])
         else:
-            keys, vals, limit = K[gi], V[gi], pos + steps
-    o = _attend(q, keys, vals, limit, 1.0 / math.sqrt(hd))
-    gate = jax.nn.sigmoid(_dot32(h, p[pre + "g.weight"]))
-    o = (gate * o.reshape(B, t, H * hd).astype(jnp.float32)).astype(h.dtype)
-    return _dot32(o, p[pre + "o.weight"]), K, V
+            K = jax.lax.dynamic_update_slice(K, k[None], (gi, 0, pos, 0, 0))
+            V = jax.lax.dynamic_update_slice(V, v[None], (gi, 0, pos, 0, 0))
+    with jax.named_scope("core"):
+        if jnp.ndim(pos) == 1:
+            keys, vals, limit = K[gi], V[gi], at[:, None]
+        else:
+            steps = jnp.arange(t, dtype=jnp.int32)[None, :]
+            if isinstance(pos, int) and pos == 0:
+                # a sequence from its start attends to itself alone
+                keys, vals, limit = k, v, steps
+            else:
+                keys, vals, limit = K[gi], V[gi], pos + steps
+        o = _attend(q, keys, vals, limit, 1.0 / math.sqrt(hd))
+    with jax.named_scope("proj"):
+        gate = jax.nn.sigmoid(_dot32(h, p[pre + "g.weight"]))
+        o = (gate * o.reshape(B, t, H * hd).astype(jnp.float32)).astype(
+            h.dtype)
+        return _dot32(o, p[pre + "o.weight"]), K, V
 
 
 def _kda(p, pre, cfg, h, S, conv, valid_len):
@@ -269,7 +275,6 @@ def _kda(p, pre, cfg, h, S, conv, valid_len):
     call. t = 1 takes the recurrence, t > 1 the chunked form, with positions
     from `valid_len` on (None: t) leaving S and conv as they were.
     Returns (y, S, conv)."""
-    import jax
     import jax.numpy as jnp
 
     from ..ops import kda as _kda_ops
@@ -277,48 +282,56 @@ def _kda(p, pre, cfg, h, S, conv, valid_len):
     f32 = jnp.float32
     B, t, _ = h.shape
     H, dk, K = cfg.kda_num_heads, cfg.kda_head_dim, cfg.conv_size
-    proj = jnp.concatenate([_dot(h, p[pre + n + ".weight"])
-                            for n in ("q", "k", "v")], axis=-1)
-    ext = jnp.concatenate([conv.astype(proj.dtype), proj], axis=1)
-    taps = jnp.concatenate([p[pre + n + "_conv.weight"]
-                            for n in ("q", "k", "v")], axis=-1).astype(f32)
-    mixed = sum(taps[j] * ext[:, j:j + t].astype(f32) for j in range(K))
-    mixed = jax.nn.silu(mixed)                               # [B, t, 3 H dk]
-    if t == 1:
-        conv = ext[:, 1:].astype(conv.dtype)
-    else:
-        n = t if valid_len is None else valid_len
-        conv = jax.lax.dynamic_slice_in_dim(ext, n, K - 1, axis=1).astype(
-            conv.dtype)
-    q, k, v = (x.reshape(B, t, H, dk) for x in jnp.split(mixed, 3, axis=-1))
+    with jax.named_scope("proj"):
+        proj = jnp.concatenate([_dot(h, p[pre + n + ".weight"])
+                                for n in ("q", "k", "v")], axis=-1)
+    with jax.named_scope("conv"):
+        ext = jnp.concatenate([conv.astype(proj.dtype), proj], axis=1)
+        taps = jnp.concatenate([p[pre + n + "_conv.weight"]
+                                for n in ("q", "k", "v")],
+                               axis=-1).astype(f32)
+        mixed = sum(taps[j] * ext[:, j:j + t].astype(f32) for j in range(K))
+        mixed = jax.nn.silu(mixed)                           # [B, t, 3 H dk]
+        if t == 1:
+            conv = ext[:, 1:].astype(conv.dtype)
+        else:
+            n = t if valid_len is None else valid_len
+            conv = jax.lax.dynamic_slice_in_dim(
+                ext, n, K - 1, axis=1).astype(conv.dtype)
+        q, k, v = (x.reshape(B, t, H, dk)
+                   for x in jnp.split(mixed, 3, axis=-1))
 
     def l2(x):
         return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
 
-    q, k = l2(q), l2(k)
-    beta = 2.0 * jax.nn.sigmoid(_dot32(h, p[pre + "b.weight"]))  # [B, t, H]
-    f = _dot32(_dot(h, p[pre + "f_down.weight"]), p[pre + "f_up.weight"]) \
-        + p[pre + "dt_bias"].astype(f32)
-    g = -jnp.exp(p[pre + "A_log"].astype(f32))[:, None] \
-        * jax.nn.softplus(f).reshape(B, t, H, dk)
+    with jax.named_scope("proj"):
+        q, k = l2(q), l2(k)
+        beta = 2.0 * jax.nn.sigmoid(_dot32(h, p[pre + "b.weight"]))  # [B,t,H]
+        f = _dot32(_dot(h, p[pre + "f_down.weight"]),
+                   p[pre + "f_up.weight"]) + p[pre + "dt_bias"].astype(f32)
+        g = -jnp.exp(p[pre + "A_log"].astype(f32))[:, None] \
+            * jax.nn.softplus(f).reshape(B, t, H, dk)
     scale = 1.0 / math.sqrt(dk)
-    if t == 1:
-        o, S = _kda_ops.recurrent_step(S, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                       beta[:, 0], scale)
-        o = o[:, None]
-    else:
-        if valid_len is not None:
-            live = (jnp.arange(t) < valid_len)[None, :, None]
-            g = jnp.where(live[..., None], g, 0.0)
-            beta = jnp.where(live, beta, 0.0)
-        o, S = _kda_ops.chunked(S, q, k, v, g, beta, scale)
-    gate = jax.nn.sigmoid(_dot32(
-        _dot(h, p[pre + "g_down.weight"]),
-        p[pre + "g_up.weight"])).reshape(B, t, H, dk)
-    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
-                          + cfg.rms_norm_eps)
-    o = o * p[pre + "o_norm.weight"].astype(f32) * gate
-    y = _dot32(o.reshape(B, t, H * dk).astype(h.dtype), p[pre + "o.weight"])
+    with jax.named_scope("state"):
+        if t == 1:
+            o, S = _kda_ops.recurrent_step(S, q[:, 0], k[:, 0], v[:, 0],
+                                           g[:, 0], beta[:, 0], scale)
+            o = o[:, None]
+        else:
+            if valid_len is not None:
+                live = (jnp.arange(t) < valid_len)[None, :, None]
+                g = jnp.where(live[..., None], g, 0.0)
+                beta = jnp.where(live, beta, 0.0)
+            o, S = _kda_ops.chunked(S, q, k, v, g, beta, scale)
+    with jax.named_scope("proj"):
+        gate = jax.nn.sigmoid(_dot32(
+            _dot(h, p[pre + "g_down.weight"]),
+            p[pre + "g_up.weight"])).reshape(B, t, H, dk)
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                              + cfg.rms_norm_eps)
+        o = o * p[pre + "o_norm.weight"].astype(f32) * gate
+        y = _dot32(o.reshape(B, t, H * dk).astype(h.dtype),
+                   p[pre + "o.weight"])
     return y, S, conv
 
 
@@ -368,23 +381,28 @@ def _decode_fns(cfg):
         # layers' inputs are rounded to it, their sum is not, and the
         # router reads the normalised stream before the rounding
         cdt = p["embed.weight"].dtype
-        x = p["embed.weight"][toks].astype(jnp.float32)
+        with jax.named_scope("embed"):
+            x = p["embed.weight"][toks].astype(jnp.float32)
         K, V = kv["k"], kv["v"]
         S, conv = list(fixed["recurrent"]), list(fixed["conv"])
         total = jnp.zeros((len(STEP_COUNTS),), jnp.int32)
         gi = ki = 0
         for l in range(cfg.num_layers):
             pre = f"layers.{l}."
-            h = _rms(x, p[pre + "norm1.weight"], cfg.rms_norm_eps).astype(cdt)
-            if l in cfg.gqa_layers:
-                y, K, V = _gqa(p, pre + "attn.", cfg, h, K, V, gi, pos)
-                gi += 1
-            else:
-                y, S[ki], conv[ki] = _kda(p, pre + "kda.", cfg, h, S[ki],
-                                          conv[ki], valid_len)
-                ki += 1
-            x = x + y
-            h32 = _rms(x, p[pre + "norm2.weight"], cfg.rms_norm_eps)
+            # a layer's norm and residual sum lie under the layer's word
+            with jax.named_scope("attn" if l in cfg.gqa_layers else "kda"):
+                h = _rms(x, p[pre + "norm1.weight"],
+                         cfg.rms_norm_eps).astype(cdt)
+                if l in cfg.gqa_layers:
+                    y, K, V = _gqa(p, pre + "attn.", cfg, h, K, V, gi, pos)
+                    gi += 1
+                else:
+                    y, S[ki], conv[ki] = _kda(p, pre + "kda.", cfg, h,
+                                              S[ki], conv[ki], valid_len)
+                    ki += 1
+                x = x + y
+            with jax.named_scope("moe"):
+                h32 = _rms(x, p[pre + "norm2.weight"], cfg.rms_norm_eps)
             y, c = _moe(p, pre + "moe.", cfg, h32.astype(cdt), h32)
             x = x + y
             total = total + count_vector(c)
@@ -394,8 +412,9 @@ def _decode_fns(cfg):
 
     def logits_of(p, x):
         w = p["lm_head.weight"]
-        return _dot32(_rms(x, p["norm.weight"],
-                           cfg.rms_norm_eps).astype(w.dtype), w)
+        with jax.named_scope("head"):
+            return _dot32(_rms(x, p["norm.weight"],
+                               cfg.rms_norm_eps).astype(w.dtype), w)
 
     return fwd, logits_of, cache_init
 

@@ -264,7 +264,7 @@ class LayerList(Layer):
         return self._sub_layers[str(idx)]
 
     def __setitem__(self, idx, layer):
-        self._sub_layers[str(idx)] = layer
+        self.add_sublayer(str(idx), layer)
 
     def __len__(self):
         return len(self._sub_layers)
@@ -281,7 +281,7 @@ class LayerList(Layer):
         layers.insert(index, layer)
         self._sub_layers.clear()
         for i, l in enumerate(layers):
-            self._sub_layers[str(i)] = l
+            self.add_sublayer(str(i), l)
 
     def extend(self, layers):
         for l in layers:

@@ -10,6 +10,7 @@ this is the bridge between the stateful dygraph API and XLA's functional world.
 """
 import collections
 
+import jax
 import numpy as np
 
 from ...core import dtype as dtype_mod
@@ -28,6 +29,9 @@ class Layer:
         self.training = True
         self._dtype = dtype_mod.convert_dtype(dtype)
         self._name = name_scope or self.__class__.__name__.lower()
+        # the jax.named_scope of __call__: the name the parent registered
+        # this layer under (None at the root: the class name)
+        self._scope = None
 
     # ---- registration --------------------------------------------------------
     def __setattr__(self, name, value):
@@ -44,6 +48,7 @@ class Layer:
             self.__dict__.pop(name, None)
         elif isinstance(value, Layer):
             layers[name] = value
+            _registered(self, name, value)
             for d in (params, buffers):
                 if d is not None and name in d:
                     del d[name]
@@ -75,6 +80,7 @@ class Layer:
 
     def add_sublayer(self, name, sublayer):
         self._sub_layers[name] = sublayer
+        _registered(self, name, sublayer)
         return sublayer
 
     def register_buffer(self, name, tensor, persistable=True):
@@ -235,7 +241,11 @@ class Layer:
             result = hook(self, inputs)
             if result is not None:
                 inputs = result if isinstance(result, tuple) else (result,)
-        out = self.forward(*inputs, **kwargs)
+        # the device's time under the program's own names: metadata of
+        # the lowered program, nothing else (docs/OBSERVABILITY.md
+        # "Device scopes")
+        with jax.named_scope(self._scope or type(self).__name__):
+            out = self.forward(*inputs, **kwargs)
         for hook in self._forward_post_hooks.values():
             result = hook(self, inputs, out)
             if result is not None:
@@ -303,6 +313,21 @@ class Layer:
             rep = repr(l).replace("\n", "\n  ")
             lines.append(f"  ({name}): {rep}")
         return "\n".join(lines) + ")" if len(lines) > 1 else lines[0] + ")"
+
+
+def _registered(parent, name, layer):
+    """Give `layer` the scope it runs under when `parent` registers it as
+    `name`. A member of a list (a name of digits) runs under the list's own
+    name, so that the same sublayer of every block counts as one:
+    `blocks/attn`, not `blocks/7/attn`; a list that is named later hands
+    its name down to the members it holds."""
+    scope = parent._scope if name.isdigit() else name
+    if layer is None or scope is None:
+        return
+    layer._scope = scope
+    for sub_name, sub in layer._sub_layers.items():
+        if sub_name.isdigit():
+            _registered(layer, sub_name, sub)
 
 
 class _HookRemoveHelper:

@@ -95,7 +95,7 @@ class SyncBatchNorm(_BatchNormBase):
             out._mean.set_value(layer._mean.numpy())
             out._variance.set_value(layer._variance.numpy())
         for name, sub in list(layer._sub_layers.items()):
-            layer._sub_layers[name] = cls.convert_sync_batchnorm(sub)
+            layer.add_sublayer(name, cls.convert_sync_batchnorm(sub))
         return out
 
 

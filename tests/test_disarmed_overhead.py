@@ -4,7 +4,11 @@ plain attribute read inside a `with` over a no-op context manager — in
 the same breath, best of five rounds each. A loaded worker slows both
 sides, so the ratio holds where a budget in microseconds does not (the
 suite runs six workers to a machine; `test_phase_under_3us` was red on
-the driver for that reason alone).
+the driver for that reason alone). A burst of load that falls on one
+side's rounds alone still moves a ratio (`step_phase` read over its limit
+beside a chip call's wait and passed alone), so a case is timed up to
+ATTEMPTS times and held to the best of them: a slow path is slow every
+time, a neighbour is not.
 
 One case for each flag's "unset costs one boolean check" claim; the
 other tests of each `tests/test_*_gate.py` stay where they are. A
@@ -25,6 +29,8 @@ from paddle_tpu.distributed.spmd import SpmdTrainer
 
 #: a disarmed path may cost this many reference units a call
 DISARMED = 10.0
+#: a case is timed at most this often; its best reading is held to the limit
+ATTEMPTS = 3
 
 
 class _Probe:
@@ -236,23 +242,71 @@ def _idle_engine_step():
     return [eng.step], 400.0, 2_000, None
 
 
+def _eager_layer_call():
+    """`nn.Layer.__call__` opens the `jax.named_scope` it runs under on
+    every call, traced or not (docs/OBSERVABILITY.md "Device scopes"): a
+    layer that does nothing, called eagerly, costs the two empty hook
+    loops, that scope and the call; 8-10 reference units on the sandbox's
+    CPU, of them 6-7 the scope. The limit is 25."""
+    from paddle_tpu import nn
+
+    class Through(nn.Layer):
+        def forward(self, x):
+            return x
+
+    layer = Through()
+    return [lambda: layer(None)], 25.0, 20_000, None
+
+
 CASES = [_cached_jit_unwarmed, _step_phase, _failpoint,
          _failpoint_transform_and_numerics_flag, _blackbox_beacon_and_note,
          _trace_span, _monitor_disabled, _async_and_tpp_flags,
          _compress_flags, _goodput_flag, _perf_ledger_flag, _elastic_flag,
-         _mpmd_flag, _idle_engine_step]
+         _mpmd_flag, _idle_engine_step, _eager_layer_call]
+
+
+def best_reading(case):
+    """(worst body's ratio, limit) of the best of up to ATTEMPTS timings of
+    `case`, each set up afresh and checked; stops at the first under the
+    limit."""
+    best = float("inf")
+    for _ in range(ATTEMPTS):
+        bodies, limit, n, check = case()
+        try:
+            ratios = [cost_ratio(body, n) for body in bodies]
+        finally:
+            if check is not None:
+                check(5 * n)
+        best = min(best, max(ratios))
+        if best < limit:
+            break
+    return best, limit
 
 
 @pytest.mark.parametrize("case", CASES,
                          ids=[c.__name__.lstrip("_") for c in CASES])
 def test_cost_in_reference_units(case):
-    bodies, limit, n, check = case()
-    try:
-        ratios = [cost_ratio(body, n) for body in bodies]
-    finally:
-        if check is not None:
-            check(5 * n)
-    assert max(ratios) < limit, (
-        f"{case.__name__}: {[round(r, 2) for r in ratios]} reference "
-        f"units a call against a limit of {limit} — the fast path "
+    best, limit = best_reading(case)
+    assert best < limit, (
+        f"{case.__name__}: {best:.2f} reference units a call at best of "
+        f"{ATTEMPTS} timings against a limit of {limit} — the fast path "
         "regressed")
+
+
+def test_a_slowed_phase_still_fails(monkeypatch):
+    """The best of several timings forgives a neighbour, not the code: with
+    20 us of work put into every `trace.phase` the step-phase case reads
+    over its limit on every attempt."""
+    real = trace.phase
+
+    @contextlib.contextmanager
+    def slowed(name, **counts):
+        t_end = time.perf_counter() + 20e-6
+        while time.perf_counter() < t_end:
+            pass
+        with real(name, **counts) as ph:
+            yield ph
+
+    monkeypatch.setattr(trace, "phase", slowed)
+    best, limit = best_reading(_step_phase)
+    assert best >= limit, (best, limit)
