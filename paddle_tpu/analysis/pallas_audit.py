@@ -6,7 +6,8 @@ Every Pallas kernel family the repo ships (the TPP micro-kernel registry
 ``ops/nms_pallas.py``, the decode step's in-place KV-cache store
 ``ops/kv_store.py``, its read of the live cache tiles, with and without
 that store folded in, ``ops/decode_attention.py`` and the latent family's
-``ops/latent_decode_attention.py``) exposes an ``audit_manifest()``: a list of
+``ops/latent_decode_attention.py``, and its prefill chunk's
+``ops/latent_prefill_attention.py``) exposes an ``audit_manifest()``: a list of
 declarative entries describing what each kernel compiles to at its
 representative shapes — grid dims with their block edges, every
 VMEM-resident buffer's block shape and dtype, scratch allocations, and
@@ -168,11 +169,12 @@ def collect_manifest():
     """Every registered kernel family's manifest entries. Imports the
     ops modules (jax import cost only — nothing compiles or runs)."""
     from ..ops import decode_attention, flash_attention, kv_store, \
-        latent_decode_attention, nms_pallas, tpp
+        latent_decode_attention, latent_prefill_attention, nms_pallas, tpp
 
     entries = []
     for mod in (tpp, flash_attention, nms_pallas, kv_store,
-                decode_attention, latent_decode_attention):
+                decode_attention, latent_decode_attention,
+                latent_prefill_attention):
         entries.extend(mod.audit_manifest())
     return entries
 

@@ -79,6 +79,7 @@ request's trace, and `admit_prefilled()` accepts a KV row prefilled by a
 bit-identical to local admission.
 """
 import collections
+import functools
 import time
 
 import numpy as np
@@ -545,6 +546,10 @@ class ServingEngine:
         # (stats()["kv_tiles_written"]); 0 where the store is its own
         self._kv_tiles_written = self.B * dm.kv_tiles_written(
             cfg, k_side, cache_dt, tp_size)
+        # the key tiles a prefill chunk's attention walks through a kernel
+        # over its layers (stats()["chunk_kv_tiles"]), by chunk width
+        self._chunk_kv_tile = functools.lru_cache(maxsize=None)(
+            lambda width: dm.chunk_kv_tile(cfg, k_side, cache_dt, width))
 
         fixed_state, count_names = self._fixed_state, self._count_names
 
@@ -951,7 +956,7 @@ class ServingEngine:
                    "prefix_hit": 0, "prefix_miss": 0,
                    "occupancy_sum": 0, "occupancy_steps": 0,
                    "kv_tiles_read": 0, "kv_tiles_held": 0,
-                   "kv_tiles_written": 0,
+                   "kv_tiles_written": 0, "chunk_kv_tiles": 0,
                    "state_bytes_moved": {k: 0 for k in self._state_held},
                    "step_counts": {n: 0 for n in self._count_names},
                    "lookahead": {"rounds": 0, "rounds_overlapped": 0,
@@ -1262,6 +1267,9 @@ class ServingEngine:
             "kv_tiles_read": m["kv_tiles_read"],
             "kv_tiles_held": m["kv_tiles_held"],
             "kv_tiles_written": m["kv_tiles_written"],
+            # key tiles the prefill chunks' attention walked through a
+            # kernel, over the layers; 0 where it is not a kernel's walk
+            "chunk_kv_tiles": m["chunk_kv_tiles"],
             # bytes of state by kind (kv, recurrent, conv): what the engine
             # holds, and what its decode steps so far had to move of it
             "state_bytes": {"held": dict(self._state_held),
@@ -2494,6 +2502,11 @@ class ServingEngine:
                     kc1d, vc1d = self._draft_feed(self._params_d,
                                                   jnp.asarray(chunk),
                                                   np.int32(off), kc1d, vc1d)
+                tile = self._chunk_kv_tile(C)
+                walked = 0 if tile is None else \
+                    tile[1] * -(-min(off + C, self.T) // tile[0])
+                ph.counts["chunk_kv_tiles"] = walked
+                self._m["chunk_kv_tiles"] += walked
                 if end >= n:
                     del self._prefilling[slot]
                     self._slot_req[slot] = None   # _activate re-binds

@@ -25,10 +25,13 @@ for all heads.** Two forms of the same attention over it, picked by `t`:
   naive (t > 1: a whole prompt, or a chunk at `offset` over the row's cached
     latents): `[k_nope_i | v_i] = W_kvb,i c_kv` for every head i, scores
     `(q_nope_i . k_nope_i + q_rope_i . k_rope) scale`, causal softmax in
-    float32, `o_i = sum p v_i`. The keys are walked in blocks of `_K_BLOCK`
-    columns with a running softmax, live blocks only, so a chunk expands
-    the columns up to its own last one and never a `[t, T]` score tensor of
-    all heads.
+    float32, `o_i = sum p v_i`. The keys are walked with a running
+    softmax, live columns only, so a chunk expands the columns up to its
+    own last one and never a `[t, T]` score tensor of all heads. On the
+    chip through ops/latent_prefill_attention.py (a chunk, or a whole
+    prompt of up to 1,024 tokens: tiles of 512 columns up-projected,
+    scored and folded in fast memory, one call a layer); elsewhere an
+    einsum loop over blocks of `_K_BLOCK` columns, the kernel's reference.
   absorbed (t = 1, every row at its own `pos`): with `W_kvb,i = [W_UK,i ;
     W_UV,i]`, `q_lat_i = W_UK,i^T q_nope_i` (r wide), scores `([q_lat_i |
     q_rope_i] . [c_kv | k_rope]) scale`: H heads against ONE shared `[T, r +
@@ -204,21 +207,32 @@ def _default_init(cfg):
 
 # -- the pure functions --------------------------------------------------------
 
-def _attend_naive(q_nope, q_rope, lat, w_kvb, limit, live, cfg):
-    """The naive form. q_nope [B, t, H, dn], q_rope [B, t, H, dr]; lat [B, S,
-    W], the latents the queries may see; w_kvb [r, H (dn + dv)]; limit
-    [B or 1, t]: query i of row b sees columns 0..limit[b, i]; live: how many
-    of the S columns some query sees (a traced scalar, or None: all): the
-    blocks of `min(_K_BLOCK, S)` columns past them are not walked. Every
-    block up-projects its columns to per-head keys and values and is folded
-    into a running float32 softmax.
+def _attend_naive(q_nope, q_rope, lat, l, w_kvb, offset, cfg):
+    """The naive form. q_nope [B, t, H, dn], q_rope [B, t, H, dr]; lat [L,
+    B, S, W], layer l's are the latents the queries may see (the cache as it
+    lies: nothing is sliced out of it); w_kvb [r, H (dn + dv)]; query i
+    sees columns 0..offset + i (`offset` a traced scalar or 0), so no query
+    sees past column offset + t. On the chip, where the kernel takes the
+    call (`live_only`), through ops/latent_prefill_attention.py: the live
+    tiles of 512 columns, up-projected, scored and folded into a running
+    softmax in fast memory, one call a layer. Elsewhere an einsum loop over
+    the live blocks of `min(_K_BLOCK, S)` columns: every block up-projects
+    its columns to per-head keys and values and is folded into a running
+    float32 softmax; it is the kernel's reference.
     Returns [B, t, H, dv] in q_nope's dtype."""
     import jax.numpy as jnp
 
+    from ..ops import latent_prefill_attention as _lpa
+
+    if _lpa.live_only(q_nope, q_rope, lat, w_kvb):
+        return _lpa.latent_prefill_attention(q_nope, q_rope, lat, l, w_kvb,
+                                             offset, cfg.softmax_scale)
     B, t, H, dn = q_nope.shape
     r, dr, dv = cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.v_head_dim
+    lat = lat[l]
     S = lat.shape[1]
     kb = min(_K_BLOCK, S)
+    limit = (offset + jnp.arange(t, dtype=jnp.int32))[None, :]
     total = -(-S // kb)
     if S % kb:
         lat = jnp.pad(lat, [(0, 0), (0, total * kb - S), (0, 0)])
@@ -247,7 +261,8 @@ def _attend_naive(q_nope, q_rope, lat, w_kvb, limit, live, cfg):
     init = (jnp.full((B, H, t), _MASKED, jnp.float32),
             jnp.zeros((B, H, t), jnp.float32),
             jnp.zeros((B, t, H, dv), jnp.float32))
-    n = total if live is None else jnp.minimum((live + kb - 1) // kb, total)
+    n = total if isinstance(offset, int) and offset + t >= S else \
+        jnp.minimum((offset + t + kb - 1) // kb, total)
     _, l, acc = jax.lax.fori_loop(0, n, block, init)
     return (acc / jnp.moveaxis(l, 1, 2)[..., None]).astype(q_nope.dtype)
 
@@ -331,12 +346,10 @@ def _attn(p, pre, cfg, inv_freq, rot_scale, h, lat, l, pos):
             o = _attend_absorbed(q_nope[:, 0], q_rope[:, 0], lat, l, w_kvb,
                                  at, cfg)[:, None]
         else:
-            if isinstance(pos, int) and pos == 0:
-                # a sequence from its start attends to itself alone
-                seen, live = new, None
-            else:
-                seen, live = lat[l], pos + t
-            o = _attend_naive(q_nope, q_rope, seen, w_kvb, where, live, cfg)
+            # a sequence from its start attends to itself alone
+            seen, layer = (new[None], 0) \
+                if isinstance(pos, int) and pos == 0 else (lat, l)
+            o = _attend_naive(q_nope, q_rope, seen, layer, w_kvb, pos, cfg)
     with jax.named_scope("proj"):
         return _dot32(o.reshape(B, t, H * dv), p[pre + "o.weight"]), lat
 
@@ -485,6 +498,21 @@ class AXK1DecodeModel(_decode_model.DecodeModel):
         q = jax.ShapeDtypeStruct((lat.shape[1], cfg.num_heads, lat.shape[3]),
                                  dtype)
         return _lda.tile_of(lat.shape[2]) if _lda.live_only(lat, q) else None
+
+    def chunk_kv_tile(self, cfg, side, dtype, width):
+        """ops/latent_prefill_attention.py's key tile, in every layer, where
+        the naive form of a chunk takes the kernel (a TPU)."""
+        from ..ops import latent_prefill_attention as _lpa
+
+        H, r = cfg.num_heads, cfg.kv_lora_rank
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
+            cfg.v_head_dim
+        L, _, T, W = side["latent"].shape
+        shapes = ((1, width, H, dn), (1, width, H, dr), (L, 1, T, W),
+                  (r, H * (dn + dv)))
+        if _lpa.live_only(*(jax.ShapeDtypeStruct(s, dtype) for s in shapes)):
+            return _lpa.TILE, cfg.num_layers
+        return None
 
     def matches(self, model):
         return isinstance(model, AXK1ForCausalLM)

@@ -155,6 +155,13 @@ class DecodeModel:
         row, the step's column in them; 0: the store is not its work."""
         return 0
 
+    def chunk_kv_tile(self, cfg, side, dtype, width):
+        """(tile, layers) where a prefill chunk of `width` queries over a
+        row of ``side`` walks the row's live key tiles of `tile` columns
+        through a kernel, once in each of `layers` layers; None: no
+        kernel's walk."""
+        return None
+
     # -- optional (multi-LoRA batched decode, FLAGS_paged_kv engines) ------
     def lora_init(self, cfg, n_slots, rank, dtype=None):
         """Zero-filled stacked adapter pytree for ``n_slots`` adapter

@@ -301,6 +301,154 @@ def test_the_latent_kernel_is_the_einsums_over_live_tiles(monkeypatch):
     assert len(calls) == 2
 
 
+def _small_prefill_kernel(monkeypatch):
+    """ops/latent_prefill_attention.py at the test model's size: lanes of
+    8, key tiles of 16 columns, strips of 8 queries, groups of 2 heads."""
+    from paddle_tpu.ops import latent_prefill_attention as lpa
+
+    for name, value in (("LANE", 8), ("TILE", 16), ("STRIP", 8),
+                        ("GROUP", 2)):
+        monkeypatch.setattr(lpa, name, value)
+    return lpa
+
+
+_PREFILL_CASES = {
+    # name: (t, S, offset, rows) of the kernel against the einsum loop
+    "offset 0, a whole prompt": (32, 32, 0, 1),
+    "offset inside a tile": (16, 64, 5, 1),
+    "offset on a tile's first column": (16, 64, 16, 1),
+    "offset on a tile's last column": (16, 64, 15, 1),
+    "several tiles live": (32, 96, 40, 1),
+    "live columns to the row's end": (16, 64, 48, 1),
+    "two rows of a batch": (16, 64, 21, 2),
+}
+_PREFILL_REFUSED = {
+    # name: what `fits` is handed instead of the good call
+    "channels not in whole lanes": lambda qn, qr, lat, w: (
+        qn[..., :12], qr, lat, w[:, :4 * 28]),
+    "queries not in whole strips": lambda qn, qr, lat, w: (
+        qn[:, :12], qr[:, :12], lat, w),
+    "more queries than fast memory holds": lambda qn, qr, lat, w: (
+        *(_rows(a, 1032) for a in (qn, qr)), lat, w),
+    "columns not in whole tiles": lambda qn, qr, lat, w: (
+        qn, qr, lat[:, :, :40], w),
+    "heads not in whole groups": lambda qn, qr, lat, w: (
+        qn[:, :, :3], qr[:, :, :3], lat, w[:, :3 * 32]),
+    "the rotary part past the cache's width": lambda qn, qr, lat, w: (
+        qn, qr, lat[..., :32], w),
+    "mixed dtypes": lambda qn, qr, lat, w: (
+        qn, qr, lat.astype("bfloat16"), w),
+}
+
+
+def _rows(a, rows):
+    """a [B, t, ...] repeated along t to `rows` rows (whole strips)."""
+    import jax.numpy as jnp
+
+    return jnp.resize(a, (a.shape[0], rows) + a.shape[2:])
+
+
+@pytest.mark.parametrize("case", list(_PREFILL_CASES) + [
+    "junk past the live columns changes nothing",
+    "a query that sees one column", "the model's chunk and whole prompt"]
+    + [f"refused: {k}" for k in _PREFILL_REFUSED])
+def test_the_prefill_kernel_is_the_einsum_loop(monkeypatch, case):
+    """ops/latent_prefill_attention.py in interpret mode against
+    `_attend_naive`'s einsum loop (float32 both: the order of the sums
+    alone): a whole prompt, offsets inside a tile and on its first and last
+    column, several tiles live, junk past the live columns, a query that
+    sees one column; the model's chunk and whole prompt with the kernel
+    taken (as on a chip) and refused: the same hidden states, one call a
+    layer; and one case for each thing `fits` refuses."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.models import axk1
+
+    lpa = _small_prefill_kernel(monkeypatch)
+    rng = np.random.default_rng(abs(hash(case)) % 2**31)
+    m = _model()
+    cfg = m.cfg
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    W = cfg.cache_width
+
+    def call(t, S, B=1):
+        qn = jnp.asarray(rng.normal(size=(B, t, H, dn)), jnp.float32)
+        qr = jnp.asarray(rng.normal(size=(B, t, H, dr)), jnp.float32)
+        lat = rng.normal(size=(2, B, S, W)).astype(np.float32)
+        lat[..., r + dr:] = 0                   # two layers: the second read
+        w = jnp.asarray(0.3 * rng.normal(size=(r, H * (dn + dv))),
+                        jnp.float32)
+        return qn, qr, jnp.asarray(lat), w
+
+    def both(args, offset):
+        qn, qr, lat, w = args
+        got = lpa.latent_prefill_attention(qn, qr, lat, 1, w, offset,
+                                           cfg.softmax_scale, interpret=True)
+        monkeypatch.setattr(axk1, "_K_BLOCK", 32)   # blocks of another size
+        want = axk1._attend_naive(qn, qr, lat, 1, w,
+                                  jnp.int32(offset) if offset else 0, cfg)
+        return np.asarray(got), np.asarray(want)
+
+    if case in _PREFILL_CASES:
+        t, S, offset, B = _PREFILL_CASES[case]
+        args = call(t, S, B)
+        assert lpa.fits(*args)
+        got, want = both(args, offset)
+        assert got.shape == (B, t, H, dv)
+        np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
+    elif case.startswith("junk"):
+        qn, qr, lat, w = call(16, 64)
+        got = lpa.latent_prefill_attention(qn, qr, lat, 1, w, 20,
+                                           cfg.softmax_scale, interpret=True)
+        lat = lat.at[1, :, 36:].set(1e9)      # past column 20 + 16 - 1
+        lat = lat.at[0].set(1e9)              # and the other layer
+        again = lpa.latent_prefill_attention(qn, qr, lat, 1, w, 20,
+                                             cfg.softmax_scale,
+                                             interpret=True)
+        np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
+    elif case.startswith("a query that sees"):
+        qn, qr, lat, w = call(16, 32)
+        got = lpa.latent_prefill_attention(qn, qr, lat, 0, w, 0,
+                                           cfg.softmax_scale, interpret=True)
+        v0 = (lat[0, 0, 0, :r] @ w).reshape(H, dn + dv)[:, dn:]
+        np.testing.assert_allclose(np.asarray(got)[0, 0], np.asarray(v0),
+                                   atol=1e-5, rtol=0)
+    elif case.startswith("the model"):
+        (fwd, _, cache_init), p = _fns(m)
+        ids = _ids(48, seed=5)
+        kv, rest = cache_init(1, 256, jnp.float32)
+        _, kv, rest = fwd(p, jnp.asarray(ids[None, :32]), 0, kv, rest)
+        chunk = jnp.asarray(ids[None, 32:48])
+        want_chunk, want_kv, _ = fwd(p, chunk, jnp.int32(32), kv, rest)
+        want_whole, *_ = fwd(p, jnp.asarray(ids[None, :32]), 0,
+                             *cache_init(1, 256, jnp.float32))
+        calls = []
+        real = lpa.latent_prefill_attention
+        monkeypatch.setattr(lpa, "live_only", lpa.fits)
+        monkeypatch.setattr(lpa, "latent_prefill_attention",
+                            lambda *a, **kw: calls.append(a[2].shape)
+                            or real(*a, **kw))
+        got_chunk, got_kv, _ = fwd(p, chunk, jnp.int32(32), kv, rest)
+        L = cfg.num_layers
+        assert calls == [(L, 1, 256, W)] * L                # one a layer
+        np.testing.assert_allclose(np.asarray(got_chunk),
+                                   np.asarray(want_chunk), atol=TOL, rtol=0)
+        np.testing.assert_allclose(np.asarray(got_kv["latent"]),
+                                   np.asarray(want_kv["latent"]), atol=TOL,
+                                   rtol=0)
+        got_whole, *_ = fwd(p, jnp.asarray(ids[None, :32]), 0,
+                            *cache_init(1, 256, jnp.float32))
+        assert calls[L:] == [(1, 1, 32, W)] * L
+        np.testing.assert_allclose(np.asarray(got_whole),
+                                   np.asarray(want_whole), atol=TOL, rtol=0)
+    else:
+        good = call(16, 64)
+        assert lpa.fits(*good)
+        assert not lpa.fits(*_PREFILL_REFUSED[case[len("refused: "):]](
+            *good))
+
+
 def test_the_router_takes_no_bias():
     """`select_bias=None`: the k largest scores themselves, weights 2.5 s /
     sum s; a bias of zeros gives the same experts and weights."""
@@ -502,6 +650,36 @@ def test_one_slot_prefills_in_chunks_at_a_time():
             assert chunks[-1] == 5 + 3 + 6
             assert max(b - a for a, b in zip(chunks, chunks[1:])) == 1
     assert outs[0] == outs[1]
+
+
+@pytest.mark.parametrize("kernel", [True, False])
+def test_chunk_kv_tiles_counts_the_walked_tiles(monkeypatch, kernel):
+    """A prompt of two chunks of 16 (columns 0..15, then 16..31; key tiles
+    of 16): the kernel walks 1 then 2 tiles in each of 3 layers, and every
+    `serve/prefill_chunk` phase notes its own; 0 where the einsum loop
+    runs. The tokens are the same either way."""
+    from paddle_tpu import trace
+
+    lpa = _small_prefill_kernel(monkeypatch)
+    if kernel:
+        monkeypatch.setattr(lpa, "live_only", lpa.fits)
+    eng = _engine(_model(), prefill_chunk=16)
+    since = trace._now_ns()
+    rid = eng.submit(_ids(20, seed=3), max_new_tokens=4)
+    eng.run_until_complete()
+    rows, _ = trace.phases(since)
+    noted = [c["chunk_kv_tiles"] for name, *_, c in rows
+             if name == "serve/prefill_chunk"]
+    layers = eng.cfg.num_layers
+    assert noted == ([layers * 1, layers * 2] if kernel else [0, 0])
+    assert eng.stats()["chunk_kv_tiles"] == sum(noted)
+    # the reference's tokens: the engine of the einsum loop's
+    ref_eng = _engine(_model(), prefill_chunk=16)
+    ref_rid = ref_eng.submit(_ids(20, seed=3), max_new_tokens=4)
+    monkeypatch.undo()
+    ref_eng.run_until_complete()
+    assert list(eng.get_request(rid).output_ids) == \
+        list(ref_eng.get_request(ref_rid).output_ids)
 
 
 def test_a_slot_reused_by_a_shorter_request_is_a_fresh_slot(ref):

@@ -521,3 +521,52 @@ def test_latent_step_keeps_no_copy_of_its_cache(chip, monkeypatch, width,
         assert temp > leaf
     else:
         assert temp < 3 * scores < leaf, (temp, scores)
+
+
+@pytest.mark.parametrize("kernel", [True, False])
+def test_latent_prefill_chunk_keeps_its_scores_in_fast_memory(
+        chip, monkeypatch, kernel):
+    """The prefill-chunk program of a small A.X-K1 (one layer, the dense
+    one, a vocabulary of 1,024; the cell's attention widths: 64 heads, a
+    chunk of 1,024 queries at a traced offset over a row of 8,192 latent
+    columns). With ops/latent_prefill_attention.py taken (one
+    `tpu_custom_call` a layer) the program holds no float32 `[1, 64, 1024,
+    1024]` scores, its temporaries are under half the einsum loop's and
+    its code no larger (a kernel that raises its fast-memory limit
+    turns on XLA's prefetches across the program, +5 MB of code a chunk
+    program, which `memory_peak_bytes` counts: PERF.md, PR 39); refused,
+    the loop's scores are there. This is what would catch the loop
+    quietly coming back."""
+    from paddle_tpu.distributed import moe
+    from paddle_tpu.models import axk1
+    from paddle_tpu.ops import latent_prefill_attention as lpa
+
+    monkeypatch.setattr(moe, "on_tpu", lambda: True)    # bf16 x bf16 -> f32
+    cfg = axk1.AXK1Config(num_hidden_layers=1, vocab_size=1024,
+                          n_routed_experts=12, max_seq_len=8192)
+    fwd, _, _ = axk1._decode_fns(cfg)
+    params = {n: chip(shape) for n, (shape, _) in
+              axk1.param_shapes(cfg).items()}
+
+    def compiled(take):
+        def chunk(p, toks, offset, lat):    # a function of its own a choice
+            x, kv, _ = fwd(p, toks, offset, {"latent": lat}, {})
+            return x, kv["latent"]
+
+        monkeypatch.setattr(lpa, "on_tpu", lambda: take)
+        return jax.jit(chunk, donate_argnums=(3,)).lower(
+            params, chip((1, 1024), jnp.int32), chip((), jnp.int32),
+            chip((1, 1, 8192, 640))).compile()
+
+    got = compiled(kernel)
+    text = got.as_text()
+    scores = "f32[1,64,1024,1024]"
+    assert text.count("tpu_custom_call") == int(kernel)
+    assert (scores in text) == (not kernel)
+    if kernel:
+        mem, loop = got.memory_analysis(), compiled(False).memory_analysis()
+        assert mem.temp_size_in_bytes < loop.temp_size_in_bytes // 2
+        # (a raised limit read +2.0 MB here; the kernel's own code is
+        # what the loop's was, within 0.02 MB)
+        assert mem.generated_code_size_in_bytes \
+            < loop.generated_code_size_in_bytes + (1 << 20)

@@ -104,11 +104,11 @@ def test_pallas_audit_zero_errors():
     errs = [f for f in pallas_audit.audit_package()
             if f.severity == "error"]
     assert errs == [], [repr(f) for f in errs]
-    # the manifest actually covers all six kernel families
+    # the manifest actually covers all seven kernel families
     kerns = {e["kernel"].split(".")[0]
              for e in pallas_audit.collect_manifest()}
     assert kerns == {"tpp", "flash", "nms", "kv_store", "decode_attention",
-                     "latent_decode_attention"}
+                     "latent_decode_attention", "latent_prefill_attention"}
 
 
 def test_list_rules_carries_the_new_vocabulary():

@@ -271,7 +271,12 @@ class TestServingPhases:
         chunks = by["serve/prefill_chunk"]
         assert {r[3] for r in chunks} == {"serve/admit"}
         assert len(chunks) == eng.stats()["steps"]["prefill_chunk"]
-        assert all(set(r[5]) == {"slot", "offset", "width"} for r in chunks)
+        assert all(set(r[5]) == {"slot", "offset", "width", "chunk_kv_tiles"}
+                   for r in chunks)
+        # no kernel walks a GPT chunk's keys (ops/latent_prefill_attention.py
+        # is the latent family's, on a TPU)
+        assert {r[5]["chunk_kv_tiles"] for r in chunks} == {0}
+        assert eng.stats()["chunk_kv_tiles"] == 0
         assert {r[3] for r in by["serve/prefill_wait"]} == {"serve/step"}
         bd = eng.stats()["breakdown"]["kinds"]["prefill_chunk"]
         assert bd["count"] == len(chunks)
