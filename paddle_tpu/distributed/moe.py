@@ -20,9 +20,13 @@ is the serving one: no capacity, no token ever dropped under any imbalance. A la
 which experts it HOLDS (`held=(first, count)` of the router's `num_experts`: one chip's share
 of an expert-parallel deployment), routes over all of them and computes its own experts'
 part of the result. Assignments are sorted by expert, each held expert's run is padded to
-whole tiles of `tile` rows, and one loop walks the tiles that exist: work grows with the
-assignments that land on held experts, never with tokens x k x experts. Its trip count is
-data, so it is a forward-only form; training keeps the capacity form above.
+whole tiles of `tile` rows, and one loop walks the tiles that exist: each tile takes its
+rows of the tokens, runs its expert's products and adds its weighted results into its
+tokens' rows of the float32 result (on a TPU by ops/moe_rows.py, a DMA a row). The products
+and the combine grow with the assignments that land on held experts, never with tokens x k
+x experts; so does the dispatch where a tile is large enough to gather its own rows
+(`moe_dropless`: the shape rule). Its trip count is data, so it is a forward-only form;
+training keeps the capacity form above.
 """
 import functools
 import math
@@ -32,6 +36,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..core.device import on_tpu
+from ..ops import moe_rows
 
 
 def compute_capacity(num_tokens, num_experts, k, capacity_factor, multiple_of=4):
@@ -192,6 +197,11 @@ def sigmoid_topk_routing(x, router_w, select_bias, k, normalize=True, scale=1.0)
     return experts.astype(jnp.int32), weights * scale
 
 
+#: the least tile (rows) that gathers its own rows of the tokens inside the loop; a smaller
+#: one slices them from every padded row, gathered before it (`moe_dropless`)
+IN_LOOP_GATHER_MIN_TILE = 64
+
+
 def dropless_tile(num_assignments, num_experts):
     """Rows a tile of the grouped products holds: a power of two from 8 to 256 near twice
     the assignments an expert expects under even routing, so that a decode step's few rows
@@ -210,7 +220,33 @@ def moe_dropless(x, experts, weights, w_gate, w_up, w_down, held=None, num_exper
     `first + i` at row i. held=(first, count), default all. Returns (y [T, d] float32:
     sum over a token's held experts of weight * down(act(gate x) * up x), zero for a token
     with none; counts: int32 scalars `assignments_held`, `rows_computed` (tiles walked x
-    rows a tile) and `experts_touched` (held experts with an assignment))."""
+    rows a tile), `experts_touched` (held experts with an assignment) and `rows_moved`
+    (rows the dispatch gathered plus rows the combine added back)).
+
+    Before the loop: one stable sort of the N = T x k assignments by held expert and, for
+    each of the P = (N / tile + count) x tile rows of the most tiles the routing could
+    need, the token it carries and that assignment's weight, as int32 and float32 tables.
+    In it, a tile takes its rows of x (a padding row is zeros) and adds weight x its
+    expert's result into its tokens' rows of y, each row read and written back whole
+    (ops/moe_rows.py on a TPU): the combine moves the rows walked. A tile's rows belong to
+    one expert, a token takes an expert once and the stable sort keeps an expert's tokens
+    ascending, so a tile's rows are unique and ascending; a padding row names a row past T,
+    which the add skips. A token's row so sums its experts' parts in expert order, in
+    float32; the products take the operands' type (bfloat16 on a TPU) and accumulate in
+    float32.
+
+    The shape rule, for the dispatch: a tile of `IN_LOOP_GATHER_MIN_TILE` rows or more
+    gathers its own rows inside the loop (`rows_moved` 2 x `rows_computed`); a smaller one
+    slices them from all P rows, gathered once before the loop (`rows_moved` P +
+    `rows_computed`). A gather inside the loop costs a few microseconds a tile whatever its
+    rows, which many small tiles do not earn back. Measured on a TPU v5e, the layer alone
+    at the serving cells' shapes, ms a layer (in the loop / before it / the form before
+    either, which also gathered all N assignments back): A.X-K1's prefill chunk (T 1,024,
+    12 of 192 experts held, d 7,168, tile 128) 2.041 / 2.359 / 3.207; Solar's 2,048-token
+    bucket (40 of 320 held, d 4,096, tile 128) 3.311 / 3.566 / 4.584; Solar's 512 bucket
+    (tile 32) 2.351 / 2.288 / 2.297; the decode steps (T 128) with tiles of 16 and 8 1.569
+    / 1.568 / 1.608 and 2.063 / 2.057 / 2.095. The crossover lies between tiles of 32 and
+    128 rows."""
     T, d = x.shape
     k = experts.shape[1]
     count = w_gate.shape[0]
@@ -223,8 +259,9 @@ def moe_dropless(x, experts, weights, w_gate, w_up, w_down, held=None, num_exper
     max_tiles = -(-N // tm) + count
     P_rows = max_tiles * tm
 
-    # the sort and the gathers that lay the assignments out in expert order are the
-    # router's; the loop over the tiles is `moe/experts`, the weighted sum `moe/combine`
+    # the sort and the tables that lay the assignments out in expert order, and the
+    # gather of the tokens' rows, are the router's; the products `moe/experts`; each
+    # tile's weighted add into its tokens' rows `moe/combine`
     with jax.named_scope("moe/router"):
         flat_e = experts.reshape(N) - first
         is_held = (flat_e >= 0) & (flat_e < count)
@@ -236,43 +273,52 @@ def moe_dropless(x, experts, weights, w_gate, w_up, w_down, held=None, num_exper
         n_tiles = tile_end[-1]
         tile_start = tile_end - tiles
         run_start = jnp.cumsum(sizes) - sizes
-        # gathers only (a sort and its inverse), no scatter: the assignments in expert order,
-        # then for every padded row the assignment it carries, if any
         order = jnp.argsort(group, stable=True).astype(jnp.int32)
         tile_expert = jnp.minimum(jnp.searchsorted(
             tile_end, jnp.arange(max_tiles, dtype=jnp.int32), side="right"),
             count - 1).astype(jnp.int32)
+        # a row that carries no assignment names T + its place in the tile: out of range,
+        # and the tile's rows stay unique and ascending
         p_row = jnp.arange(P_rows, dtype=jnp.int32)
         tau = p_row // tm
         e_p = tile_expert[tau]
         r_p = (tau - tile_start[e_p]) * tm + p_row % tm
         carries = (tau < n_tiles) & (r_p < sizes[e_p])
         a_p = order[jnp.clip(run_start[e_p] + r_p, 0, N - 1)]
-        src = jnp.where(carries, a_p // k, T)
-        x_pad = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[src]   # [P_rows, d]
-        # and for every assignment the padded row that carries it
-        safe = jnp.minimum(group, count - 1)
-        rank = jnp.argsort(order).astype(jnp.int32) - run_start[safe]
-        row = jnp.clip(tile_start[safe] * tm + rank, 0, P_rows - 1)
+        src = jnp.where(carries, a_p // k, T + p_row % tm)
+        w_row = jnp.where(carries, weights.reshape(N)[a_p], 0.0).reshape(max_tiles, tm)
+        in_loop = tm >= IN_LOOP_GATHER_MIN_TILE
+        if not in_loop:
+            x_pad = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[jnp.minimum(src, T)]
+        src = src.reshape(max_tiles, tm)
 
-    def body(t, y_pad):
+    R, L = moe_rows.slab_shape(d)     # y laid out so that a token's row is one slab
+
+    def body(t, y3):
         e = tile_expert[t]
-        xt = jax.lax.dynamic_slice_in_dim(x_pad, t * tm, tm, 0)
+        rows = src[t]
+        with jax.named_scope("moe/router"):
+            if in_loop:
+                xt = jnp.take(x, rows, axis=0, mode="fill", fill_value=0,
+                              indices_are_sorted=True, unique_indices=True)
+            else:
+                xt = jax.lax.dynamic_slice_in_dim(x_pad, t * tm, tm, 0)
         wg = jax.lax.dynamic_index_in_dim(w_gate, e, 0, keepdims=False)
         wu = jax.lax.dynamic_index_in_dim(w_up, e, 0, keepdims=False)
         wd = jax.lax.dynamic_index_in_dim(w_down, e, 0, keepdims=False)
         a = activation(dot_f32(xt, wg)) * dot_f32(xt, wu)
         yt = dot_f32(a.astype(x.dtype), wd)
-        return jax.lax.dynamic_update_slice_in_dim(y_pad, yt.astype(x.dtype), t * tm, 0)
+        with jax.named_scope("moe/combine"):
+            return moe_rows.scatter_add_rows(
+                y3, rows, (yt * w_row[t][:, None]).reshape(tm, R, L))
 
     with jax.named_scope("moe/experts"):
-        y_pad = jax.lax.fori_loop(0, n_tiles, body, jnp.zeros((P_rows, d), x.dtype))
-    with jax.named_scope("moe/combine"):
-        picked = y_pad[row].astype(jnp.float32)               # [N, d]
-        w_flat = jnp.where(is_held, weights.reshape(N), 0.0)
-        y = jnp.sum((picked * w_flat[:, None]).reshape(T, k, d), axis=1)
-    counts = {"assignments_held": jnp.sum(sizes), "rows_computed": n_tiles * tm,
-              "experts_touched": jnp.sum(sizes > 0, dtype=jnp.int32)}
+        y3 = jax.lax.fori_loop(0, n_tiles, body, jnp.zeros((T, R, L), jnp.float32))
+    y = y3.reshape(T, d)
+    rows_computed = n_tiles * tm
+    counts = {"assignments_held": jnp.sum(sizes), "rows_computed": rows_computed,
+              "experts_touched": jnp.sum(sizes > 0, dtype=jnp.int32),
+              "rows_moved": (rows_computed if in_loop else P_rows) + rows_computed}
     return y, counts
 
 

@@ -9,7 +9,7 @@ from ..core.tensor import ParamBase, Tensor
 
 #: the counts a decode step returns beside its tokens, summed over the layers
 STEP_COUNTS = ("moe_assignments", "moe_assignments_held", "moe_rows_computed",
-               "moe_experts_touched")
+               "moe_experts_touched", "moe_rows_moved")
 
 
 def count_vector(c):

@@ -108,7 +108,7 @@ class DroplessMoELayer(Layer):
     deployment without its exchange) and adds the shared expert's. Forward
     only: the loop over the tiles that exist has a data-dependent length.
     `self.counts` holds the last forward's `assignments`,
-    `assignments_held`, `rows_computed`, `experts_touched`."""
+    `assignments_held`, `rows_computed`, `experts_touched`, `rows_moved`."""
 
     def __init__(self, d_model, d_ff, num_experts, k=8, held=None,
                  shared_d_ff=None, normalize=True, scale=1.0):

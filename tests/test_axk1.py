@@ -564,6 +564,9 @@ def test_prefill_then_decode_through_the_cache(ref, chunk):
     assert st["kv_tiles_read"] == st["kv_tiles_held"] > 0
     assert st["moe_assignments"] > 0 and \
         0 < st["moe_assignments_held"] == st["moe_assignments"]
+    # the dispatch gathers the rows walked or, where the tiles are small,
+    # every padded row; the combine adds the rows walked back
+    assert st["moe_rows_moved"] >= 2 * st["moe_rows_computed"] > 0
     assert st["lookahead"]["rounds"] > 0
 
 

@@ -13,6 +13,8 @@ The topology is described inside a module-scoped fixture, never at import, and
 every compile happens in this process and this one file: only one process may
 load the TPU's library, and under xdist only the worker handed this file does.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -570,3 +572,61 @@ def test_latent_prefill_chunk_keeps_its_scores_in_fast_memory(
         # what the loop's was, within 0.02 MB)
         assert mem.generated_code_size_in_bytes \
             < loop.generated_code_size_in_bytes + (1 << 20)
+
+
+#: the A.X-K1 prefill-chunk program at its cell's widths, compiled for the
+#: described chip: its code (resident in HBM, which `memory_peak_bytes`
+#: counts, and within 3 GB of the chip in that cell) and the compiler's
+#: prefetches into fast memory (a program that gets them grows by megabytes
+#: of code, PERF.md)
+CHUNK_CODE_BYTES = 13_459_456
+CHUNK_COPY_STARTS = 1
+
+
+def test_latent_prefill_chunk_program_holds_its_code_and_prefetches(
+        chip, monkeypatch):
+    """The benchmark cell's configuration (5 layers, the dense one and four
+    expert layers of 12 held of 192 experts, a vocabulary of 20,480), a chunk
+    of 1,024 tokens at a traced offset into a row of 8,192 latent columns:
+    the attention kernel a layer and the combine's kernel an expert layer
+    are there, the expert layers hold no buffer of the worst case's padded
+    rows (9,728) by the width and sort once a layer, and the program's code
+    and prefetches are no more than this file holds."""
+    import json
+    import os
+
+    from benchmark.runners.serve_latent import program_config
+    from paddle_tpu.distributed import moe
+    from paddle_tpu.models import axk1
+    from paddle_tpu.ops import latent_prefill_attention as lpa
+    from paddle_tpu.ops import moe_rows
+
+    for mod in (moe, lpa, moe_rows):
+        monkeypatch.setattr(mod, "on_tpu", lambda: True)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "a.x-k1.json")) as f:
+        cfg = program_config(json.load(f), 8192)
+    fwd, logits_of, _ = axk1._decode_fns(cfg)
+    params = {n: chip(shape) for n, (shape, _) in
+              axk1.param_shapes(cfg).items()}
+
+    def chunk(p, toks, offset, lat, last):
+        x, kv, _ = fwd(p, toks, offset, {"latent": lat}, {})
+        x_last = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)[:, 0]
+        return kv["latent"], logits_of(p, x_last).astype(jnp.float32)[0]
+
+    got = jax.jit(chunk, donate_argnums=(3,)).lower(
+        params, chip((1, 1024), jnp.int32), chip((), jnp.int32),
+        chip((5, 1, 8192, cfg.cache_width)), chip((), jnp.int32)).compile()
+    text = got.as_text()
+    assert text.count("tpu_custom_call") == 5 + 4
+    assert text.count("moe_scatter_add_rows/pallas_call") == 4
+    # the loops carry the result a token a slab into the kernel, which adds in
+    # place: the program holds no copy of it
+    carry = re.compile(r"= f32\[1024,56,128\]\{[^}]*\} copy\(")
+    assert "f32[1024,56,128]" in text and not carry.search(text)
+    assert "[9728,7168]" not in text
+    assert text.count(" sort(") == 4 * 2      # one a layer, and its top-k's
+    assert got.memory_analysis().generated_code_size_in_bytes \
+        <= CHUNK_CODE_BYTES
+    assert text.count("copy-start(") == CHUNK_COPY_STARTS

@@ -292,6 +292,9 @@ def test_prefill_at_every_bucket_then_steps_with_other_slots_live(ref):
     assert st["lookahead"]["rounds_overlapped"] >= st["lookahead"]["rounds"] - 2
     assert st["moe_assignments"] == st["moe_assignments_held"] > 0
     assert st["moe_rows_computed"] >= st["moe_assignments_held"]
+    # the dispatch gathers the rows walked or, where the tiles are small,
+    # every padded row; the combine adds the rows walked back
+    assert st["moe_rows_moved"] >= 2 * st["moe_rows_computed"] > 0
     assert set(st["state_bytes"]["held"]) == {"kv", "recurrent", "conv"}
     # 6 KDA layers x 4 slots x 4 heads x 16 x 16 float32
     assert st["state_bytes"]["held"]["recurrent"] == 6 * 4 * 4 * 16 * 16 * 4
